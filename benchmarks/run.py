@@ -35,6 +35,8 @@ def main(argv=None) -> int:
                     help="machine-readable per-bench results on stdout")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig9_tap, kernel_dispatch, roofline,
                             serve_continuous, serve_decode, serve_drift,
                             serve_fleet, serve_migration, serve_observed,

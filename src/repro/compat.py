@@ -1,30 +1,17 @@
-"""Version-compatibility shims for the jax APIs this repo uses.
-
-The codebase targets the modern API surface (``jax.shard_map`` with
-``check_vma``); these shims keep it importable and correct on jax 0.4.x,
-where shard_map lives in ``jax.experimental.shard_map`` and the replication
-check is spelled ``check_rep``.
-"""
+"""Thin wrappers over jax APIs whose spelling the codebase pins in one
+place: ``shard_map`` (replication check off by default) and the compiled
+program's cost analysis as one flat dict."""
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.6
-    _CHECK_KW = "check_vma"
-except ImportError:                                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` signature, portable across jax versions."""
+    """``jax.shard_map`` with the replication check off unless asked for."""
     return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
+                      check_vma=check_vma)
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as one dict: jax 0.4.x returns a
-    per-partition list, newer jax a flat dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()`` as one dict."""
+    return compiled.cost_analysis() or {}

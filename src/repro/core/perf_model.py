@@ -99,6 +99,15 @@ def optimal_folding(workloads: Sequence[float], budget: int,
         ideal = budget * wl / tot
         lv = max(l for l in levels if l <= max(ideal, 1))
         alloc.append(lv)
+    # the one-unit floor per layer can push the projection past the budget:
+    # step back down the levels where the lower level hurts the rate least
+    while sum(alloc) > max(budget, len(alloc)):
+        lower = {i: max(l for l in levels if l < a)
+                 for i, a in enumerate(alloc) if a > min(levels)}
+        if not lower:
+            break
+        i = min(lower, key=lambda i: workloads[i] / lower[i])
+        alloc[i] = lower[i]
     # greedily spend leftover budget on the bottleneck layer
     def bump(a):
         while True:

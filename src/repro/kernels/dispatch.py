@@ -5,9 +5,10 @@ The serving runtime (runtime/serve_loop.py) and the one-shot pipeline
 buffer call through this module instead of picking an implementation at the
 call site. Three backends exist per kernel:
 
-  pallas     — the compiled Pallas TPU kernel (kernel.py). Only meaningful
-               on a TPU backend; requesting it elsewhere degrades to
-               ``interpret``.
+  pallas     — the compiled Pallas TPU kernel (kernel.py). Only exists on
+               a TPU backend; requesting it elsewhere raises, so a run that
+               asked for the chip's kernels never silently measures the
+               interpreter instead.
   interpret  — the same Pallas kernel body run under the Pallas interpreter.
                Validates the kernel on CPU but is orders of magnitude slower
                than XLA; used by the parity tests, never by the hot path.
@@ -17,7 +18,7 @@ call site. Three backends exist per kernel:
 Resolution order: explicit ``backend=`` argument > ``set_backend()`` >
 ``REPRO_KERNEL_BACKEND`` env var > ``auto``. ``auto`` picks ``pallas`` on
 TPU and ``ref`` everywhere else — i.e. the hot path always runs compiled
-code, and the interpreter is something you must ask for.
+code, and the interpreter is something you must ask for by name.
 """
 from __future__ import annotations
 
@@ -27,7 +28,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro import compat
 from repro.kernels.exit_decision.kernel import exit_decision_pallas
 from repro.kernels.exit_decision.ref import exit_decision_ref
 from repro.kernels.fused_dispatch.kernel import fused_dispatch_pallas
@@ -91,56 +94,80 @@ def kernel_backend(backend: Optional[str] = None) -> str:
     if req == "auto":
         res = "pallas" if _on_tpu() else "ref"
     elif req == "pallas" and not _on_tpu():
-        res = "interpret"           # kernel body still runs, just interpreted
+        raise RuntimeError(
+            f"kernel backend 'pallas' needs a TPU, but JAX's default backend "
+            f"is {jax.default_backend()!r}; ask for 'interpret' to run the "
+            f"kernel bodies under the Pallas interpreter")
     else:
         res = req
     _resolve_cache[key] = res
     return res
 
 
+def kernel_body_backend() -> str:
+    """The backend that runs the Pallas kernel BODIES here: compiled on
+    TPU, interpreted elsewhere — what the per-kernel ``ops.py`` parity
+    wrappers ask for with ``use_pallas=True``."""
+    return "pallas" if _on_tpu() else "interpret"
+
+
 # ---------------------------------------------------------------------------
 # dispatched ops (the serving hot path calls these)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("backend",))
-def _exit_decision(logits, c_thr, backend: str):
+def _on_mesh(kernel, mesh, *args):
+    """Call a Pallas kernel inside a program partitioned over ``mesh`` (a
+    multi-device stage submesh). The TPU compiler cannot partition a Mosaic
+    kernel, so every device runs the whole call on replicated operands."""
+    if mesh is None or mesh.size == 1:
+        return kernel(*args)
+    return compat.shard_map(kernel, mesh=mesh, in_specs=P(),
+                            out_specs=P())(*args)
+
+
+@functools.partial(jax.jit, static_argnames=("backend", "mesh"))
+def _exit_decision(logits, c_thr, backend: str, mesh=None):
     if backend == "ref":
         return exit_decision_ref(logits, c_thr)
-    return exit_decision_pallas(logits, c_thr,
-                                interpret=(backend == "interpret"))
+    return _on_mesh(functools.partial(exit_decision_pallas,
+                                      interpret=(backend == "interpret")),
+                    mesh, logits, c_thr)
 
 
 def exit_decision_op(logits: jnp.ndarray, c_thr, *,
-                     backend: Optional[str] = None
+                     backend: Optional[str] = None, mesh=None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused exit decision (Eq. 4). logits: (..., V) -> (exit bool, pred
     i32, conf f32), each shaped (...,). One streamed read of the logits;
-    no materialized softmax on any backend."""
+    no materialized softmax on any backend. ``mesh``: the stage submesh
+    the enclosing program spans, if any."""
     lead = logits.shape[:-1]
     x = logits.reshape((-1, logits.shape[-1]))
-    e, p, c = _exit_decision(x, c_thr, kernel_backend(backend))
+    e, p, c = _exit_decision(x, c_thr, kernel_backend(backend), mesh)
     return e.reshape(lead), p.reshape(lead), c.reshape(lead)
 
 
-@functools.partial(jax.jit, static_argnames=("capacity", "backend"))
-def _gather_compact(x, hard_mask, capacity: int, backend: str):
+@functools.partial(jax.jit, static_argnames=("capacity", "backend", "mesh"))
+def _gather_compact(x, hard_mask, capacity: int, backend: str, mesh=None):
     if backend == "ref":
         return gather_compact_ref(x, hard_mask, capacity)
-    return gather_compact_pallas(x, hard_mask, capacity,
-                                 interpret=(backend == "interpret"))
+    return _on_mesh(functools.partial(gather_compact_pallas,
+                                      capacity=capacity,
+                                      interpret=(backend == "interpret")),
+                    mesh, x, hard_mask)
 
 
 def gather_compact_op(x: jnp.ndarray, hard_mask: jnp.ndarray, capacity: int,
-                      *, backend: Optional[str] = None
+                      *, backend: Optional[str] = None, mesh=None
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Conditional-buffer compaction. x: (B, ...); hard_mask: (B,) bool.
     Returns (slab (capacity, ...), slab_ids (capacity,) int32 with -1 flush
-    slots, n_hard ())."""
+    slots, n_hard ()). ``mesh`` as for ``exit_decision_op``."""
     B = x.shape[0]
     feat = x.shape[1:]
     xf = x.reshape(B, -1)
     slab, ids, nh = _gather_compact(xf, hard_mask, capacity,
-                                    kernel_backend(backend))
+                                    kernel_backend(backend), mesh)
     return slab.reshape((capacity,) + feat), ids, nh
 
 

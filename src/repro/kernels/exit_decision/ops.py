@@ -20,5 +20,5 @@ def exit_decision_op(logits: jnp.ndarray, c_thr, *, use_pallas: bool = True
                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused exit decision. logits: (..., V) -> (exit bool, pred i32,
     conf f32), each shaped (...,)."""
-    backend = "pallas" if use_pallas else "ref"
+    backend = dispatch.kernel_body_backend() if use_pallas else "ref"
     return dispatch.exit_decision_op(logits, c_thr, backend=backend)

@@ -17,10 +17,11 @@ Composition of two streamed kernels plus O(B + size) integer cursor math:
      the buffer being the inter-stage ring itself rather than a slab that
      XLA would scatter into the ring afterwards.
 
-The slot→source map ``src_ring`` (size,) is precomputed in SMEM: ring slot
-``r`` takes payload row ``src[(r - head - count) % size]`` iff that lane is
-below ``n_enq = min(n_hard, free)``, else keeps its current bytes. Each
-slot is claimed at most once because ``n_enq <= size``.
+The slot→source map ``src_map`` (size,) is computed by XLA and handed to
+the merge kernel as a scalar-prefetch operand: ring slot ``r`` takes
+payload row ``src[(r - head - count) % size]`` iff that lane is below
+``n_enq = min(n_hard, free)``, else keeps its current bytes. Each slot is
+claimed at most once because ``n_enq <= size``.
 """
 from __future__ import annotations
 
@@ -37,9 +38,9 @@ from repro.kernels.fused_dispatch.ref import compact_src
 
 
 def _scatter_merge_kernel(srcmap_ref, x_ref, ring_ref, out_ref):
-    sr = srcmap_ref[...]                                   # (size,) SMEM
-    rows = jnp.take(x_ref[...], jnp.maximum(sr, 0), axis=0)
-    out_ref[...] = jnp.where((sr >= 0)[:, None], rows.astype(out_ref.dtype),
+    r = pl.program_id(0)
+    claimed = srcmap_ref[r] >= 0                          # scalar SMEM read
+    out_ref[...] = jnp.where(claimed, x_ref[...].astype(out_ref.dtype),
                              ring_ref[...])
 
 
@@ -48,23 +49,36 @@ def _scatter_merge(src_map, x, ring_leaf, *, block_f: int = 2048,
                    interpret: bool = False):
     """x: (B, F) payload leaf; ring_leaf: (size, F). Writes row
     ``x[src_map[r]]`` into slot r where ``src_map[r] >= 0``; other slots
-    keep their bytes. Ring slab aliased in place."""
+    keep their bytes. Ring slab aliased in place.
+
+    ``src_map`` is a SCALAR-PREFETCH operand: grid cell (r, j) DMAs payload
+    row ``src_map[r]`` (row 0 for unclaimed slots — consecutive cells
+    re-use the fetched block) next to ring slot r's tile j, and the body
+    reads the one scalar ``src_map[r]``. Rows move as ``(1, 1, bf)`` blocks
+    over a unit middle axis, which the TPU's (8, 128) tiling rule accepts
+    for any ring size or batch."""
     size, F = ring_leaf.shape
+    b = x.shape[0]
     bf = min(block_f, F)
-    n_f = pl.cdiv(F, bf)
-    return pl.pallas_call(
-        _scatter_merge_kernel,
-        grid=(n_f,),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                      # src_map -> SMEM
+        grid=(size, pl.cdiv(F, bf)),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # src_map (size,)
-            pl.BlockSpec((x.shape[0], bf), lambda j: (0, j)),
-            pl.BlockSpec((size, bf), lambda j: (0, j)),
+            pl.BlockSpec((1, 1, bf),
+                         lambda r, j, sm: (jnp.maximum(sm[r], 0), 0, j)),
+            pl.BlockSpec((1, 1, bf), lambda r, j, sm: (r, 0, j)),
         ],
-        out_specs=pl.BlockSpec((size, bf), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((size, F), ring_leaf.dtype),
+        out_specs=pl.BlockSpec((1, 1, bf), lambda r, j, sm: (r, 0, j)),
+    )
+    out = pl.pallas_call(
+        _scatter_merge_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((size, 1, F), ring_leaf.dtype),
+        # flat inputs = (src_map, x, ring): the ring slab aliases the output
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(src_map, x, ring_leaf)
+    )(src_map, x.reshape(b, 1, F), ring_leaf.reshape(size, 1, F))
+    return out.reshape(size, F)
 
 
 def fused_dispatch_pallas(logits, active, sample_ids, payload, ring, c_thr,

@@ -19,6 +19,6 @@ def fused_dispatch_op(logits: jnp.ndarray, active: Optional[jnp.ndarray],
                       *, use_pallas: bool = True):
     """See ``fused_dispatch_ref`` for the contract. Returns
     (ring', exit_mask, pred, conf, src, n_hard)."""
-    backend = "pallas" if use_pallas else "ref"
+    backend = dispatch.kernel_body_backend() if use_pallas else "ref"
     return dispatch.fused_dispatch_op(logits, active, sample_ids, payload,
                                       ring, c_thr, backend=backend)

@@ -2,15 +2,21 @@
 
 The FPGA conditional buffer drops an exiting sample's feature map by
 invalidating its addresses in one cycle. The TPU analogue: a stable
-prefix-sum partition computed ONCE into SMEM scratch (grid step 0), then a
-row-gather of surviving samples streamed feature-tile by feature-tile —
-x is read once from HBM and only the compacted slab is written back, so the
-stage-2 input slab never round-trips through host memory (the paper keeps
-the decision on-chip for exactly this reason).
+prefix-sum partition over the (B,) mask — O(B) integer math that XLA lowers
+inline (``compact_src``, shared bitwise with the fused dispatch kernel) —
+yields the take vector, which rides into the kernel as a SCALAR-PREFETCH
+operand (``pltpu.PrefetchScalarGridSpec``). The x BlockSpec's index map
+looks up ``take[c]``, so grid cell (c, j) DMAs exactly source row
+``take[c]``'s feature tile j into slab row c: x is read only where a row
+survives and only the compacted slab is written back, so the stage-2 input
+slab never round-trips through host memory (the paper keeps the decision
+on-chip for exactly this reason).
 
-Grid: (F / bf,), feature axis only; the (B,) mask and the (C,) take-vector
-live in SMEM across all steps. Dynamic row-gather inside a tile lowers to
-the TPU dynamic-gather over sublanes.
+SMEM is only ever read as scalars (the index maps), and rows move as
+``(1, 1, bf)`` blocks over a unit middle axis, so every block obeys the
+TPU's (8, 128) tiling rule whatever the batch size.
+
+Grid: (C, F / bf).
 """
 from __future__ import annotations
 
@@ -21,33 +27,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fused_dispatch.ref import compact_src
 
-def _gather_compact_kernel(mask_ref, x_ref, slab_ref, ids_ref, nhard_ref,
-                           take_ref, *, batch: int, capacity: int):
-    j = pl.program_id(0)
 
-    # -- step 0: prefix-sum partition -> take vector + ids + n_hard (SMEM) ----
-    @pl.when(j == 0)
-    def _():
-        hard = mask_ref[...].astype(jnp.int32)              # (B,)
-        n_hard = jnp.sum(hard)
-        pos_hard = jnp.cumsum(hard) - 1                     # slot among hard
-        pos_easy = jnp.cumsum(1 - hard) - 1                 # slot among easy
-        slot = jnp.where(hard == 1, pos_hard, n_hard + pos_easy)
-        perm = jnp.zeros((batch,), jnp.int32).at[slot].set(
-            jnp.arange(batch, dtype=jnp.int32))
-        take = perm[:capacity] if capacity <= batch else jnp.pad(
-            perm, (0, capacity - batch))
-        valid = jnp.arange(capacity, dtype=jnp.int32) < jnp.minimum(
-            n_hard, capacity)
-        take = jnp.where(valid, take, 0)
-        take_ref[...] = take
-        ids_ref[...] = jnp.where(valid, take, -1)
-        nhard_ref[0] = n_hard
+def _copy_row_kernel(take_ref, x_ref, slab_ref):
+    slab_ref[...] = x_ref[...]
 
-    # -- every step: gather surviving rows for this feature tile --------------
-    xt = x_ref[...]                                         # (B, bf)
-    slab_ref[...] = jnp.take(xt, take_ref[...], axis=0)     # (C, bf)
+
+def gather_rows_pallas(x: jnp.ndarray, take: jnp.ndarray, *,
+                       block_f: int = 2048, interpret: bool = False):
+    """x: (B, F); take: (C,) int32 row indices in [0, B). Returns
+    ``x[take]`` (C, F), one scalar-prefetched row DMA per (row, tile)."""
+    B, F = x.shape
+    C = take.shape[0]
+    bf = min(block_f, F)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                      # take -> SMEM
+        grid=(C, pl.cdiv(F, bf)),
+        in_specs=[pl.BlockSpec((1, 1, bf), lambda c, j, t: (t[c], 0, j))],
+        out_specs=pl.BlockSpec((1, 1, bf), lambda c, j, t: (c, 0, j)),
+    )
+    slab = pl.pallas_call(
+        _copy_row_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((C, 1, F), x.dtype),
+        interpret=interpret,
+    )(take, x.reshape(B, 1, F))
+    return slab.reshape(C, F)
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "block_f",
@@ -57,32 +63,12 @@ def gather_compact_pallas(x: jnp.ndarray, hard_mask: jnp.ndarray,
                           interpret: bool = False):
     """x: (B, F); hard_mask: (B,) bool. Returns (slab (C, F), slab_ids (C,),
     n_hard ())."""
-    B, F = x.shape
-    bf = min(block_f, F)
-    n_f = pl.cdiv(F, bf)
-
-    kernel = functools.partial(_gather_compact_kernel, batch=B,
-                               capacity=capacity)
-    slab, ids, nh = pl.pallas_call(
-        kernel,
-        grid=(n_f,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # mask (B,)
-            pl.BlockSpec((B, bf), lambda j: (0, j)),        # x feature tile
-        ],
-        out_specs=(
-            pl.BlockSpec((capacity, bf), lambda j: (0, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # ids (C,)
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # n_hard (1,)
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((capacity, F), x.dtype),
-            jax.ShapeDtypeStruct((capacity,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ),
-        scratch_shapes=[
-            pltpu.SMEM((capacity,), jnp.int32),             # take vector
-        ],
-        interpret=interpret,
-    )(hard_mask, x)
-    return slab, ids, nh[0]
+    B = x.shape[0]
+    src, n_hard = compact_src(hard_mask)             # (B,), -1 past n_hard
+    if capacity <= B:
+        ids = src[:capacity]
+    else:
+        ids = jnp.pad(src, (0, capacity - B), constant_values=-1)
+    take = jnp.maximum(ids, 0)                       # flush slots -> row 0
+    slab = gather_rows_pallas(x, take, block_f=block_f, interpret=interpret)
+    return slab, ids, n_hard

@@ -19,6 +19,6 @@ def gather_compact_op(x: jnp.ndarray, hard_mask: jnp.ndarray, capacity: int,
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """x: (B, ...); hard_mask: (B,). Returns (slab (C, ...), slab_ids (C,),
     n_hard ())."""
-    backend = "pallas" if use_pallas else "ref"
+    backend = dispatch.kernel_body_backend() if use_pallas else "ref"
     return dispatch.gather_compact_op(x, hard_mask, capacity,
                                       backend=backend)

@@ -43,8 +43,8 @@ def _paged_gather_append_kernel(bt_ref, pos_ref, ap_ref, bp_ref, an_ref,
             & (bt_ref[b, p] > 0))
     rows = jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
     write = tail & (rows == pos % page)                  # (page, 1)
-    a_merged = jnp.where(write, an_ref[0][None, :], ap_ref[0])
-    b_merged = jnp.where(write, bn_ref[0][None, :], bp_ref[0])
+    a_merged = jnp.where(write, an_ref[0], ap_ref[0])    # an_ref[0]: (1, F)
+    b_merged = jnp.where(write, bn_ref[0], bp_ref[0])
     ga_ref[0, 0] = a_merged
     gb_ref[0, 0] = b_merged
     apo_ref[0] = a_merged
@@ -71,8 +71,10 @@ def paged_gather_append_pallas(a_pool: jnp.ndarray, b_pool: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((1, page, fa), lambda b, p, bt, pos: (bt[b, p], 0, 0)),
             pl.BlockSpec((1, page, fb), lambda b, p, bt, pos: (bt[b, p], 0, 0)),
-            pl.BlockSpec((1, fa), lambda b, p, bt, pos: (b, 0)),
-            pl.BlockSpec((1, fb), lambda b, p, bt, pos: (b, 0)),
+            # new-token rows ride a unit middle axis: a (1, 1, F) block
+            # is (8, 128)-legal for any batch size
+            pl.BlockSpec((1, 1, fa), lambda b, p, bt, pos: (b, 0, 0)),
+            pl.BlockSpec((1, 1, fb), lambda b, p, bt, pos: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, page, fa),
@@ -97,4 +99,5 @@ def paged_gather_append_pallas(a_pool: jnp.ndarray, b_pool: jnp.ndarray,
             # b_new); the pools alias the in-place pool outputs (2 and 3)
             input_output_aliases={2: 2, 3: 3},
             interpret=interpret,
-        )(block_tables, pos, a_pool, b_pool, a_new, b_new)
+        )(block_tables, pos, a_pool, b_pool, a_new.reshape(B, 1, fa),
+          b_new.reshape(B, 1, fb))

@@ -16,7 +16,7 @@ def paged_gather_append_op(a_pool, b_pool, a_new, b_new, block_tables, pos,
     """a_pool/b_pool: (P, page, *F); a_new/b_new: (B, *F); block_tables:
     (B, M) i32; pos: (B,) i32. Returns (gathered_a (B, M, page, *Fa),
     gathered_b, a_pool', b_pool')."""
-    backend = "pallas" if use_pallas else "ref"
+    backend = dispatch.kernel_body_backend() if use_pallas else "ref"
     return dispatch.paged_gather_append_op(a_pool, b_pool, a_new, b_new,
                                            block_tables, pos,
                                            backend=backend, donate=donate)

@@ -2,6 +2,10 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state; only launch/dryrun.py forces the 512 host-device platform.
+
+Every mesh here has ``Auto`` axes: ``jax.make_mesh`` defaults to
+``Explicit`` axes, which the model's ``with_sharding_constraint`` layout
+hints (models/hints.py) may not name.
 """
 from __future__ import annotations
 
@@ -14,12 +18,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Arbitrary mesh (degraded/elastic shapes, e.g. (15, 16))."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def stage_submeshes(plan, devices=None):

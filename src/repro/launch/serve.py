@@ -67,16 +67,18 @@ work in every mode."""
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
-from typing import Optional
+from typing import Dict, List, Optional
 
 import jax
 import numpy as np
 
 from repro.core import early_exit as ee
 from repro.core.stage_mesh import StageMeshPlan, stage2_capacity
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import stage_submeshes
 from repro.launch.shardings import stage_io_shardable
 from repro.models.registry import get_arch, get_smoke, list_archs
@@ -103,6 +105,25 @@ def make_placement(p: float, batch: int, chips1: Optional[int] = None,
                       name="stage1"),
         StageExecutor(m2, shard_io=stage_io_shardable(m2, batch),
                       name="stage2"))
+
+
+def serve_requests(sched, prompts: np.ndarray, n_tokens: int,
+                   arrival_rate: float = float("inf"), *, first_id: int = 0,
+                   profile=None) -> Dict[int, List[int]]:
+    """Submit one open-loop decode request per prompt row (sample ids from
+    ``first_id``, Poisson arrivals at ``arrival_rate``; inf = all at once)
+    and drive ``sched`` until every request finishes, inside ``profile``
+    when given. Returns this call's {sample_id: greedy token stream}."""
+    arrivals = poisson_arrivals(len(prompts), arrival_rate, seed=2)
+    sids = range(first_id, first_id + len(prompts))
+    for sid, prompt, t in zip(sids, prompts, arrivals):
+        sched.submit(Request(sample_id=sid, prompt=prompt, n_tokens=n_tokens,
+                             arrival_time=float(t)))
+    with profile if profile is not None else contextlib.nullcontext():
+        results = sched.run()
+    streams = {sid: results[sid] for sid in sids}
+    assert all(len(v) == n_tokens for v in streams.values())
+    return streams
 
 
 def _summarized_stats(stats) -> dict:
@@ -202,7 +223,6 @@ def _finalize_observability(args, obs, expect_sids=None) -> dict:
 def _maybe_profile(args, obs, events):
     """Context for the serving loop: a jax.profiler window when
     --profile-dir is set, nullcontext otherwise."""
-    import contextlib
     if obs is None or not args.profile_dir:
         return contextlib.nullcontext()
     return obs["observe"].ProfileWindow(args.profile_dir,
@@ -370,6 +390,7 @@ def main(argv=None) -> int:
                      help="scheduler ticks to keep the --profile-dir "
                           "window open (default 64)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.mode == "prefill" and (args.trace_out or args.spans_out
                                    or args.profile_dir):
@@ -423,16 +444,11 @@ def main(argv=None) -> int:
                 latency_slo_p99=args.controller_slo_p99,
                 apply_replan=args.controller_replan))
             controller.attach(sched)
-        arrivals = poisson_arrivals(args.requests, args.arrival_rate, seed=2)
-        for i in range(args.requests):
-            sched.submit(Request(sample_id=i, prompt=prompts[i],
-                                 n_tokens=args.decode_tokens,
-                                 arrival_time=float(arrivals[i])))
-        with _maybe_profile(args, obs, sched.events):
-            results = sched.run()
+        results = serve_requests(sched, prompts, args.decode_tokens,
+                                 args.arrival_rate,
+                                 profile=_maybe_profile(args, obs,
+                                                        sched.events))
         makespan = sched.clock.now()
-        assert len(results) == args.requests
-        assert all(len(v) == args.decode_tokens for v in results.values())
         n_tok = sum(len(v) for v in results.values())
         stats = _summarized_stats(sched.stats)
         payload = {"arch": args.arch, "mode": "decode",

@@ -13,6 +13,7 @@ import json
 
 from repro.core import early_exit as ee
 from repro.data import pipeline as dp
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_arch, get_smoke, list_archs
 from repro.optim import adamw
 from repro.runtime import train_loop as TL
@@ -33,6 +34,7 @@ def main(argv=None) -> int:
                     help="inject a failure at this step (restart demo)")
     ap.add_argument("--exit-layer", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     spec = (ee.EarlyExitSpec(exit_layer=args.exit_layer)

@@ -689,9 +689,22 @@ def _seg_select(active, new, old):
 # DecodeFns reuse compiled programs)
 # ---------------------------------------------------------------------------
 
+def _tick_fn(fn) -> jax.tree_util.Partial:
+    """A stage body as a pytree ARGUMENT of the tick jit: the arrays a
+    ``jax.tree_util.Partial`` binds (the stage params) become program
+    arguments, while the function itself keys the compile cache. A static
+    or closed-over function would embed its arrays in the program as
+    constants — gigabytes of host memory per compile at published
+    widths."""
+    if isinstance(fn, jax.tree_util.Partial):
+        return fn
+    return jax.tree_util.Partial(fn)
+
+
 @functools.partial(jax.jit, donate_argnums=(1,),
-                   static_argnames=("s1", "backend"))
-def _pool_tick(tok, c1, pos, active, start, budget, c_thr, *, s1, backend):
+                   static_argnames=("backend", "mesh"))
+def _pool_tick(tok, c1, pos, active, start, budget, c_thr, *, s1, backend,
+               mesh):
     """One continuous tick over the whole slot pool: masked stage 1 at
     per-slot positions, fused exit decision + compaction, easy-token
     advance. The active mask is device-resident — easy rows stay active
@@ -707,13 +720,13 @@ def _pool_tick(tok, c1, pos, active, start, budget, c_thr, *, s1, backend):
         nc1 = _seg_select(active, nc1, c1)
         # the decision kernel's pred IS the greedy token — one logits pass
         # serves both the exit decision and the emitted token
-        exit_mask, pred, conf = dispatch.exit_decision_op(exit_logits, c_thr,
-                                                          backend=backend)
+        exit_mask, pred, conf = dispatch.exit_decision_op(
+            exit_logits, c_thr, backend=backend, mesh=mesh)
         easy = active & exit_mask
         hard = active & ~exit_mask
         n = tok.shape[0]
-        slab, src, n_hard = dispatch.gather_compact_op(h, hard, n,
-                                                       backend=backend)
+        slab, src, n_hard = dispatch.gather_compact_op(
+            h, hard, n, backend=backend, mesh=mesh)
         slab_slots = src                      # slot index IS the ring id
         slab_steps = jnp.where(src >= 0, jnp.take(pos, jnp.maximum(src, 0)),
                                0)
@@ -725,7 +738,7 @@ def _pool_tick(tok, c1, pos, active, start, budget, c_thr, *, s1, backend):
 
 
 @functools.partial(jax.jit, donate_argnums=(1, 6),
-                   static_argnames=("s1", "backend"))
+                   static_argnames=("backend",))
 def _pool_tick_fused(tok, c1, pos, active, start, budget, ring, rows, c_thr,
                      *, s1, backend):
     """The persistent-tick variant: ONE compiled program per steady-state
@@ -1226,20 +1239,22 @@ class ContinuousScheduler:
         # derive the fused tick's ring row spec without executing stage 1:
         # the ring must exist BEFORE the first fused launch (it is donated
         # through the tick), and its 'h' leaf shape is stage 1's output.
-        # Duck-typed fns that resist abstract evaluation simply keep the
-        # composed three-program tick.
+        # Duck-typed host-side fns that pull a tracer to the host (JAX's
+        # tracer-conversion errors) keep the composed three-program tick;
+        # any other failure is a real stage-1 bug and raises here.
         try:
             h_av, _, _ = jax.eval_shape(self.fns.s1_raw, self._tok,
                                         self._c1, self._pos)
-            self._ring_row_spec = {
-                "h": jax.ShapeDtypeStruct(h_av.shape[1:], h_av.dtype),
-                "cache": jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
-                    self._rows),
-                "step": jax.ShapeDtypeStruct((), jnp.int32),
-            }
-        except Exception:
+        except jax.errors.JAXTypeError:
             self._ring_row_spec = None
+            return
+        self._ring_row_spec = {
+            "h": jax.ShapeDtypeStruct(h_av.shape[1:], h_av.dtype),
+            "cache": jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                self._rows),
+            "step": jax.ShapeDtypeStruct((), jnp.int32),
+        }
 
     def _admit_chunk(self, reqs: List[Request]) -> None:
         """Admit a chunk of requests sharing one prompt length with ONE
@@ -1301,18 +1316,20 @@ class ContinuousScheduler:
                 self._finish_slot(slot)
         self.peak_busy = max(self.peak_busy, self.n_slots - len(self._free))
 
-    def _try_admit(self) -> None:
+    def _try_admit(self) -> int:
         """Admit admissible requests in arrival order, chunked to power-of-2
         batch sizes (bounded set of prefill shapes -> bounded compiles). A
         chunk is a same-prompt-length prefix of the admissible run, bounded
-        by free slots AND the controller's live-occupancy cap."""
+        by free slots AND the controller's live-occupancy cap. Returns the
+        number of requests admitted."""
+        admitted = 0
         if not self._admission_open:          # QUIESCE: migration in flight
-            return
+            return admitted
         while self._free and self.queue:
             busy = self.n_slots - len(self._free)
             headroom = min(len(self._free), self.active_cap - busy)
             if headroom <= 0:
-                return
+                return admitted
             now = self.clock.now()
             n_adm = 0
             pages_acc = 0
@@ -1337,9 +1354,11 @@ class ContinuousScheduler:
                     pages_acc += need
                 n_adm += 1
             if n_adm == 0:
-                return
+                return admitted
             k = 1 << (n_adm.bit_length() - 1)     # largest power of 2 <= n
             self._admit_chunk([self.queue.popleft() for _ in range(k)])
+            admitted += k
+        return admitted
 
     # -- emission / completion ----------------------------------------------
 
@@ -1471,6 +1490,23 @@ class ContinuousScheduler:
         return (self._ring_row_spec is not None
                 and not self.placement.disaggregated)
 
+    def tick_hlo(self) -> str:
+        """The compiled program text of the pool tick this scheduler runs
+        (fused or composed) at the pool's current shapes — which kernels
+        the tick really contains. Needs a pool: call after an admission."""
+        lanes = (self._tok, self._c1, self._pos, self._active_lane,
+                 self._start_lane, self._budget_lane)
+        kw = dict(s1=_tick_fn(self.fns.s1_raw),
+                  backend=dispatch.kernel_backend())
+        if self._use_fused():
+            lowered = _pool_tick_fused.lower(
+                *lanes, self.ring.ensure(self._ring_row_spec), self._rows,
+                self.c_thr, **kw)
+        else:
+            lowered = _pool_tick.lower(*lanes, self.c_thr,
+                                       mesh=self.ex1.mesh, **kw)
+        return lowered.compile().as_text()
+
     def _tick(self) -> None:
         if self._use_fused():
             self._tick_fused()
@@ -1522,7 +1558,8 @@ class ContinuousScheduler:
              self._tok, self._pos, self._active_lane, conf) = _pool_tick(
                 self._tok, self._c1, self._pos, self._active_lane,
                 self._start_lane, self._budget_lane, self.c_thr,
-                s1=self.fns.s1_raw, backend=dispatch.kernel_backend())
+                s1=_tick_fn(self.fns.s1_raw),
+                backend=dispatch.kernel_backend(), mesh=self.ex1.mesh)
         n_hard, hard_np = self._finish_tick(n_hard_dev, easy, hard, pred,
                                             conf)
         if n_hard > 0:
@@ -1548,7 +1585,7 @@ class ContinuousScheduler:
              conf) = _pool_tick_fused(
                 self._tok, self._c1, self._pos, self._active_lane,
                 self._start_lane, self._budget_lane, ring_buf, self._rows,
-                self.c_thr, s1=self.fns.s1_raw,
+                self.c_thr, s1=_tick_fn(self.fns.s1_raw),
                 backend=dispatch.kernel_backend())
         self.ring.put_buf(ring_buf)
         n_hard, hard_np = self._finish_tick(n_hard_dev, easy, hard, pred,
@@ -1638,7 +1675,7 @@ class ContinuousScheduler:
         pending — ``drain`` harvests them)."""
         self._maybe_migrate()                # discrete re-plan points only
         self._maybe_apply_capacity()
-        self._try_admit()
+        admitted = self._try_admit()
         self._refresh_page_stats()
         if self._n_state(_ACTIVE) > 0:
             self._tick()
@@ -1653,6 +1690,8 @@ class ContinuousScheduler:
             # forced partial: all parked
             faults.retry(self._dispatch_bucket, what="forced-drain")
             return "busy"
+        if admitted:                         # prefill-only requests finish
+            return "busy"                    # at admission: still progress
         if self.queue:
             if not self._free:               # full pool, all parked, empty
                 raise AssertionError("scheduler wedged: parked slots "

@@ -114,8 +114,9 @@ from repro.runtime.serve_api import (  # noqa: F401  (re-exports)
     ReplicaHandle, RequestQueue, build, validate_request)
 
 
-@functools.partial(jax.jit, static_argnames=("backend",))
-def _decide_compact(hidden, exit_logits, sample_ids, c_thr, *, backend):
+@functools.partial(jax.jit, static_argnames=("backend", "mesh"))
+def _decide_compact(hidden, exit_logits, sample_ids, c_thr, *, backend,
+                    mesh=None):
     """Fused exit decision + conditional-buffer compaction, one device
     program shared by every server instance (c_thr is traced, so a new
     threshold never recompiles; the resolved kernel backend is a static
@@ -126,11 +127,11 @@ def _decide_compact(hidden, exit_logits, sample_ids, c_thr, *, backend):
     drift-telemetry reservoir (free on device; only fetched when a
     controller is listening), as do the greedy preds — the decode merge
     path emits them instead of re-running argmax over the logits."""
-    exit_mask, pred, conf = dispatch.exit_decision_op(exit_logits, c_thr,
-                                                      backend=backend)
+    exit_mask, pred, conf = dispatch.exit_decision_op(
+        exit_logits, c_thr, backend=backend, mesh=mesh)
     b = hidden.shape[0]
-    slab, pos, n_hard = dispatch.gather_compact_op(hidden, ~exit_mask, b,
-                                                   backend=backend)
+    slab, pos, n_hard = dispatch.gather_compact_op(
+        hidden, ~exit_mask, b, backend=backend, mesh=mesh)
     slab_ids = jnp.where(pos >= 0,
                          jnp.take(sample_ids, jnp.maximum(pos, 0)), -1)
     return slab, slab_ids, n_hard, exit_mask, pred, conf
@@ -325,7 +326,7 @@ class TwoStageServer(_RingedServer):
         else:
             slab, slab_ids, n_hard_dev, exit_mask, _, conf = _decide_compact(
                 hidden, exit_logits, ids_dev, self.c_thr,
-                backend=dispatch.kernel_backend())
+                backend=dispatch.kernel_backend(), mesh=self.ex1.mesh)
             if self.conf_sink is not None:    # rides the n_hard sync
                 n_hard_dev, conf_np = jax.device_get((n_hard_dev, conf))
                 self.conf_sink.extend(conf_np)
@@ -575,7 +576,9 @@ class DecodeFns(NamedTuple):
     batch position (sync) or a per-row (B,) vector (continuous pool).
     ``s1_raw`` is the un-jitted stage-1 body: the continuous pool tick
     inlines it inside its own jitted step (masked cache select around it),
-    which a donating jit wrapper would get in the way of."""
+    which a donating jit wrapper would get in the way of. A
+    ``jax.tree_util.Partial`` binding the stage-1 params keeps them tick
+    ARGUMENTS rather than constants baked into the tick program."""
     prefill: Callable   # (tokens (B,S), max_len static) -> (logits, caches)
     split: Callable     # caches -> (stage1_caches, stage2_cache_rows)
     s1: Callable        # (tok (B,1), c1, step) -> (h (B,d), c1', exit_logits)
@@ -596,14 +599,19 @@ def decode_stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
                      page_size: Optional[int] = None) -> DecodeFns:
     """Jitted decode callables with per-stage residency: the one-shot
     full-depth prefill (and its cache split) runs on ex1 with the full
-    param tree, per-step stage 1 closes over the stage-1 slice on ex1, and
-    the bucketed stage-2 decode closes over the stage-2 slice on ex2.
-    Degenerate placement = everything on the default device, the same
-    programs as before."""
+    param tree, per-step stage 1 takes the same tree on ex1, and the
+    bucketed stage-2 decode takes the stage-2 slice on ex2. Degenerate
+    placement = everything on the default device, the same programs as
+    before.
+
+    Params enter every program as an ARGUMENT (bound with
+    ``functools.partial`` / ``jax.tree_util.Partial``), never as a closure:
+    a jit that closes over an array embeds it in the program as a constant,
+    which at published widths is gigabytes of host memory per program."""
     pl = placement or StagePlacement.single_device()
     # ex1 holds the FULL tree (the one-shot prefill needs every layer);
-    # per-step stage 1 closes over the same placed tree rather than a
-    # second stage-1 slice, so stage-1 params are resident once, not twice.
+    # per-step stage 1 takes the same placed tree rather than a second
+    # stage-1 slice, so stage-1 params are resident once, not twice.
     # The stage-2 slice is only cut (a copy of its superblock leaves) when
     # there is a second submesh to put it on.
     presliced = pl.disaggregated
@@ -615,8 +623,8 @@ def decode_stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
         p2 = params
 
     @functools.partial(jax.jit, static_argnames=("max_len",))
-    def pf(tokens, max_len: int):
-        logits, caches, _ = T.prefill(p_full["backbone"], cfg, tokens,
+    def pf(p, tokens, max_len: int):
+        logits, caches, _ = T.prefill(p["backbone"], cfg, tokens,
                                       max_len=max_len)
         return logits, caches
 
@@ -625,31 +633,36 @@ def decode_stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
         c1, c2 = ee.split_caches(cfg, spec, caches)
         return c1, cache_rows_of(c2)
 
-    def s1_raw(tok, c1, step):
-        h, nc1, exit_logits = ee.stage1_decode(p_full, cfg, spec, tok, c1,
-                                               step)
+    def s1_body(p, tok, c1, step):
+        h, nc1, exit_logits = ee.stage1_decode(p, cfg, spec, tok, c1, step)
         return h[:, 0], nc1, exit_logits
 
-    s1 = functools.partial(jax.jit, donate_argnums=(1,))(s1_raw)
+    s1 = jax.jit(s1_body, donate_argnums=(2,))
 
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def s2(h_rows, cache_rows, step):
-        logits, nc = ee.stage2_decode(p2, cfg, spec, h_rows[:, None],
+    @functools.partial(jax.jit, donate_argnums=(2,))
+    def s2(p, h_rows, cache_rows, step):
+        logits, nc = ee.stage2_decode(p, cfg, spec, h_rows[:, None],
                                       cache_of_rows(cache_rows), step,
                                       presliced_params=presliced)
         return logits, cache_rows_of(nc)
 
+    pf = functools.partial(pf, p_full)
+    s1 = functools.partial(s1, p_full)
+    s2 = functools.partial(s2, p2)
+    s1_raw = jax.tree_util.Partial(s1_body, p_full)
     if page_size is None:
         return DecodeFns(pf, split, s1, s2, s1_raw)
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
 
-    @functools.partial(jax.jit, donate_argnums=(3,))
-    def s2_paged(h_rows, bt, step, pool):
-        logits, nc = ee.stage2_decode(p2, cfg, spec, h_rows[:, None],
+    @functools.partial(jax.jit, donate_argnums=(4,))
+    def s2_paged(p, h_rows, bt, step, pool):
+        logits, nc = ee.stage2_decode(p, cfg, spec, h_rows[:, None],
                                       _inject_bt(pool, bt), step,
                                       presliced_params=presliced)
         return logits, _strip_bt(nc)
+
+    s2_paged = functools.partial(s2_paged, p2)
 
     def pool_init(rows, n_pages: int):
         return paged_seg_pool(rows, page_size, n_pages)
@@ -792,7 +805,7 @@ class DecodeServer(_RingedServer):
         else:
             slab, slab_ids, n_hard_dev, _, pred, conf = _decide_compact(
                 h_rows, exit_logits, self._ids, self.c_thr,
-                backend=dispatch.kernel_backend())
+                backend=dispatch.kernel_backend(), mesh=self.ex1.mesh)
             if self.conf_sink is not None:   # rides the n_hard sync
                 n_hard_dev, conf_np = jax.device_get((n_hard_dev, conf))
                 self.conf_sink.extend(conf_np)
@@ -950,10 +963,10 @@ class HostLoopDecoder:
 
 def _stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
                placement: Optional[StagePlacement] = None):
-    """Per-stage jitted prefill callables. Disaggregated: each closes over
+    """Per-stage jitted prefill callables. Disaggregated: each is bound to
     ITS stage's param slice placed on its executor (ee.split_params) —
     stage-1 layers + exit head resident on ex1, stage-2 layers + final
-    head on ex2. Degenerate: both close over the caller's full tree
+    head on ex2. Degenerate: both are bound to the caller's full tree
     (slicing would COPY the superblock leaves for no placement benefit);
     the sliced and full-tree programs are bitwise-identical, which the
     disaggregation tests enforce."""
@@ -967,17 +980,19 @@ def _stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
         p1 = p2 = params
 
     @jax.jit
-    def s1(tokens):
-        h, _, logits, _ = ee.stage1_prefill(p1, cfg, spec, tokens)
+    def s1(p, tokens):
+        h, _, logits, _ = ee.stage1_prefill(p, cfg, spec, tokens)
         return h, logits
 
     @jax.jit
-    def s2(slab):
-        logits, _ = ee.stage2_prefill(p2, cfg, spec, slab,
+    def s2(p, slab):
+        logits, _ = ee.stage2_prefill(p, cfg, spec, slab,
                                       presliced_params=presliced)
         return logits
 
-    return s1, s2
+    # params are program arguments, not closure constants (see
+    # decode_stage_fns)
+    return functools.partial(s1, p1), functools.partial(s2, p2)
 
 
 # ---------------------------------------------------------------------------

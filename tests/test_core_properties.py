@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")  # optional dev dep; skip, not error, when absent
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import conditional as cond
 from repro.core import exit_decision as ed
@@ -98,6 +98,7 @@ def test_tap_pareto_and_monotone(pts):
 
 @SET
 @given(tap_strategy, tap_strategy, st.floats(0.05, 1.0))
+@example([(1.0, 1.0)], [(1.0, 1.0)], 1.0)
 def test_combine_eq1_invariants(pts1, pts2, p):
     f = TAPFunction([DesignPoint(resources=(r,), throughput=t)
                      for r, t in pts1], "f")
@@ -119,8 +120,9 @@ def test_combine_eq1_invariants(pts1, pts2, p):
                 assert min(a.throughput, b.throughput / p) <= \
                     d.design_throughput + 1e-9
     # (4) Fig. 4 robustness ordering: q < p cannot hurt, q > p cannot help
-    band = robustness_band(d, [max(p - 0.05, 1e-3), p, min(p + 0.05, 1.0)])
-    vals = list(band.values())
+    qs = [max(p - 0.05, 1e-3), p, min(p + 0.05, 1.0)]
+    band = robustness_band(d, qs)
+    vals = [band[q] for q in qs]          # p = 1.0 repeats a q (dict key)
     assert vals[0] >= vals[1] - 1e-9 >= vals[2] - 2e-9
     # (5) throughput at q never exceeds the stage-1 rate (hard ceiling)
     for q in (0.01, p, 1.0):

@@ -11,18 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat as _compat
 from repro.models.layers import blocked_attention, flash_attention_diff
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# jax < 0.5 only ships jax.experimental.shard_map, whose transpose rule
-# raises _SpecError on the grad-through-shard_map paths below (upstream
-# limitation; the forward paths work through repro.compat.shard_map).
-_xfail_old_shard_map = pytest.mark.xfail(
-    _compat._CHECK_KW == "check_rep",
-    reason="grad through jax.experimental.shard_map (jax<0.5) hits an "
-    "upstream transpose _SpecError", strict=False)
 
 
 def _run(src: str) -> str:
@@ -89,10 +80,11 @@ def test_flash_vjp_q_offset_grad():
 def test_sp_attention_exact_on_mesh():
     out = _run("""
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.models import hints
     from repro.models.layers import blocked_attention
     from repro.models.attention import attention_core
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     k = jax.random.PRNGKey(0)
     B, S, H, KH, D = 4, 256, 6, 2, 32
     q = jax.random.normal(k, (B, S, H, D), jnp.float32)
@@ -115,17 +107,17 @@ def test_sp_attention_exact_on_mesh():
     assert float(vals["GRAD"]) < 1e-4
 
 
-@_xfail_old_shard_map
 def test_vocab_parallel_ce_on_mesh():
     out = _run("""
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.models import hints
     from repro.core import losses
     from repro.models.config import ArchConfig
     cfg = ArchConfig(name="t", family="dense", n_layers=1, d_model=32,
                      n_heads=2, n_kv_heads=2, d_ff=64, vocab=64,
                      dtype="float32", param_dtype="float32")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     k = jax.random.PRNGKey(0)
     bb = {"embed": {"table": jax.random.normal(k, (64, 32)) * 0.1}}
     hidden = jax.random.normal(jax.random.fold_in(k, 1), (4, 24, 32))
@@ -147,13 +139,13 @@ def test_vocab_parallel_ce_on_mesh():
     assert float(vals["GRAD"]) < 1e-5
 
 
-@_xfail_old_shard_map
 def test_train_step_on_mesh_matches_single_device():
     """One EE train step on the 8-device mesh (SP attention + VP loss + TP
     shardings active) must match the same step on one device bit-for-bit
     within fp tolerance."""
     out = _run("""
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.core import early_exit as ee, losses
     from repro.models import hints
     from repro.models.config import ArchConfig
@@ -173,7 +165,7 @@ def test_train_step_on_mesh_matches_single_device():
 
     l_single = float(loss_fn(params))
     g_single = jax.grad(loss_fn)(params)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     hints.set_mesh(mesh)
     with mesh:
         l_mesh = float(jax.jit(loss_fn)(params))

@@ -229,9 +229,14 @@ def test_kernel_backend_memoized_and_invalidated(clean_backend):
 
 
 def test_kernel_backend_pallas_degrades_off_tpu(clean_backend):
+    """An explicit 'pallas' request off-TPU raises instead of quietly
+    running the interpreter; the interpreter is asked for by name."""
     clean_backend.setattr(dispatch, "_on_tpu", lambda: False)
     dispatch.set_backend(None)
-    assert dispatch.kernel_backend("pallas") == "interpret"
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        dispatch.kernel_backend("pallas")
+    assert dispatch.kernel_backend("interpret") == "interpret"
+    assert dispatch.kernel_body_backend() == "interpret"
 
 
 def test_kernel_backend_rejects_unknown(clean_backend):
@@ -414,20 +419,38 @@ def test_fused_tick_off_for_disaggregated_placement():
 
 
 def test_fused_tick_falls_back_when_fns_resist_eval_shape():
-    """Duck-typed stage fns that cannot be abstractly evaluated must keep
-    the composed tick rather than fail at pool build."""
+    """Duck-typed host-side stage fns that cannot be abstractly evaluated
+    (they pull their inputs to the host) must keep the composed tick
+    rather than fail at pool build."""
     fns = _toy_decode_fns(0)
 
     def opaque_s1(tok, c1, pos):
-        raise TypeError("host-side stage fn: no abstract evaluation")
+        return np.asarray(tok)          # host-side: no abstract evaluation
 
     hacked = SL.DecodeFns(fns.prefill, fns.split, fns.s1, fns.s2, opaque_s1)
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
     sched = ContinuousScheduler(hacked, sc, n_slots=2, max_len=_TOY_S + 4,
                                 clock=LogicalClock())
-    tok = jnp.zeros((2, 1), jnp.int32)
     c1 = {"first": [jnp.zeros((2, 1), jnp.int32)], "blocks": (), "rem": []}
     rows = {"sid": jnp.zeros((2, 1), jnp.int32)}
     sched._ensure_pool(c1, rows)
     assert sched._ring_row_spec is None
     assert not sched._use_fused()
+
+
+def test_fused_tick_eval_shape_failure_on_traceable_fns_raises():
+    """A stage-1 body that traces but fails (a real model bug) must raise
+    at pool build, never drop silently to the composed tick."""
+    fns = _toy_decode_fns(0)
+
+    def broken_s1(tok, c1, pos):
+        return tok @ jnp.zeros((3, 3), tok.dtype)   # shape mismatch
+
+    broken = SL.DecodeFns(fns.prefill, fns.split, fns.s1, fns.s2, broken_s1)
+    sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
+    sched = ContinuousScheduler(broken, sc, n_slots=2, max_len=_TOY_S + 4,
+                                clock=LogicalClock())
+    c1 = {"first": [jnp.zeros((2, 1), jnp.int32)], "blocks": (), "rem": []}
+    rows = {"sid": jnp.zeros((2, 1), jnp.int32)}
+    with pytest.raises(TypeError):
+        sched._ensure_pool(c1, rows)

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 pytest.importorskip("hypothesis")  # optional dev dep; skip, not error, when absent
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.core import dse, perf_model as pm, profiler as prof
 from repro.core.stage_mesh import stage2_capacity
@@ -64,6 +64,7 @@ def test_make_test_set_with_q_exact():
 @SET
 @given(st.lists(st.floats(10, 1e5), min_size=2, max_size=8),
        st.integers(4, 512))
+@example([10.0, 10.0, 10.0, 30.0], 4)     # the one-unit floor overshoots
 def test_optimal_folding_within_budget(workloads, budget):
     alloc = pm.optimal_folding(workloads, budget)
     assert sum(alloc) <= max(budget, len(workloads))

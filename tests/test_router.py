@@ -266,7 +266,7 @@ def test_fleet_stats_schema():
 # ---------------------------------------------------------------------------
 
 try:
-    from hypothesis import given, settings, strategies as st_h
+    from hypothesis import example, given, settings, strategies as st_h
     _HAVE_HYP = True
 except ImportError:                                   # pragma: no cover
     _HAVE_HYP = False
@@ -292,6 +292,12 @@ if _HAVE_HYP:
         pre_steps=st_h.integers(0, 4),
         degrade=st_h.booleans(),
     )
+    # one slot and a one-deep queue: a prefill-only request admitted and
+    # finished within one replica step is progress, not a wedged fleet
+    @example(n_tokens_list=[3, 1, 1], policy="round_robin", n_replicas=1,
+             n_slots=1, max_queue=1, q_pcts=[0, 0, 0], tenant_picks=[0] * 12,
+             slo_picks=["gold"] * 12, arrival_gaps=[0.0] * 12, pre_steps=0,
+             degrade=False)
     def test_fleet_invariants_random(n_tokens_list, policy, n_replicas,
                                      n_slots, max_queue, q_pcts,
                                      tenant_picks, slo_picks, arrival_gaps,

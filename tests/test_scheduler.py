@@ -8,6 +8,7 @@ when the host exposes 8 devices, subprocess on every tier-1 run)."""
 import subprocess
 import sys
 import textwrap
+import warnings
 from pathlib import Path
 
 import jax
@@ -164,6 +165,43 @@ def test_continuous_admission_gating(tiny_cfg, tiny_params, prompt, fns):
     assert sched.stats.n_finished == 4
     # the late arrivals can't have finished before they arrived
     assert all(lat >= 0.0 for lat in sched.stats.latencies)
+
+
+def test_serving_programs_take_params_as_arguments(tiny_cfg, tiny_params,
+                                                   tiny_spec, prompt):
+    """The params enter every serving program — prefill, the pool tick,
+    the paged stage-2 bucket, the host-loop oracle, the prefill server —
+    as ARGUMENTS. A jit that closes over them embeds them as constants,
+    which at published widths is gigabytes of host memory per program."""
+    from repro.runtime import serve_api
+    n_bytes = sum(x.nbytes for x in jax.tree.leaves(tiny_params))
+    n_new = 8
+    max_len = prompt.shape[1] + n_new
+    c_thr = float(np.median(_decode_conf(tiny_cfg, tiny_params, tiny_spec,
+                                         prompt, max_len)))
+    sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=c_thr)
+    build = lambda **kw: serve_api.build(tiny_params, tiny_cfg, tiny_spec,
+                                         sc, **kw)
+    before = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", n_bytes // 4)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings(
+                "error", message="A large amount of constants")
+            sched = build(mode="decode", scheduler="continuous", n_slots=4,
+                          max_len=max_len, page_size=4, clock=LogicalClock())
+            for i, p in enumerate(prompt):
+                sched.submit(Request(i, p, n_tokens=n_new))
+            res = sched.run()
+            assert 0 < sched.stats.n_exited < sched.stats.n_decisions
+            sched.tick_hlo()
+            oracle = build(mode="decode", scheduler=None,
+                           host=True).generate(prompt, n_new)
+            SL.serve_dataset(build(mode="prefill", scheduler=None), prompt,
+                             batch=4)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", before)
+    assert res == _expect_streams(oracle["tokens"], [n_new] * len(prompt))
 
 
 def test_continuous_rejects_overlong_and_duplicate(tiny_cfg, tiny_params,

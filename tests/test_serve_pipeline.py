@@ -32,7 +32,8 @@ def _hermetic_backend_env(monkeypatch):
 def test_backend_resolution_off_tpu(monkeypatch):
     monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
     assert dispatch.kernel_backend() == "ref"        # auto on CPU
-    assert dispatch.kernel_backend("pallas") == "interpret"
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        dispatch.kernel_backend("pallas")
     assert dispatch.kernel_backend("ref") == "ref"
     with pytest.raises(ValueError):
         dispatch.kernel_backend("vulkan")
