@@ -1,0 +1,74 @@
+"""The trace reduction, on hand-made event lists and on a small recorded
+trace (``data/trace_q2off.json.gz``: the first 0.3 s of the lists
+``tracing.extract`` kept from a traced ``qwen2-1.5b.offline.p25`` run on
+one TPU v5e, every event clipped to that span).
+
+    JAX_PLATFORMS=cpu python3 -m pytest -q benchmarks/chip/tests
+"""
+from __future__ import annotations
+
+import gzip
+import json
+import sys
+from pathlib import Path
+
+import pytest
+
+CHIP = Path(__file__).resolve().parents[1]
+sys.path[:0] = [str(CHIP)]
+
+from chipbench import tracing  # noqa: E402
+from chipbench.tracing import Reduced  # noqa: E402
+
+RECORDED = Path(__file__).resolve().parent / "data" / "trace_q2off.json.gz"
+
+
+def test_hand_made_trace():
+    tr = {"window": [0, 100],
+          "chips": [{"ops": [[10, 20, "%fusion.1 = f32[8] fusion(..)"],
+                             [15, 30, "%paged_gather_append_kernel.3"],
+                             [50, 60, "%while.2"], [52, 58, "%copy.9"],
+                             [90, 130, "%fusion.7"]],
+                     "modules": [[5, 35, "jit__pool_tick_fused(12)"],
+                                 [45, 100, "jit_s2_paged(3)"]]}],
+          "host": [[0, 100, "bench_step"], [30, 50, "finish_tick_sync"]]}
+    r = Reduced.of(tr)
+    assert r.window_s == pytest.approx(100e-9)
+    # union: [10, 30] + [50, 60] + [90, 100] (clipped to the window)
+    assert r.busy_s == pytest.approx(40e-9)
+    assert r.ops_matching("paged_gather_append_kernel") == (
+        pytest.approx(15e-9), 1)
+    assert "while" not in r.op_s                 # containers not ranked
+    assert r.op_s["copy"] == pytest.approx(6e-9)
+    assert r.modules_matching("pool_tick") == (pytest.approx(30e-9), 1)
+    assert r.modules_matching("s2_paged") == (pytest.approx(55e-9), 1)
+    # gaps [0,10], [30,50], [60,90]: the innermost covering span names each
+    assert r.idle_by_host["finish_tick_sync"] == pytest.approx(20e-9)
+    assert r.idle_by_host["bench_step"] == pytest.approx(40e-9)
+    assert sum(r.idle_by_host.values()) == pytest.approx(
+        r.window_s - r.busy_s)
+    b = r.breakdown()
+    assert b["device_ops"][0][0] == \
+        "_pool_tick_fused:paged_gather_append_kernel"
+    assert len(b["device_ops"]) <= 10 and len(b["idle_gaps"]) <= 10
+
+
+def test_op_and_module_names():
+    assert tracing.op_name("%fusion.12 = (bf16[2]) fusion(x)") == "fusion"
+    assert tracing.op_name("%broadcast_select_fusion = (bf16[1]) f") == \
+        "broadcast_select_fusion"
+    assert tracing.module_name("jit__pool_tick_fused(123)") == \
+        "jit__pool_tick_fused"
+
+
+@pytest.mark.skipif(not RECORDED.exists(), reason="no recorded trace")
+def test_recorded_trace():
+    with gzip.open(RECORDED, "rt") as f:
+        tr = json.load(f)
+    r = Reduced.of(tr)
+    assert 0 < r.busy_s <= r.window_s
+    assert sum(r.idle_by_host.values()) == pytest.approx(
+        r.window_s - r.busy_s, rel=1e-6)
+    assert r.modules_matching("pool_tick")[1] >= 1
+    t, n = r.ops_matching("exit_decision")
+    assert n >= 1 and t > 0
