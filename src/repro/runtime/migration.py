@@ -152,7 +152,7 @@ class LiveMigrator:
                     f"ring still holds {s.ring.count} rows after "
                     f"{self.plan.quiesce_timeout_s:.1f}s — cannot reach a "
                     f"shape-change-safe point")
-            faults.retry(s._dispatch_bucket, what="quiesce-drain")
+            faults.retry(s._dispatch_bucket, "forced", what="quiesce-drain")
         while s._pending:
             s._harvest_one()
         assert not any(st == _PARKED for st in s._state), \

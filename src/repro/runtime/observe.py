@@ -13,7 +13,8 @@ Three layers, all zero-cost when unused:
 2. **Metrics export** — :class:`MetricsRegistry` with a FROZEN name+label
    schema (:data:`METRICS_SCHEMA`, key set locked in tests like the
    ServeStats v3 dict), fed by :class:`StatsSampler` over ``ServeStats`` /
-   ``FleetStats`` plus kernel-backend resolution and jit-cache counters.
+   ``FleetStats`` plus kernel-backend resolutions and backend compiles
+   by program (one ``jax.monitoring`` listener).
    Prometheus text exposition via :func:`MetricsRegistry.exposition`, a
    zero-dependency stdlib HTTP endpoint (:class:`MetricsServer`) and a
    one-shot :func:`dump_metrics` file mode.
@@ -22,9 +23,12 @@ Three layers, all zero-cost when unused:
    ``jax.profiler.TraceAnnotation`` (only while a :class:`ProfileWindow`
    is active; a shared nullcontext otherwise), and :class:`ProfileWindow`
    opens an opt-in ``jax.profiler`` trace capture for N scheduler ticks
-   so TPU runs produce attributable xprof timelines. The jitted bodies
-   themselves carry ``jax.named_scope`` labels (trace-time metadata,
-   zero runtime cost).
+   so TPU runs produce attributable xprof timelines. While a window is
+   open each annotated section is also a row of the window's
+   :class:`WindowRecord` (:func:`last_window`), which maps onto the trace
+   through its ``profile_window`` anchor span and is written beside the
+   trace as ``program_spans.jsonl``. The jitted bodies themselves carry
+   ``jax.named_scope`` labels (trace-time metadata, zero runtime cost).
 
 The tracing layer never touches device values: it rides the host-side
 event feed the scheduler already maintains, so token streams are bitwise
@@ -36,15 +40,18 @@ from __future__ import annotations
 
 import contextlib
 import json
+import os
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
+
+import jax
 
 __all__ = [
     "Tracer", "MetricsRegistry", "StatsSampler", "MetricsServer",
     "ProfileWindow", "METRICS_SCHEMA", "annotate", "profiling_active",
     "parse_exposition", "dump_metrics", "export_events_jsonl",
-    "jit_cache_entries",
+    "WindowRecord", "last_window", "backend_compiles",
 ]
 
 
@@ -448,11 +455,9 @@ METRICS_SCHEMA: Tuple[Tuple[str, str, Tuple[str, ...], str], ...] = (
      "Router-level pending requests"),
     ("repro_backend_resolutions_total", "c", (),
      "kernel_backend() memo misses (fresh resolutions)"),
-    ("repro_jit_cache_entries", "g", (),
-     "Compiled-executable cache entries across serving jits (retrace "
-     "counter)"),
-    ("repro_scrapes_total", "c", (),
-     "Metrics exposition renders (HTTP scrapes + dumps)"),
+    ("repro_backend_compiles_total", "c", ("program",),
+     "Backend compiles by jitted program (a climbing count after warm-up "
+     "is a retrace)"),
     ("repro_request_latency_seconds", "h", ("replica",),
      "Submit-to-finish latency (scheduler clock)"),
 )
@@ -581,7 +586,6 @@ class MetricsRegistry:
 
     def exposition(self) -> str:
         with self._lock:
-            self.get("repro_scrapes_total").inc()
             lines: List[str] = []
             for m in self._metrics.values():
                 lines.extend(m.render())
@@ -614,7 +618,7 @@ def parse_exposition(text: str) -> Dict[str, float]:
 
 
 # ---------------------------------------------------------------------------
-# Cadence sampler over ServeStats / FleetStats + jit counters
+# Cadence sampler over ServeStats / FleetStats + compile counters
 # ---------------------------------------------------------------------------
 
 class StatsSampler:
@@ -699,7 +703,9 @@ class StatsSampler:
         from repro.kernels import dispatch as _dispatch
         reg.get("repro_backend_resolutions_total").set_total(
             _dispatch.n_backend_resolutions())
-        reg.get("repro_jit_cache_entries").set(jit_cache_entries())
+        compiles = reg.get("repro_backend_compiles_total")
+        for prog, (n, _secs) in backend_compiles().items():
+            compiles.set_total(n, program=prog)
 
     def _sample_sched(self, reg, sched, rep) -> None:
         st = getattr(sched, "stats", None)
@@ -760,35 +766,6 @@ class StatsSampler:
             for v in list(lat)[-min(new, len(lat)):]:
                 hist.observe(v, replica=rep)
             seen[key] = st.n_finished
-
-
-def jit_cache_entries() -> int:
-    """Total compiled-executable cache entries across the serving jits —
-    the retrace/recompile counter (same ``_cache_size`` the tier-1 tests
-    assert single-launch ticks with). Best-effort: jits without the
-    private API count as 0."""
-    total = 0
-    try:
-        from repro.runtime import scheduler as _sched
-        from repro.kernels import dispatch as _dispatch
-        fns = [getattr(_sched, n, None) for n in
-               ("_pool_tick", "_pool_tick_fused", "_admit_stage1",
-                "_unpark_lanes", "_ring_enqueue_range", "ring_drain")]
-        fns += [getattr(_dispatch, n, None) for n in
-                ("_exit_decision", "_gather_compact",
-                 "_fused_dispatch_donated", "_fused_dispatch_copy",
-                 "_paged_gather_append_donated",
-                 "_paged_gather_append_copy")]
-        for fn in fns:
-            size = getattr(fn, "_cache_size", None)
-            if callable(size):
-                try:
-                    total += int(size())
-                except Exception:
-                    pass
-    except Exception:
-        return 0
-    return total
 
 
 # ---------------------------------------------------------------------------
@@ -878,53 +855,226 @@ def dump_metrics(registry: MetricsRegistry, path: str,
 
 
 # ---------------------------------------------------------------------------
-# Layer 3: profiler hooks
+# Layer 3: profiler hooks + the window record + compiles by program
 # ---------------------------------------------------------------------------
 
-_PROFILING = False
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+WINDOW_ANCHOR = "profile_window"
+SPANS_FILE = "program_spans.jsonl"
+
+
+class WindowRecord:
+    """What the program's :func:`annotate` spans and backend compiles
+    captured while one :class:`ProfileWindow` was open, stamped with
+    ``time.perf_counter_ns()``.
+
+    ``spans`` rows are ``[name, t0_ns, t1_ns, parent, args]`` in the order
+    the spans opened; ``parent`` is the row index of the enclosing open
+    span (-1 at the top) and ``t1_ns`` is None while a span is open. Row
+    0 is the window's anchor span, ``profile_window``, which is also a
+    ``TraceAnnotation``: a span's offset from the anchor is its offset on
+    the profiler trace's clock. ``compiles`` holds one
+    ``(program, seconds, t_ns)`` per backend compile in the window."""
+
+    def __init__(self):
+        self.spans: List[list] = []
+        self.compiles: List[tuple] = []
+        self.end_ns: Optional[int] = None     # set when the window stops
+        self._open: List[int] = []            # rows still open, innermost last
+
+    def open(self, name: str, args: dict) -> int:
+        i = len(self.spans)
+        self.spans.append([name, time.perf_counter_ns(), None,
+                           self._open[-1] if self._open else -1, args])
+        self._open.append(i)
+        return i
+
+    def close(self, i: int) -> None:
+        self.spans[i][2] = time.perf_counter_ns()
+        if self._open[-1] == i:
+            self._open.pop()
+        else:                 # the anchor, closed by a stop inside a step
+            self._open.remove(i)
+
+    def _t1(self, row) -> int:
+        """A span's end, clipped to the window's: a span still open when
+        the window stopped counts up to the stop."""
+        t1 = row[2]
+        if self.end_ns is not None and (t1 is None or t1 > self.end_ns):
+            return self.end_ns
+        return t1 if t1 is not None else time.perf_counter_ns()
+
+    def total_s(self, name: str) -> float:
+        """Summed seconds of every span named ``name``."""
+        return 1e-9 * sum(max(self._t1(r) - r[1], 0)
+                          for r in self.spans if r[0] == name)
+
+    def self_s(self, name: str, exclude: Tuple[str, ...] = ()) -> float:
+        """Summed seconds of the spans named ``name``, each less the part
+        of it that its descendants named in ``exclude`` cover."""
+        rows, spans = {}, self.spans
+        for i, r in enumerate(spans):
+            if r[0] == name:
+                rows[i] = []
+        if not rows:
+            return 0.0
+        if exclude:
+            for r in spans:
+                if r[0] not in exclude:
+                    continue
+                p = r[3]
+                while p >= 0 and p not in rows:
+                    p = spans[p][3]
+                if p >= 0:
+                    rows[p].append((r[1], self._t1(r)))
+        total = 0
+        for i, kids in rows.items():
+            t0, t1 = spans[i][1], self._t1(spans[i])
+            covered, hi = 0, t0
+            for s, e in sorted(kids):
+                s, e = max(s, hi), min(e, t1)
+                if e > s:
+                    covered += e - s
+                    hi = e
+            total += max(t1 - t0 - covered, 0)
+        return 1e-9 * total
+
+    def count(self, name: str, **args) -> int:
+        """Spans named ``name`` whose arguments include ``args``."""
+        return sum(1 for r in self.spans if r[0] == name
+                   and all(r[4].get(k) == v for k, v in args.items()))
+
+    def export_jsonl(self, path: str) -> int:
+        """The record in ``Tracer.export_jsonl``'s line shape: spans, then
+        compiles as instants. Times are seconds from the anchor's start
+        (the trace's clock); ``args`` carry the span's ``id`` and its
+        ``parent`` id."""
+        base = self.spans[0][1] if self.spans else 0
+        n = 0
+        with open(path, "w") as f:
+            for i, r in enumerate(self.spans):
+                rec = {"kind": "span", "name": r[0], "sid": None,
+                       "replica": 0, "t0": 1e-9 * (r[1] - base),
+                       "t1": 1e-9 * (self._t1(r) - base),
+                       "args": {"id": i, "parent": r[3], **r[4]}}
+                f.write(json.dumps(rec, default=str) + "\n")
+                n += 1
+            for prog, secs, t in self.compiles:
+                rec = {"kind": "instant", "name": "compile", "sid": None,
+                       "replica": 0, "t": 1e-9 * (t - base),
+                       "tid": _TID_SCHED,
+                       "args": {"program": prog, "seconds": secs}}
+                f.write(json.dumps(rec) + "\n")
+                n += 1
+        return n
+
+
+_WINDOW: Optional[WindowRecord] = None    # the open window's record
+_LAST = WindowRecord()                    # the most recent window's record
 _NULL_CTX = contextlib.nullcontext()
 
 
 def profiling_active() -> bool:
-    return _PROFILING
+    return _WINDOW is not None
 
 
-def annotate(name: str):
+def last_window() -> WindowRecord:
+    """The record of the most recent :class:`ProfileWindow`, open or
+    closed (an empty record before the first)."""
+    return _LAST
+
+
+class _Span:
+    """One recorded section: a ``TraceAnnotation`` plus a record row."""
+    __slots__ = ("rec", "name", "args", "ann", "row")
+
+    def __init__(self, rec: WindowRecord, name: str, args: dict):
+        self.rec, self.name, self.args = rec, name, args
+
+    def __enter__(self):
+        self.ann = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self.ann.__enter__()
+        self.row = self.rec.open(self.name, self.args)
+        return self
+
+    def __exit__(self, *exc):
+        self.rec.close(self.row)
+        self.ann.__exit__(*exc)
+
+
+def annotate(name: str, **args):
     """Host-side profiler annotation for a hot section.
 
     A shared nullcontext unless a :class:`ProfileWindow` is open, so the
     steady-state tick pays one global load + one compare. Inside a
-    window it becomes ``jax.profiler.TraceAnnotation`` and the section
-    shows up on the xprof host timeline."""
-    if not _PROFILING:
+    window it becomes ``jax.profiler.TraceAnnotation(name, **args)``, so
+    the section shows up on the xprof host timeline, and a row of the
+    window's :class:`WindowRecord`."""
+    w = _WINDOW
+    if w is None:
         return _NULL_CTX
-    import jax
-    return jax.profiler.TraceAnnotation(name)
+    return _Span(w, name, args)
+
+
+_COMPILES: Dict[str, List] = {}           # program -> [count, seconds]
+
+
+def _program_name(fun_name: str) -> str:
+    """``jit(pf)`` -> ``pf``: the jitted function's own name."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    if event != COMPILE_EVENT:
+        return
+    prog = _program_name(str(kw.get("fun_name", "?")))
+    c = _COMPILES.setdefault(prog, [0, 0.0])
+    c[0] += 1
+    c[1] += secs
+    w = _WINDOW
+    if w is not None:
+        w.compiles.append((prog, secs, time.perf_counter_ns()))
+
+
+def backend_compiles() -> Dict[str, Tuple[int, float]]:
+    """Backend compiles since this module loaded, by program: (count,
+    seconds). A climbing count after warm-up is a retrace."""
+    return {k: (v[0], v[1]) for k, v in _COMPILES.items()}
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 class ProfileWindow:
     """Opt-in ``jax.profiler`` capture window (``--profile-dir``).
 
-    Starts a trace into ``logdir`` on ``__enter__``; stops after
-    ``n_ticks`` scheduler ticks when given an event feed (counted on
-    ``tick`` events), or at ``__exit__`` otherwise. While open,
-    :func:`annotate` sections are live."""
+    Starts a trace into ``logdir`` on ``__enter__`` and opens the anchor
+    span ``profile_window``; stops after ``n_ticks`` scheduler ticks when
+    given an event feed (counted on ``tick`` events), or at ``__exit__``
+    otherwise. While open, :func:`annotate` sections are live and land in
+    ``self.record`` (also :func:`last_window`); on stop the record is
+    written to ``logdir/program_spans.jsonl``."""
 
     def __init__(self, logdir: str, n_ticks: Optional[int] = None,
                  events=None):
         self.logdir = logdir
         self.n_ticks = n_ticks
         self.events = events
+        self.record: Optional[WindowRecord] = None
         self._ticks = 0
         self._active = False
+        self._anchor = None
         self._cb = None
 
     def __enter__(self):
-        global _PROFILING
-        import jax
+        global _WINDOW, _LAST
         jax.profiler.start_trace(self.logdir)
         self._active = True
-        _PROFILING = True
+        self.record = _LAST = WindowRecord()
+        self._anchor = _Span(self.record, WINDOW_ANCHOR, {}).__enter__()
+        _WINDOW = self.record
         if self.events is not None and self.n_ticks is not None:
             def cb(ev):
                 if ev.get("event") == "tick":
@@ -935,13 +1085,15 @@ class ProfileWindow:
         return self
 
     def _stop(self) -> None:
-        global _PROFILING
+        global _WINDOW
         if not self._active:
             return
         self._active = False
-        _PROFILING = False
-        import jax
+        _WINDOW = None
+        self._anchor.__exit__(None, None, None)
+        self.record.end_ns = self.record.spans[0][2]
         jax.profiler.stop_trace()
+        self.record.export_jsonl(os.path.join(self.logdir, SPANS_FILE))
         if self._cb is not None and self.events is not None:
             try:
                 self.events.unsubscribe(self._cb)
@@ -951,3 +1103,4 @@ class ProfileWindow:
 
     def __exit__(self, *exc):
         self._stop()
+

@@ -1263,58 +1263,62 @@ class ContinuousScheduler:
         keeps backfill from serializing the pipeline."""
         prompts = np.stack([np.asarray(r.prompt, np.int32) for r in reqs])
         S = prompts.shape[1]
-        for r in reqs:
-            self.stats.n_samples += 1
-            self.stats.record_submit(r.sample_id, r.arrival_time)
-        logits0, caches = self.fns.prefill(
-            self.ex1.place_io(jnp.asarray(prompts)), self.max_len)
-        c1_rows, rows_rows = self.fns.split(caches)
-        self._ensure_pool(c1_rows, rows_rows)
-        slots = [self._free.pop() for _ in reqs]
-        slots_dev = jnp.asarray(slots, jnp.int32)
-        budgets = jnp.asarray([r.n_tokens for r in reqs], jnp.int32)
-        (self._c1, self._tok, self._pos, self._active_lane,
-         self._start_lane, self._budget_lane, tok0) = _admit_stage1(
-            self._c1, self._tok, self._pos, self._active_lane,
-            self._start_lane, self._budget_lane, logits0, c1_rows,
-            self.ex1.place_io(slots_dev), S, self.ex1.place_io(budgets))
-        if self._paged:
-            # alloc-on-admit: one block-table row per request (the page-
-            # budget admission check in _try_admit guarantees the free
-            # list covers the chunk), then ONE fused pool scatter moves
-            # the chunk's prefill cache rows into their pages
-            needs = []
-            for r, slot in zip(reqs, slots):
-                need = PageAllocator.pages_for(S + r.n_tokens - 1,
-                                               self.page_size)
-                needs.append(need)
-                self._slot_pages[slot] = need
-                self._slot_len[slot] = S
-            bt_rows = self.ex2.place_io(
-                self._alloc.alloc_many(needs, max_pages=self.max_pages))
-            self._pool = self.fns.admit_pages(
-                self._pool, self.ex2.place_io(rows_rows), bt_rows)
-            self._rows = _scatter_rows(self._rows, bt_rows,
-                                       self.ex2.place_io(slots_dev))
-        else:
-            self._rows = _scatter_rows(self._rows,
-                                       self.ex2.place_io(rows_rows),
-                                       self.ex2.place_io(slots_dev))
-        tok0_np = np.asarray(tok0)           # one admission sync per chunk
-        for j, (r, slot) in enumerate(zip(reqs, slots)):
-            self.results[r.sample_id] = [int(tok0_np[j])]
-            self._sid[slot] = r.sample_id
-            self._emitted[slot] = 1
-            self._budget[slot] = r.n_tokens
-            self._state[slot] = _ACTIVE
-            self._slot_hard[slot] = 0
-            self._slot_dec[slot] = 0
-            if self.events is not None:
-                self.events.emit("admit", sid=r.sample_id, slot=slot,
-                                 prompt_len=S)
-            if r.n_tokens == 1:              # prefill-only: free right away
-                self._finish_slot(slot)
-        self.peak_busy = max(self.peak_busy, self.n_slots - len(self._free))
+        with observe.annotate("admit_chunk", n=len(reqs), prompt_len=S):
+            for r in reqs:
+                self.stats.n_samples += 1
+                self.stats.record_submit(r.sample_id, r.arrival_time)
+            with observe.annotate("prefill"):
+                logits0, caches = self.fns.prefill(
+                    self.ex1.place_io(jnp.asarray(prompts)), self.max_len)
+                c1_rows, rows_rows = self.fns.split(caches)
+            self._ensure_pool(c1_rows, rows_rows)
+            slots = [self._free.pop() for _ in reqs]
+            slots_dev = jnp.asarray(slots, jnp.int32)
+            budgets = jnp.asarray([r.n_tokens for r in reqs], jnp.int32)
+            (self._c1, self._tok, self._pos, self._active_lane,
+             self._start_lane, self._budget_lane, tok0) = _admit_stage1(
+                self._c1, self._tok, self._pos, self._active_lane,
+                self._start_lane, self._budget_lane, logits0, c1_rows,
+                self.ex1.place_io(slots_dev), S, self.ex1.place_io(budgets))
+            if self._paged:
+                # alloc-on-admit: one block-table row per request (the page-
+                # budget admission check in _try_admit guarantees the free
+                # list covers the chunk), then ONE fused pool scatter moves
+                # the chunk's prefill cache rows into their pages
+                needs = []
+                for r, slot in zip(reqs, slots):
+                    need = PageAllocator.pages_for(S + r.n_tokens - 1,
+                                                   self.page_size)
+                    needs.append(need)
+                    self._slot_pages[slot] = need
+                    self._slot_len[slot] = S
+                bt_rows = self.ex2.place_io(
+                    self._alloc.alloc_many(needs, max_pages=self.max_pages))
+                self._pool = self.fns.admit_pages(
+                    self._pool, self.ex2.place_io(rows_rows), bt_rows)
+                self._rows = _scatter_rows(self._rows, bt_rows,
+                                           self.ex2.place_io(slots_dev))
+            else:
+                self._rows = _scatter_rows(self._rows,
+                                           self.ex2.place_io(rows_rows),
+                                           self.ex2.place_io(slots_dev))
+            with observe.annotate("admit_sync"):
+                tok0_np = np.asarray(tok0)       # one admission sync per chunk
+            for j, (r, slot) in enumerate(zip(reqs, slots)):
+                self.results[r.sample_id] = [int(tok0_np[j])]
+                self._sid[slot] = r.sample_id
+                self._emitted[slot] = 1
+                self._budget[slot] = r.n_tokens
+                self._state[slot] = _ACTIVE
+                self._slot_hard[slot] = 0
+                self._slot_dec[slot] = 0
+                if self.events is not None:
+                    self.events.emit("admit", sid=r.sample_id, slot=slot,
+                                     prompt_len=S)
+                if r.n_tokens == 1:          # prefill-only: free right away
+                    self._finish_slot(slot)
+            self.peak_busy = max(self.peak_busy,
+                                 self.n_slots - len(self._free))
 
     def _try_admit(self) -> int:
         """Admit admissible requests in arrival order, chunked to power-of-2
@@ -1403,7 +1407,10 @@ class ContinuousScheduler:
 
     # -- stage 2 dispatch ----------------------------------------------------
 
-    def _dispatch_bucket(self) -> None:
+    def _dispatch_bucket(self, reason: str) -> None:
+        """Pop one bucket off the ring and run stage 2 on it. ``reason``
+        names the drain rule that asked for it (``full``, ``eager``,
+        ``forced`` or ``backpressure``) on the dispatch span."""
         # the injection boundary sits BEFORE the pop — a retried dispatch
         # re-runs from an unmutated ring, so transient faults are safe to
         # absorb with faults.retry at every call site
@@ -1412,7 +1419,8 @@ class ContinuousScheduler:
         if popped is None:
             return
         bucket, ids, take = popped
-        with observe.annotate("stage2_bucket_dispatch"):
+        with observe.annotate("stage2_bucket_dispatch", reason=reason,
+                              take=take):
             if self._paged:
                 # paged stage 2: the bucket's "cache" lane carries block-
                 # table rows (page indices — the whole ring hop is index-
@@ -1470,13 +1478,14 @@ class ContinuousScheduler:
         # HarvestTimeout instead of blocking np.asarray forever — the
         # entries go back on the pending deque so a caller that survives
         # the error (or a later retry) still harvests every token
-        try:
-            bounded_wait(toks, self.sc.harvest_timeout_s,
-                         what=f"stage-2 bucket ({len(entries)} tokens)")
-        except HarvestTimeout:
-            self._pending.appendleft((entries, toks))
-            raise
-        toks_np = np.asarray(toks)
+        with observe.annotate("harvest_sync"):
+            try:
+                bounded_wait(toks, self.sc.harvest_timeout_s,
+                             what=f"stage-2 bucket ({len(entries)} tokens)")
+            except HarvestTimeout:
+                self._pending.appendleft((entries, toks))
+                raise
+            toks_np = np.asarray(toks)
         for j, (sid, idx) in enumerate(entries):
             self.results[sid][idx] = int(toks_np[j])
 
@@ -1574,7 +1583,8 @@ class ContinuousScheduler:
             with observe.annotate("ring_enqueue"):
                 faults.retry(self.ring.enqueue,
                              {"h": slab, "cache": cache_slab, "step": steps},
-                             slots2, n_hard, self._dispatch_bucket,
+                             slots2, n_hard, functools.partial(
+                                 self._dispatch_bucket, "backpressure"),
                              what="ring-enqueue")
 
     def _tick_fused(self) -> None:
@@ -1609,8 +1619,9 @@ class ContinuousScheduler:
                 # composed path exactly.
                 slab = _gather_rows({"h": h, "cache": self._rows,
                                      "step": self._pos}, src)
-                self.ring.enqueue(slab, src, n_hard, self._dispatch_bucket,
-                                  off=n_enq, fire_fault=False)
+                self.ring.enqueue(slab, src, n_hard, functools.partial(
+                    self._dispatch_bucket, "backpressure"), off=n_enq,
+                    fire_fault=False)
 
     # -- the loop ------------------------------------------------------------
 
@@ -1673,31 +1684,35 @@ class ContinuousScheduler:
         and should advance it toward ``next_arrival()``), or ``"idle"``
         (queue and pool fully drained; deferred token values may still be
         pending — ``drain`` harvests them)."""
-        self._maybe_migrate()                # discrete re-plan points only
-        self._maybe_apply_capacity()
-        admitted = self._try_admit()
-        self._refresh_page_stats()
-        if self._n_state(_ACTIVE) > 0:
-            self._tick()
-            while self.ring.count >= self.sc.capacity:
-                faults.retry(self._dispatch_bucket, what="full-drain")
-            # starved pool: partial buckets beat idle stage-1 width
-            while (self.ring.count > 0
-                   and self._n_state(_ACTIVE) < self.eager_drain_below):
-                faults.retry(self._dispatch_bucket, what="eager-drain")
-            return "busy"
-        if self.ring.count > 0:
-            # forced partial: all parked
-            faults.retry(self._dispatch_bucket, what="forced-drain")
-            return "busy"
-        if admitted:                         # prefill-only requests finish
-            return "busy"                    # at admission: still progress
-        if self.queue:
-            if not self._free:               # full pool, all parked, empty
-                raise AssertionError("scheduler wedged: parked slots "
-                                     "with an empty ring")
-            return "waiting"
-        return "idle"
+        with observe.annotate("sched_step"):
+            self._maybe_migrate()            # discrete re-plan points only
+            self._maybe_apply_capacity()
+            admitted = self._try_admit()
+            self._refresh_page_stats()
+            if self._n_state(_ACTIVE) > 0:
+                self._tick()
+                while self.ring.count >= self.sc.capacity:
+                    faults.retry(self._dispatch_bucket, "full",
+                                 what="full-drain")
+                # starved pool: partial buckets beat idle stage-1 width
+                while (self.ring.count > 0
+                       and self._n_state(_ACTIVE) < self.eager_drain_below):
+                    faults.retry(self._dispatch_bucket, "eager",
+                                 what="eager-drain")
+                return "busy"
+            if self.ring.count > 0:
+                # forced partial: all parked
+                faults.retry(self._dispatch_bucket, "forced",
+                             what="forced-drain")
+                return "busy"
+            if admitted:                     # prefill-only requests finish
+                return "busy"                # at admission: still progress
+            if self.queue:
+                if not self._free:           # full pool, all parked, empty
+                    raise AssertionError("scheduler wedged: parked slots "
+                                         "with an empty ring")
+                return "waiting"
+            return "idle"
 
     def drain(self) -> Dict[int, List[int]]:
         """Drive ``step`` until the queue and every slot drain (advancing

@@ -152,7 +152,7 @@ def test_quiesce_timeout_bounded_and_rolled_back():
         sched = _sched(fns)
         sched.run()
         sched.ring.count = 1                        # wedge: claims a row
-        sched._dispatch_bucket = lambda: None       # ...that never drains
+        sched._dispatch_bucket = lambda reason: None  # ...never drains
         with pytest.raises(MigrationError) as ei:
             LiveMigrator(sched, MigrationPlan(
                 capacity=3, quiesce_timeout_s=0.05,
