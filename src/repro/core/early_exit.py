@@ -205,8 +205,10 @@ def split_params(cfg: ArchConfig, spec: EarlyExitSpec, params):
 
     Slicing the stacked superblock leaves COPIES them (jnp slices are new
     buffers), so only split when there are disjoint submeshes to place the
-    slices on — the degenerate single-device builders close over the full
-    tree instead. Stage-2 'blocks' leaves start at the exit boundary —
+    slices on — the degenerate single-device builders pass the full tree,
+    and run_layers indexes each stage's layers in the stack in place in the
+    inference modes (only ``mode="train"`` slices the stack to the stage's
+    range). Stage-2 'blocks' leaves start at the exit boundary —
     pass ``presliced_params=True`` to the stage-2 entry points (they
     forward ``param_base_sb`` to run_layers)."""
     bb = params["backbone"]

@@ -339,7 +339,13 @@ def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
     (ee.split_caches output), the superblock index its 'blocks' leaves start
     at — run_layers subtracts it before slicing. ``param_base_sb`` is the
     same offset for a PRE-SLICED param tree (ee.split_params output, a
-    stage's resident slice on its own submesh)."""
+    stage's resident slice on its own submesh).
+
+    The stacked superblock params are never copied in ``decode`` and
+    ``prefill``: the scan runs over layer indices and each iteration reads
+    its layer from the stack in place. Only ``train`` slices the stack to
+    [lo, hi) and scans over the slice, so that the backward pass
+    accumulates a segment-sized gradient."""
     aux = jnp.zeros((), jnp.float32)
     new_caches: Dict[str, Any] = {"first": [], "blocks": None, "rem": []}
 
@@ -360,7 +366,21 @@ def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
     s_hi = max(s_lo, (s_hi_layer - cfg.first_k_dense) // pl)
     if s_hi > s_lo and cfg.n_superblocks:
         p_lo, p_hi = s_lo - param_base_sb, s_hi - param_base_sb
-        seg_params = jax.tree.map(lambda x: x[p_lo:p_hi], params["blocks"])
+        if mode == "train":
+            # a sliced xs keeps the reverse scan's weight gradients
+            # segment-sized; a closed-over stack carries full-stack ones,
+            # which float32 programs pay for in temporaries, and rounds
+            # the gradients differently on the chip
+            param_xs = jax.tree.map(lambda x: x[p_lo:p_hi], params["blocks"])
+            layer_params = lambda bp: bp
+        else:
+            # index the stack in place: a sliced xs is materialized as a
+            # segment-sized copy before the loop on every call
+            param_xs = jnp.arange(p_lo, p_hi, dtype=jnp.int32)
+            layer_params = lambda i: jax.tree.map(
+                lambda x: jax.lax.dynamic_index_in_dim(
+                    x, i, keepdims=False, allow_negative_indices=False),
+                params["blocks"])
         c_lo, c_hi = s_lo - cache_base_sb, s_hi - cache_base_sb
         seg_caches = (jax.tree.map(lambda x: x[c_lo:c_hi], caches["blocks"])
                       if caches else None)
@@ -368,6 +388,7 @@ def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
         def body(carry, xs):
             hh = carry
             bp, bc = xs
+            bp = layer_params(bp)
             a_tot = jnp.zeros((), jnp.float32)
             ncs = []
             for pos in range(pl):
@@ -383,7 +404,7 @@ def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
             body_fn = jax.checkpoint(body)  # remat each superblock
         else:
             body_fn = body
-        h, (ncs, aux_s) = jax.lax.scan(body_fn, h, (seg_params, seg_caches))
+        h, (ncs, aux_s) = jax.lax.scan(body_fn, h, (param_xs, seg_caches))
         new_caches["blocks"] = ncs
         aux = aux + jnp.sum(aux_s)
 
@@ -425,7 +446,7 @@ def encode(params, cfg: ArchConfig, frame_embeds):
 
 # ----------------------------------------------------------------------------
 # whole-model entry points (single-exit baseline; EE staging lives in
-# core/early_exit.py and reuses run_layers with slicing)
+# core/early_exit.py and reuses run_layers over partial ranges)
 # ----------------------------------------------------------------------------
 
 def forward(params, cfg: ArchConfig, tokens, *, frontend_embeds=None):

@@ -6,7 +6,9 @@ topology that is described rather than attached. The topology is described
 inside a module-scoped fixture, never at import time, because only one
 process at a time may load the TPU library and every test worker imports
 this file. Each test asserts that the compiled program really contains the
-Pallas kernel (``tpu_custom_call``), i.e. nothing routed around it.
+Pallas kernel (``tpu_custom_call``), i.e. nothing routed around it. One
+more reads the compiler's temporaries for the training gradient, which only
+the chip's compiler counts as the chip would.
 """
 import os
 
@@ -18,12 +20,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.archs import QWEN2_1_5B as CFG
+from repro.core import early_exit as ee
+from repro.core import losses
 from repro.kernels import dispatch
 from repro.kernels.exit_decision.kernel import exit_decision_pallas
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.fused_dispatch.kernel import fused_dispatch_pallas
 from repro.kernels.gather_compact.kernel import gather_compact_pallas
 from repro.kernels.paged_attention.kernel import paged_gather_append_pallas
+from repro.models.config import ArchConfig
 
 V, D = CFG.vocab, CFG.d_model
 KH, HD = CFG.n_kv_heads, CFG.resolved_head_dim
@@ -138,3 +143,34 @@ def test_stage1_kernels_compile_on_a_stage_submesh(topo):
     txt = _compiled_text(chain, s((N_SLOTS, V), jnp.float32),
                          s((N_SLOTS, D), jnp.bfloat16))
     assert "tpu_custom_call" in txt
+
+
+def test_train_gradient_keeps_segment_sized_buffers(one_chip):
+    """Training scans each exit's range over a slice of the stacked layers
+    (``run_layers`` in ``mode="train"``), so the reverse scans carry
+    gradients of that segment only. For this 6-layer float32 model (exit
+    after 3) the joint loss's gradient needs 0.267 x the stack's bytes of
+    temporaries; indexing the whole stack in place, as inference does,
+    carries full-stack gradients through both reverse scans and needs
+    0.984 x."""
+    cfg = ArchConfig(name="train-6", family="dense", n_layers=6,
+                     d_model=512, n_heads=4, n_kv_heads=2, d_ff=2048,
+                     vocab=1024, dtype="float32", param_dtype="float32",
+                     tie_embeddings=True)
+    spec = ee.EarlyExitSpec(exit_layer=3)
+    shapes = jax.eval_shape(lambda k: ee.init_ee_params(k, cfg, spec),
+                            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    params = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
+                          shapes)
+    tokens = _spec(one_chip, (2, 256), jnp.int32)
+
+    def loss(p, tokens):
+        eh, fh, aux = ee.forward_train(p, cfg, spec, tokens)
+        return losses.branchynet_joint_loss(p, cfg, eh, fh, tokens,
+                                            spec.loss_weights, aux=aux)[0]
+
+    temp = jax.jit(jax.grad(loss)).lower(params, tokens).compile(
+        ).memory_analysis().temp_size_in_bytes
+    stack = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(shapes["backbone"]["blocks"]))
+    assert temp < 0.65 * stack, temp / stack
