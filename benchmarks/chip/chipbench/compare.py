@@ -15,6 +15,10 @@ follows the program's own path through the ambiguous tokens.
 
 The number compared is the widest gap by which a served token's reference
 logit lies below the reference's best logit of the head that judges it.
+Beside it, the widest distance from the threshold, in log confidence, of
+a flipped decision (a served token that is the greedy token of the head
+the reference's decision does not pick, and not of the one it picks) is
+compared with the band itself.
 All shapes are padded to the pool's ``max_len`` and the mix's longest
 output, so one compiled reference serves every request.
 """
@@ -38,7 +42,7 @@ class Judged:
     n_hard: int
     worst_at: int           # the token of the widest gap
     worst_lc: float         # its log exit confidence less log threshold
-    n_flips: int            # outside the band, the other head's token
+    n_flips: int            # the other head's token: a flipped decision
     flip_lc: float          # the largest |log confidence| of those
 
 
@@ -111,11 +115,14 @@ def judge(ref: Reference, prompt, served: List[int], c_thr: float,
     g_fin = np.asarray(_gap(fin, jnp.asarray(tok)))[:n]
     # inside the band a token may come from either head
     gaps = np.where(amb, np.minimum(g_ex, g_fin), np.where(hard, g_fin, g_ex))
-    # a decision that went the other way outside the band: the token is
-    # the other head's greedy token and not the judging head's
+    # a decision that went the other way, in the band or outside it: the
+    # token is the greedy token of the head the reference's own decision
+    # does not pick, and not that of the head it picks
     lc = np.log(np.maximum(conf, 1e-38)) - lthr
-    other = np.where(hard, arg, np.asarray(jnp.argmax(fin, -1))[:n])
-    flip = ~amb & (pick == other) & (gaps > 0)
+    fin_arg = np.asarray(jnp.argmax(fin, -1))[:n]
+    own = np.where(lc <= 0, fin_arg, arg)
+    other = np.where(lc <= 0, arg, fin_arg)
+    flip = (pick == other) & (pick != own)
     flip[0] = False
     w = int(gaps.argmax())
     return Judged(max_gap=float(gaps.max()), n_tokens=n,

@@ -8,10 +8,20 @@ parameter tree (its structure from ``jax.eval_shape`` of the program's
 init) with seeded values, and ``reference_weights`` hands the same arrays to
 the plain reference under the reference's names. The program never makes a
 number the reference reads.
+
+Nothing here knows an architecture: the configuration file's ``program``
+block says what the published keys do not (``arch_config``), and the
+reference it names says how it reads the model (its ``dims`` and
+``weights``), so a configuration of another architecture is files only.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+import importlib.util
 import time
+import typing
+from pathlib import Path
 
 import numpy as np
 
@@ -25,32 +35,103 @@ from repro.runtime import serve_api
 from repro.runtime import serve_loop as SL
 
 
+# the published config's keys and the ArchConfig field each one sets
+PUBLISHED = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
+             "num_attention_heads": "n_heads",
+             "num_key_value_heads": "n_kv_heads",
+             "intermediate_size": "d_ff", "vocab_size": "vocab",
+             "head_dim": "head_dim", "rope_theta": "rope_theta",
+             "tie_word_embeddings": "tie_embeddings",
+             "rms_norm_eps": "norm_eps",
+             "first_k_dense_replace": "first_k_dense"}
+FLOATS = ("rope_theta", "norm_eps")
+# published keys that set a field of a nested group (DeepSeek's names);
+# where the ``program`` block states the same field, the two must agree
+PUBLISHED_NESTED = {"kv_lora_rank": ("mla", "kv_lora_rank"),
+                    "q_lora_rank": ("mla", "q_lora_rank"),
+                    "qk_nope_head_dim": ("mla", "qk_nope_head_dim"),
+                    "qk_rope_head_dim": ("mla", "qk_rope_head_dim"),
+                    "v_head_dim": ("mla", "v_head_dim"),
+                    "n_routed_experts": ("moe", "n_experts"),
+                    "num_experts_per_tok": ("moe", "top_k"),
+                    "moe_intermediate_size": ("moe", "d_ff_expert"),
+                    "n_shared_experts": ("moe", "n_shared")}
+
+
+def _from_json(cls, kw: dict, where: str):
+    """``cls(**kw)`` with each value as its field's type wants it: a JSON
+    object for a dataclass field builds that dataclass by its own field
+    names, a list becomes a tuple. A key that is not a field raises."""
+    hints = typing.get_type_hints(cls)
+    out = {}
+    for k, v in kw.items():
+        if k not in hints:
+            raise KeyError(f"{where}: {k!r} is not a field of {cls.__name__}")
+        if isinstance(v, dict):
+            sub = [t for t in typing.get_args(hints[k])
+                   if dataclasses.is_dataclass(t)]
+            if not sub:
+                raise TypeError(f"{where}: {k!r} is not a dataclass field")
+            v = _from_json(sub[0], v, f"{where}.{k}")
+        elif isinstance(v, list):
+            v = tuple(v)
+        out[k] = v
+    return cls(**out)
+
+
 def arch_config(conf: dict) -> ArchConfig:
-    """The program's ArchConfig from a configuration file (the published
-    config's keys, plus the two program switches under ``program``)."""
-    prog = conf["program"]
-    return ArchConfig(
-        name=conf["name"], family="dense",
-        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
-        head_dim=conf.get("head_dim"), qkv_bias=prog["qkv_bias"],
-        qk_norm=prog["qk_norm"], rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=conf["tie_word_embeddings"],
-        norm_eps=float(conf["rms_norm_eps"]),
-        exit_layers=(conf["serving"]["exit_layer"],),
-        dtype=conf["torch_dtype"], param_dtype=conf["torch_dtype"])
+    """The program's ArchConfig from a configuration file: the published
+    config's keys (``PUBLISHED``; a dense decoder unless told otherwise),
+    then every key of its ``program`` block by ArchConfig field name, which
+    may override them (``family``, ``d_ff``, ...). A nested group (``mla``,
+    ``moe``) takes the published keys of ``PUBLISHED_NESTED`` and the
+    block's fields; a field stated both ways must agree."""
+    where = f"{conf['name']} program"
+    kw = {"name": conf["name"], "family": "dense",
+          "exit_layers": [conf["serving"]["exit_layer"]]}
+    if "torch_dtype" in conf:
+        kw["dtype"] = kw["param_dtype"] = conf["torch_dtype"]
+    for key, field in PUBLISHED.items():
+        if key in conf:
+            kw[field] = float(conf[key]) if field in FLOATS else conf[key]
+    prog = dict(conf.get("program", {}))
+    for key, (group, field) in PUBLISHED_NESTED.items():
+        if key not in conf:
+            continue
+        value = 0 if conf[key] is None else conf[key]     # null: none
+        stated = prog.get(group, {})
+        if field in stated and stated[field] != value:
+            raise ValueError(f"{where}: {group}.{field} {stated[field]!r} "
+                             f"but the published {key} is {value!r}")
+        prog[group] = {field: value, **stated}
+    kw.update(prog)
+    return _from_json(ArchConfig, kw, where)
 
 
-def model_dims(cfg: ArchConfig) -> dict:
-    """What the reference and the FLOP count need to know of the model."""
-    return {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.resolved_head_dim, "d_ff": cfg.d_ff,
-            "vocab": cfg.vocab, "eps": cfg.norm_eps,
-            "rope_theta": cfg.rope_theta, "qkv_bias": cfg.qkv_bias,
-            "qk_norm": cfg.qk_norm, "exit": cfg.exit_layers[0]}
+REFERENCES = Path(__file__).resolve().parents[1] / "references"
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: Path):
+    """A benchmark file found by name (a reference, a metric's reader),
+    loaded once per process."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(conf: dict):
+    """The plain reference the configuration names
+    (``references/<reference>.py``), loaded once per process."""
+    return load_module((REFERENCES / f"{conf['reference']}.py").resolve())
+
+
+def model_dims(conf: dict, cfg: ArchConfig) -> dict:
+    """What the reference and the FLOP count need to know of the model, as
+    the configuration's reference reads it (its ``dims``)."""
+    return reference(conf).dims(cfg)
 
 
 def seed32(seed: int, salt: int) -> int:
@@ -65,11 +146,14 @@ def seed32(seed: int, salt: int) -> int:
 # 20 / V and the hard share swung from 0.1 to 0.45 over a 15 % change of
 # the threshold (my chip runs), so no threshold held a share steady.
 EMBED_STD = 0.1
+# an untied output head (d, V) is drawn like the tied table, so that its
+# logits spread alike
+HEAD = "backbone/head"
 
 
 def _leaf(key, name: str, sd: jax.ShapeDtypeStruct):
     dt = sd.dtype
-    if name.endswith("table"):                      # embedding
+    if name.endswith("table") or name == HEAD:      # embedding, head
         return (EMBED_STD * jax.random.normal(key, sd.shape, jnp.float32)
                 ).astype(dt)
     if name.endswith("scale"):                      # RMSNorm gains
@@ -98,7 +182,8 @@ def vocab_perm(seed: int, vocab: int) -> np.ndarray:
 def make_weights(cfg: ArchConfig, spec, weights_seed: int, perm):
     """Every parameter, on the device, in the served dtype, from one jitted
     call: the configuration's model (``weights_seed``) with its vocabulary
-    renamed by ``perm`` (the tied embedding's rows permuted). The traffic
+    renamed by ``perm`` on every vocabulary axis (the embedding's rows and,
+    where the model is untied, the output head's columns). The traffic
     renames its tokens alike, so every seed runs the same model on the same
     requests under other token ids."""
     shapes = jax.eval_shape(lambda k: ee.init_ee_params(k, cfg, spec),
@@ -111,6 +196,8 @@ def make_weights(cfg: ArchConfig, spec, weights_seed: int, perm):
             x = _leaf(jax.random.fold_in(key, i), _path(p), sd)
             if _path(p).endswith("embed/table"):
                 x = jnp.take(x, perm, axis=0)
+            elif _path(p) == HEAD:
+                x = jnp.take(x, perm, axis=1)
             leaves.append(x)
         return treedef.unflatten(leaves)
 
@@ -118,24 +205,10 @@ def make_weights(cfg: ArchConfig, spec, weights_seed: int, perm):
                          jnp.asarray(perm))
 
 
-def reference_weights(params) -> dict:
-    """The same arrays under the reference's names (no copies)."""
-    bb = params["backbone"]
-    blk = bb["blocks"][0]
-    at, mlp = blk["attn"], blk["mlp"]
-    lw = {"norm1": blk["norm1"]["scale"], "wq": at["wq"], "wk": at["wk"],
-          "wv": at["wv"], "wo": at["wo"], "norm2": blk["norm2"]["scale"],
-          "w_gate": mlp["wi_gate"], "w_up": mlp["wi_up"],
-          "w_down": mlp["wo"]}
-    for k in ("bq", "bk", "bv"):
-        if k in at:
-            lw[k] = at[k]
-    for k in ("q_norm", "k_norm"):
-        if k in at:
-            lw[k] = at[k]["scale"]
-    return {"embed": bb["embed"]["table"], "layers": lw,
-            "exit_norm": params["exit_head"]["norm"]["scale"],
-            "final_norm": bb["final_norm"]["scale"]}
+def reference_weights(conf: dict, params) -> dict:
+    """The same arrays under the reference's names, as the configuration's
+    reference maps them (its ``weights``; no copies)."""
+    return reference(conf).weights(params)
 
 
 class WallClock:

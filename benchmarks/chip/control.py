@@ -1,6 +1,7 @@
 """Readings that set a configuration's limit: the program's widest logit
 gap on many seeds (the lower reading) and the float8 control's on the same
-served streams (the upper reading), all in one process.
+served streams (the upper reading), all in one process; beside them, each
+side's widest flipped decision, which sets the judge's band.
 
     python3 benchmarks/chip/control.py --workload <cell> --seconds <s> \
         --seeds 1,2,3,...
@@ -31,8 +32,8 @@ def main(argv=None) -> int:
     spec = run.start(args.workload)
     if spec is None:
         return 2
-    ref_mod = run.load_module(run.HERE / "references"
-                              / f"{spec['conf']['reference']}.py")
+    from chipbench import system
+    ref_mod = system.reference(spec["conf"])
     for seed in (int(s) for s in args.seeds.split(",")):
         out = run.run_cell(spec, seed, args.seconds, False,
                            t_start=time.perf_counter(),
@@ -41,6 +42,8 @@ def main(argv=None) -> int:
             "seed": seed, "correct": out["correct"],
             "max_logit_gap": out["checks"]["max_logit_gap"]["value"],
             "control_max_logit_gap": out["control_max_logit_gap"],
+            "decision_flip": out["checks"]["decision_flip"]["value"],
+            "control_decision_flip": out["control_decision_flip"],
             "metrics": out["metrics"]}), flush=True)
         gc.collect()
     return 0

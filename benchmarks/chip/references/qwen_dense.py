@@ -18,6 +18,10 @@ Weights arrive in the layout ``layout()`` names, stored as served (bf16);
 they are upcast layer by layer. ``matmul`` is the one product every
 equation uses, so the fp8 control (``fp8_matmul``) swaps precision in one
 place.
+
+The harness asks this module how it reads the model: ``dims(cfg)`` gives
+the sizes it needs from the program's configuration, and
+``weights(params)`` names the served arrays in its layout (no copies).
 """
 from __future__ import annotations
 
@@ -123,6 +127,36 @@ def layout() -> dict:
             "layers": ["norm1", "wq", "wk", "wv", "bq", "bk", "bv",
                        "q_norm", "k_norm", "wo", "norm2", "w_gate", "w_up",
                        "w_down"]}
+
+
+def dims(cfg) -> dict:
+    """What the reference and the FLOP count need to know of the model."""
+    return {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
+            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
+            "head_dim": cfg.resolved_head_dim, "d_ff": cfg.d_ff,
+            "vocab": cfg.vocab, "eps": cfg.norm_eps,
+            "rope_theta": cfg.rope_theta, "qkv_bias": cfg.qkv_bias,
+            "qk_norm": cfg.qk_norm, "exit": cfg.exit_layers[0]}
+
+
+def weights(params) -> dict:
+    """The same arrays under the reference's names (no copies)."""
+    bb = params["backbone"]
+    blk = bb["blocks"][0]
+    at, mlp = blk["attn"], blk["mlp"]
+    lw = {"norm1": blk["norm1"]["scale"], "wq": at["wq"], "wk": at["wk"],
+          "wv": at["wv"], "wo": at["wo"], "norm2": blk["norm2"]["scale"],
+          "w_gate": mlp["wi_gate"], "w_up": mlp["wi_up"],
+          "w_down": mlp["wo"]}
+    for k in ("bq", "bk", "bv"):
+        if k in at:
+            lw[k] = at[k]
+    for k in ("q_norm", "k_norm"):
+        if k in at:
+            lw[k] = at[k]["scale"]
+    return {"embed": bb["embed"]["table"], "layers": lw,
+            "exit_norm": params["exit_head"]["norm"]["scale"],
+            "final_norm": bb["final_norm"]["scale"]}
 
 
 def stage1(a: dict, w: dict, tokens, rows, matmul=f32_matmul):

@@ -1,6 +1,6 @@
 """The readers of the program's own span record (``host_ms``,
-``admit_share``, ``eager_bucket_share``, each in its ``.offline`` and
-``.qwen3-4b`` file): on a traced run of the tiny cell they read finite
+``admit_share``, ``eager_bucket_share``, each in its ``.offline``,
+``.qwen3-4b`` and ``.split3-1`` file): on a traced run of the tiny cell they read finite
 values, and with no window opened in the process they read nothing.
 
     JAX_PLATFORMS=cpu python3 -m pytest -q benchmarks/chip/tests
@@ -19,18 +19,18 @@ sys.path[:0] = [str(CHIP), str(CHIP.parents[1] / "src"), str(CHIP / "tests")]
 
 import run  # noqa: E402
 import tiny  # noqa: E402
-from chipbench import roofline  # noqa: E402
+from chipbench import roofline, system  # noqa: E402
 from repro.runtime import observe  # noqa: E402
 
 SEED = 2**31 + 4099
 READERS = [f"{q}.{cell}" for q in ("host_ms", "admit_share",
                                    "eager_bucket_share")
-           for cell in ("offline", "qwen3-4b")]
+           for cell in ("offline", "qwen3-4b", "split3-1")]
 UNITS = {"host_ms": "ms", "admit_share": "%", "eager_bucket_share": "%"}
 
 
 def _reader(name):
-    return run.load_module(CHIP / "metrics" / f"{name}.py").read
+    return system.load_module(CHIP / "metrics" / f"{name}.py").read
 
 
 @pytest.fixture(scope="module")

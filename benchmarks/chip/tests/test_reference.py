@@ -40,26 +40,26 @@ def model(request):
     cfg = system.arch_config(conf)
     spec = ee.EarlyExitSpec(exit_layer=conf["serving"]["exit_layer"])
     params = system.make_weights(cfg, spec, 0, system.vocab_perm(7, cfg.vocab))
-    return cfg, spec, params
+    return conf, cfg, spec, params
 
 
 def test_full_forward_matches_program(model):
-    cfg, _, params = model
+    conf, cfg, _, params = model
     toks = jax.random.randint(jax.random.PRNGKey(1), (1, 40), 0, cfg.vocab)
     want, _ = T.forward(params["backbone"], cfg, toks)
-    got = R.full_forward(system.model_dims(cfg),
-                         system.reference_weights(params), toks[0])
+    got = R.full_forward(system.model_dims(conf, cfg),
+                         system.reference_weights(conf, params), toks[0])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want[0]),
                                rtol=1e-4, atol=1e-4)
 
 
 def test_exit_head_matches_program(model):
-    cfg, spec, params = model
+    conf, cfg, spec, params = model
     toks = jax.random.randint(jax.random.PRNGKey(2), (1, 33), 0, cfg.vocab)
     _, _, want, _ = ee.stage1_prefill(params, cfg, spec, toks)
     rows = jnp.asarray([32])
-    _, got = R.stage1(system.model_dims(cfg),
-                      system.reference_weights(params), toks[0], rows)
+    _, got = R.stage1(system.model_dims(conf, cfg),
+                      system.reference_weights(conf, params), toks[0], rows)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                rtol=1e-4, atol=1e-4)
 
@@ -68,8 +68,9 @@ def test_exit_gap_keys_are_zero_and_counted(model):
     """A position without stage-2 keys still takes softmax weight (score 0
     against a zero key) and adds nothing: dropping it from the mask instead
     changes the result."""
-    cfg, _, params = model
-    a, w = system.model_dims(cfg), system.reference_weights(params)
+    conf, cfg, _, params = model
+    a = system.model_dims(conf, cfg)
+    w = system.reference_weights(conf, params)
     toks = jax.random.randint(jax.random.PRNGKey(3), (20,), 0, cfg.vocab)
     h, _ = R.stage1(a, w, toks, jnp.asarray([19]))
     keep_all = jnp.ones(20, bool)

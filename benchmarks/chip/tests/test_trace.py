@@ -15,12 +15,22 @@ from pathlib import Path
 import pytest
 
 CHIP = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(CHIP)]
+sys.path[:0] = [str(CHIP), str(CHIP.parents[1] / "src")]
 
-from chipbench import tracing  # noqa: E402
+import run  # noqa: E402
+from chipbench import roofline, tracing  # noqa: E402
 from chipbench.tracing import Reduced  # noqa: E402
 
 RECORDED = Path(__file__).resolve().parent / "data" / "trace_q2off.json.gz"
+# the same cell traced with the program's step spans in the trace
+# (``data/trace_q2off_spans.json.gz``: the first 0.3 s of the lists
+# ``tracing.extract`` kept from a traced run on one TPU v5e, with that
+# run's window counts and counters)
+RECORDED_SPANS = RECORDED.with_name("trace_q2off_spans.json.gz")
+# the host spans that named idle gaps before the program's step spans
+OLD_SPANS = ("finish_tick_sync", "stage2_bucket_dispatch", "pool_tick_fused",
+             "pool_tick", "ring_enqueue", "bench_step", "bench_scan",
+             "bench_sleep")
 
 
 def test_hand_made_trace():
@@ -72,3 +82,43 @@ def test_recorded_trace():
     assert r.modules_matching("pool_tick")[1] >= 1
     t, n = r.ops_matching("exit_decision")
     assert n >= 1 and t > 0
+
+
+def test_step_spans_rename_idle_gaps_only():
+    """The program's step spans among the host spans (``run.HOST_SPANS``)
+    move the step's idle from ``bench_step`` to what the step was doing;
+    the device ops, busy time, the idle total and every per-layer reading
+    of the cell stay as they were."""
+    with gzip.open(RECORDED_SPANS, "rt") as f:
+        rec = json.load(f)
+    tr = rec["raw"]
+    assert set(OLD_SPANS) < set(run.HOST_SPANS)
+    old_tr = dict(tr, host=[h for h in tr["host"] if h[2] in OLD_SPANS])
+    new, old = Reduced.of(tr), Reduced.of(old_tr)
+    for field in ("window_s", "busy_s", "busy_s_per_chip", "module_s",
+                  "module_n", "op_s", "op_n", "op_in_module_s"):
+        assert getattr(new, field) == getattr(old, field), field
+    assert sum(new.idle_by_host.values()) == pytest.approx(
+        sum(old.idle_by_host.values()), rel=1e-12)
+    assert new.breakdown()["device_ops"] == old.breakdown()["device_ops"]
+    new_names = {k for k, _ in new.breakdown()["idle_gaps"]}
+    old_names = {k for k, _ in old.breakdown()["idle_gaps"]}
+    assert new_names - old_names and new_names - old_names <= set(
+        run.HOST_SPANS) - set(OLD_SPANS)
+    assert new.idle_by_host.get("bench_step", 0.0) < \
+        old.idle_by_host["bench_step"]
+
+    spec = run.load_cell(rec["workload"], CHIP.parents[1])
+    conf, mix = spec["conf"], spec["mix"]
+    from chipbench import system
+    dims = system.model_dims(conf, system.arch_config(conf))
+    read = {}
+    for red in (new, old):
+        ctx = {"trace": red, "dims": dims, "conf": conf, "mix": mix,
+               "peaks": roofline.peaks("TPU v5 lite"), "chips": 1,
+               "occupancy": [], **rec["ctx"]}
+        read[id(red)] = {m["name"]: system.load_module(
+            CHIP / "metrics" / f"{m['name']}.py").read(ctx)
+            for m in spec["per_layer"]}
+    assert read[id(new)] == read[id(old)]
+    assert any(v is not None for v in read[id(new)].values())

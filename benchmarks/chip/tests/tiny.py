@@ -21,7 +21,7 @@ TINY = {
     "serving": {"exit_layer": 2, "n_slots": 8, "max_len": 48,
                 "page_size": 8, "n_pages": 64, "queue_depth": 4,
                 "c_thr": 0.017},
-    "limits": {"max_logit_gap": 1e-2},
+    "limits": {"max_logit_gap": 1e-2, "band": 0.15},
 }
 
 
@@ -36,8 +36,9 @@ def mix(kind: str = "offline") -> dict:
     return m
 
 
-def spec(kind: str = "offline", mix_overrides=None, c_thr=None) -> dict:
-    conf = copy.deepcopy(TINY)
+def spec(kind: str = "offline", mix_overrides=None, c_thr=None,
+         conf=None) -> dict:
+    conf = copy.deepcopy(TINY if conf is None else conf)
     if c_thr is not None:
         conf["serving"]["c_thr"] = c_thr
     m = mix(kind)
