@@ -1,0 +1,6 @@
+"""``device_idle.offline`` read in the DeepSeek-V2-Lite EP4 cell, whose per-layer
+metrics are its own."""
+import runpy
+from pathlib import Path
+
+read = runpy.run_path(str(Path(__file__).with_name("device_idle.offline.py")))["read"]

@@ -7,8 +7,10 @@ while shrinking widths so a forward/train step runs on CPU in seconds.
 """
 from __future__ import annotations
 
+import dataclasses
+
 from repro.models.config import (ArchConfig, MLAConfig, MoEConfig, RGLRUConfig,
-                                 SSMConfig)
+                                 RopeScaling, SSMConfig)
 
 # --- ssm ---------------------------------------------------------------------
 MAMBA2_130M = ArchConfig(
@@ -50,11 +52,18 @@ DEEPSEEK_V2_LITE = ArchConfig(
     name="deepseek-v2-lite-16b", family="moe",
     n_layers=27, d_model=2048, n_heads=16, n_kv_heads=16, d_ff=1408,
     vocab=102400, first_k_dense=1, dense_ff=10944,
-    moe=MoEConfig(n_experts=64, top_k=6, d_ff_expert=1408, n_shared=2),
+    moe=MoEConfig(n_experts=64, top_k=6, d_ff_expert=1408, n_shared=2,
+                  norm_topk_prob=False),
     mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
                   v_head_dim=128),
+    rope_scaling=RopeScaling(type="yarn", factor=40,
+                             original_max_position_embeddings=4096,
+                             beta_fast=32, beta_slow=1, mscale=0.707,
+                             mscale_all_dim=0.707),
     tie_embeddings=False,
-)  # [arXiv:2405.04434 — MLA kv_lora=512, shared+routed experts top-6]
+)  # [arXiv:2405.04434 — MLA kv_lora=512, shared+routed experts top-6;
+#     hf:deepseek-ai/DeepSeek-V2-Lite config.json — norm_topk_prob false,
+#     YaRN rope_scaling]
 
 GROK_1_314B = ArchConfig(
     name="grok-1-314b", family="moe",
@@ -137,8 +146,9 @@ def smoke_config(cfg: ArchConfig) -> ArchConfig:
         dtype="float32", param_dtype="float32",
     )
     if cfg.moe:
-        kw["moe"] = MoEConfig(n_experts=4, top_k=2, d_ff_expert=32,
-                              n_shared=min(cfg.moe.n_shared, 1))
+        kw["moe"] = dataclasses.replace(cfg.moe, n_experts=4, top_k=2,
+                                        d_ff_expert=32, n_held=None,
+                                        n_shared=min(cfg.moe.n_shared, 1))
     if cfg.mla:
         kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
                               qk_rope_head_dim=8, v_head_dim=16)

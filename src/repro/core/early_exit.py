@@ -131,30 +131,34 @@ def stage2_prefill(params, cfg: ArchConfig, spec: EarlyExitSpec, h, *,
 
 
 def stage1_decode(params, cfg: ArchConfig, spec: EarlyExitSpec, token, caches,
-                  step):
-    """One-token stage 1. Returns (hidden (B,1,d), new_caches, exit_logits)."""
+                  step, *, with_load: bool = False):
+    """One-token stage 1. Returns (hidden (B,1,d), new_caches, exit_logits),
+    and with ``with_load`` the MoE layers' summed load counters."""
     bb = params["backbone"]
     h = T.embed_tokens(bb, cfg, token)
-    h, ncaches, _ = T.run_layers(bb, cfg, h, 0, spec.exit_layer, mode="decode",
-                                 caches=caches, step=step)
-    return h, ncaches, exit_head(params, cfg, h[:, 0])
+    h, ncaches, load = T.run_layers(bb, cfg, h, 0, spec.exit_layer,
+                                    mode="decode", caches=caches, step=step)
+    out = (h, ncaches, exit_head(params, cfg, h[:, 0]))
+    return out + (load,) if with_load else out
 
 
 def stage2_decode(params, cfg: ArchConfig, spec: EarlyExitSpec, h, caches,
                   step, *, presliced: bool = True,
-                  presliced_params: bool = False):
+                  presliced_params: bool = False, with_load: bool = False):
     """One-token stage 2 on the compacted hard slab. ``caches`` is the
     stage-2 SEGMENT cache (ee.split_caches) by default — its bucket batch
     size differs from stage 1's, so the pytrees cannot be shared.
-    ``presliced_params`` marks a stage-2 param slice (ee.split_params)."""
+    ``presliced_params`` marks a stage-2 param slice (ee.split_params).
+    ``with_load`` appends the MoE layers' summed load counters."""
     bb = params["backbone"]
     base = ((spec.exit_layer - cfg.first_k_dense) // cfg.pattern_len
             if presliced else 0)
     pbase = _stage2_base_sb(cfg, spec) if presliced_params else 0
-    h, ncaches, _ = T.run_layers(bb, cfg, h, spec.exit_layer, cfg.n_layers,
-                                 mode="decode", caches=caches, step=step,
-                                 cache_base_sb=base, param_base_sb=pbase)
-    return T.head(bb, cfg, h[:, 0]), ncaches
+    h, ncaches, load = T.run_layers(bb, cfg, h, spec.exit_layer, cfg.n_layers,
+                                    mode="decode", caches=caches, step=step,
+                                    cache_base_sb=base, param_base_sb=pbase)
+    out = (T.head(bb, cfg, h[:, 0]), ncaches)
+    return out + (load,) if with_load else out
 
 
 def _slice0(x, lo: int, hi: Optional[int]):

@@ -177,7 +177,7 @@ def _layer_param_bytes(cfg: ArchConfig, kind: str, dense_mlp: bool) -> float:
     # mlp / moe
     if cfg.moe is not None and not dense_mlp:
         m = cfg.moe
-        p += m.n_experts * 3 * d * m.d_ff_expert + d * m.n_experts
+        p += m.held * 3 * d * m.d_ff_expert + d * m.n_experts
         p += m.n_shared * 3 * d * m.d_ff_expert
     elif cfg.d_ff > 0 or dense_mlp:
         ff = cfg.dense_ff if (dense_mlp and cfg.dense_ff) else cfg.d_ff
@@ -214,7 +214,9 @@ def _layer_flops_per_token(cfg: ArchConfig, kind: str, dense_mlp: bool,
         f += 2 * (2 * d * w + 2 * w * w + w * d) + 10 * w
     if cfg.moe is not None and not dense_mlp:
         m = cfg.moe
-        f += 2 * 3 * d * m.d_ff_expert * (m.top_k + m.n_shared)
+        # routed rows land on held experts in proportion to their share
+        f += 2 * 3 * d * m.d_ff_expert * (m.top_k * m.held / m.n_experts
+                                          + m.n_shared)
         f += 2 * d * m.n_experts                   # router
     elif cfg.d_ff > 0 or dense_mlp:
         ff = cfg.dense_ff if (dense_mlp and cfg.dense_ff) else cfg.d_ff

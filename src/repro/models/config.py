@@ -16,13 +16,23 @@ import jax.numpy as jnp
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                # the router's width: experts routed over
     top_k: int
     d_ff_expert: int
     n_shared: int = 0
-    capacity_factor: float = 1.25
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # experts whose weights this layer holds: a contiguous range of
+    # ``n_held`` (the layer is told where it starts; experts 0.. by
+    # default), the chip's share under expert parallelism. None: all.
+    n_held: Optional[int] = None
+    # renormalise the top-k router weights to sum to one (DeepSeek-V2's
+    # ``norm_topk_prob``; False keeps the softmax probabilities as they are)
+    norm_topk_prob: bool = True
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 @dataclass(frozen=True)
@@ -33,6 +43,28 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rope scaling as DeepSeek-V2 publishes it: the fields are the
+    keys of the published config's ``rope_scaling`` object. The inverse
+    frequencies of the rotary dimensions between the ``beta_fast`` and
+    ``beta_slow`` correction dimensions ramp from the original ones to the
+    original / ``factor``; the attention softmax scale is multiplied by
+    mscale(factor, mscale_all_dim)^2 and cos/sin by mscale(factor, mscale)
+    / mscale(factor, mscale_all_dim)."""
+    type: str
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        if self.type != "yarn":
+            raise ValueError(f"rope_scaling type {self.type!r}: only 'yarn'")
 
 
 @dataclass(frozen=True)
@@ -70,6 +102,7 @@ class ArchConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[RopeScaling] = None   # YaRN, read by MLA
     window: Optional[int] = None        # sliding window for "lattn" blocks
     logit_softcap: Optional[float] = None
 

@@ -64,18 +64,52 @@ def layernorm(params, x, eps: float = 1e-6):
 # RoPE
 # ----------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention magnitude correction, 0.1 m ln(s) + 1 (1 for s <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, D) or (..., S, D); positions: (..., S)."""
+def _yarn_correction_dim(n_rot: float, head_dim: int, theta: float,
+                         orig_len: int) -> float:
+    """The rotary dimension whose wavelength fits ``n_rot`` turns in the
+    original context."""
+    return (head_dim * math.log(orig_len / (n_rot * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None):
+    """(D/2,) inverse frequencies; with YaRN ``scaling`` (a
+    ``config.RopeScaling``), those past the ``beta_fast`` correction
+    dimension ramp linearly to the original / ``factor``, reached at the
+    ``beta_slow`` one."""
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return inv
+    orig = scaling.original_max_position_embeddings
+    lo = max(math.floor(_yarn_correction_dim(scaling.beta_fast, head_dim,
+                                             theta, orig)), 0)
+    hi = min(math.ceil(_yarn_correction_dim(scaling.beta_slow, head_dim,
+                                            theta, orig)), head_dim - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta: float, scaling=None):
+    """x: (..., S, H, D) or (..., S, D); positions: (..., S). ``scaling``:
+    YaRN (``rope_freqs``), which also scales cos and sin by mscale(factor,
+    mscale) / mscale(factor, mscale_all_dim)."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                       # (D/2,)
+    inv = rope_freqs(d, theta, scaling)              # (D/2,)
     ang = positions.astype(jnp.float32)[..., None] * inv   # (..., S, D/2)
     if x.ndim == ang.ndim + 1:                        # (..., S, H, D): broadcast heads
         ang = ang[..., None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        f = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * f, sin * f
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -139,7 +173,7 @@ def unembed(params, h):
 def blocked_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
                       q_block: int = 256, kv_block: int = 512,
                       softcap: Optional[float] = None,
-                      q_offset=0):
+                      q_offset=0, scale: Optional[float] = None):
     """Memory-bounded attention (the jnp mirror of the Pallas flash kernel).
 
     q: (B, Sq, H, D); k, v: (B, Sk, KH, D) with H % KH == 0.
@@ -148,12 +182,13 @@ def blocked_attention(q, k, v, *, causal: bool = True, window: Optional[int] = N
     is what the Pallas kernel enforces with BlockSpecs on TPU; the scan
     structure makes the lowered HLO's working set match it). ``q_offset`` is
     the absolute position of q[0] (sequence-parallel shards / decode
-    continuation), int or traced scalar.
+    continuation), int or traced scalar. ``scale`` multiplies the scores
+    (1/sqrt(D) when None).
     """
     B, Sq, H, D = q.shape
     _, Sk, KH, _ = k.shape
     G = H // KH
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     kb = min(kv_block, Sk)
     # adaptive q tile: biggest block keeping the (B, G, qb, kb) f32 score
     # tile within a VMEM budget — fewer K/V re-reads for small-G (MHA) archs

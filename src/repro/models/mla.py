@@ -3,6 +3,9 @@
 MLA projects hidden states into a low-rank KV latent (kv_lora_rank) plus a
 shared rope key; per-head K/V are decompressed from the latent. The decode
 cache stores only (latent, k_rope) — the paper-relevant 8-9x KV compression.
+With YaRN (``cfg.rope_scaling``) the rope parts take the scaled frequencies
+and the softmax scale gains mscale(factor, mscale_all_dim)^2, as DeepSeek-V2
+publishes it.
 """
 from __future__ import annotations
 
@@ -13,7 +16,7 @@ import jax.numpy as jnp
 
 from repro.models.config import ArchConfig
 from repro.models.layers import (apply_rope, blocked_attention, dense_init,
-                                 init_rmsnorm, rmsnorm)
+                                 init_rmsnorm, rmsnorm, yarn_mscale)
 
 
 def init_mla(key, cfg: ArchConfig) -> dict:
@@ -43,13 +46,22 @@ def _mla_qkv(params, cfg: ArchConfig, x, positions):
     q = jnp.einsum("bsd,de->bse", x, params["wq"]).reshape(
         B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
 
     dkv = jnp.einsum("bsd,de->bse", x, params["w_dkv"])
     latent, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
     latent = rmsnorm(params["kv_norm"], latent, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)  # (B,S,1,r)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        cfg.rope_scaling)                        # (B,S,1,r)
     return q_nope, q_rope, latent, k_rope
+
+
+def _yarn_softmax_factor(cfg: ArchConfig) -> float:
+    """What YaRN multiplies the 1/sqrt(head dim) softmax scale by."""
+    rs = cfg.rope_scaling
+    if rs is None or not rs.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
 
 
 def _decompress(params, cfg: ArchConfig, latent):
@@ -79,7 +91,9 @@ def mla_fwd(params, cfg: ArchConfig, x, positions=None):
     # pad v to qk head dim so the blocked kernel is reusable, then slice back
     pad = q.shape[-1] - m.v_head_dim
     v_p = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-    out = blocked_attention(q, k, v_p, causal=True)[..., :m.v_head_dim]
+    scale = _yarn_softmax_factor(cfg) / math.sqrt(q.shape[-1])
+    out = blocked_attention(q, k, v_p, causal=True,
+                            scale=scale)[..., :m.v_head_dim]
     out = jnp.einsum("bse,ed->bsd", out.reshape(B, S, -1), params["wo"])
     return out, (latent, k_rope[:, :, 0, :])
 
@@ -128,6 +142,8 @@ def _mla_attend(params, cfg: ArchConfig, q_nope, q_rope, lat_cache,
     s = s + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0].astype(jnp.float32),
                        kr_cache.astype(jnp.float32))
     s = s / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if cfg.rope_scaling is not None:
+        s = s * _yarn_softmax_factor(cfg)
     s = jnp.where(valid[:, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     # out = p · V = p · (W_uv latent); absorb W_uv on the way out

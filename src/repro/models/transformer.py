@@ -92,7 +92,8 @@ def _init_block_cache(cfg: ArchConfig, kind: str, batch: int, max_len: int,
 def _apply_block(params, cfg: ArchConfig, kind: str, h, *, mode: str,
                  cache=None, step=None, causal: bool = True,
                  memory=None, dense_mlp: bool = False):
-    """Returns (h, new_cache, aux_loss)."""
+    """Returns (h, new_cache, aux): the load-balance loss in ``train``,
+    else an MoE block's load counters (zero for other blocks)."""
     aux = jnp.zeros((), jnp.float32)
     x = rmsnorm(params["norm1"], h, cfg.norm_eps)
     new_cache = cache
@@ -168,7 +169,10 @@ def _apply_block(params, cfg: ArchConfig, kind: str, h, *, mode: str,
         h = h + y
     if "moe" in params:
         x = rmsnorm(params["norm2"], h, cfg.norm_eps)
-        y, aux = moe_mod.moe_fwd(params["moe"], cfg, x)
+        y, loss, load = moe_mod.moe_fwd(params["moe"], cfg, x)
+        # training sums the load-balance loss; serving sums the load
+        # counters (moe.LOAD_FIELDS), which no dense block adds to
+        aux = loss if mode == "train" else load
         h = h + y
     elif "mlp" in params:
         x = rmsnorm(params["norm2"], h, cfg.norm_eps)
@@ -406,7 +410,7 @@ def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
             body_fn = body
         h, (ncs, aux_s) = jax.lax.scan(body_fn, h, (param_xs, seg_caches))
         new_caches["blocks"] = ncs
-        aux = aux + jnp.sum(aux_s)
+        aux = aux + jnp.sum(aux_s, axis=0)
 
     # --- remainder -------------------------------------------------------------
     rem_base = cfg.first_k_dense + cfg.n_superblocks * pl
@@ -475,19 +479,22 @@ def forward_hidden(params, cfg: ArchConfig, tokens, *, frontend_embeds=None):
 
 
 def prefill(params, cfg: ArchConfig, tokens, *, frontend_embeds=None,
-            max_len: int = 0):
-    """Returns (last_logits (B, V), caches, memory). ``max_len`` > seq pads
-    the caches so subsequent decode steps have write slots."""
+            max_len: int = 0, with_load: bool = False):
+    """Returns (last_logits (B, V), caches, memory), and with
+    ``with_load`` the MoE layers' summed load counters after them.
+    ``max_len`` > seq pads the caches so subsequent decode steps have
+    write slots."""
     memory = None
     if cfg.encdec:
         memory = encode(params, cfg, frontend_embeds)
         frontend_embeds = None
     h = embed_tokens(params, cfg, tokens, frontend_embeds)
-    h, caches, _ = run_layers(params, cfg, h, 0, cfg.n_layers, mode="prefill",
-                              memory=memory)
+    h, caches, load = run_layers(params, cfg, h, 0, cfg.n_layers,
+                                 mode="prefill", memory=memory)
     if max_len > tokens.shape[1]:
         caches = pad_caches(cfg, caches, max_len)
-    return head(params, cfg, h[:, -1]), caches, memory
+    out = (head(params, cfg, h[:, -1]), caches, memory)
+    return out + (load,) if with_load else out
 
 
 def decode_step(params, cfg: ArchConfig, token, caches, step, *, memory=None):

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import dispatch
+from repro.models.moe import LOAD_FIELDS
 from repro.runtime import faults, observe, telemetry
 from repro.runtime.serve_api import RequestQueue
 from repro.runtime.stage_executor import StagePlacement
@@ -714,9 +715,11 @@ def _pool_tick(tok, c1, pos, active, start, budget, c_thr, *, s1, backend,
     (new_c1, hard slab, slab slot ids, slab steps, n_hard, easy mask,
     hard mask, emitted tokens, new tok lane, new pos lane, new active,
     per-slot exit confidences — the controller's reservoir feed, already
-    computed by the fused decision kernel so exposing it is free)."""
+    computed by the fused decision kernel so exposing it is free), and
+    last the MoE load counters stage 1 returns (an empty list for a model
+    without MoE layers)."""
     with jax.named_scope("pool_tick"):
-        h, nc1, exit_logits = s1(tok, c1, pos)
+        h, nc1, exit_logits, *load = s1(tok, c1, pos)
         nc1 = _seg_select(active, nc1, c1)
         # the decision kernel's pred IS the greedy token — one logits pass
         # serves both the exit decision and the emitted token
@@ -734,7 +737,7 @@ def _pool_tick(tok, c1, pos, active, start, budget, c_thr, *, s1, backend,
         new_pos = pos + easy.astype(jnp.int32)
         new_active = easy & (new_pos - start + 1 < budget)
         return (nc1, slab, slab_slots, slab_steps, n_hard, easy, hard, pred,
-                new_tok, new_pos, new_active, conf)
+                new_tok, new_pos, new_active, conf, load)
 
 
 @functools.partial(jax.jit, donate_argnums=(1, 6),
@@ -753,9 +756,9 @@ def _pool_tick_fused(tok, c1, pos, active, start, budget, ring, rows, c_thr,
     backpressure chain using the returned ``src``/``h``.
 
     Only valid on a non-disaggregated placement (one submesh cannot span
-    two)."""
+    two). Returns what ``_pool_tick`` does, the MoE load counters last."""
     with jax.named_scope("pool_tick_fused"):
-        h, nc1, exit_logits = s1(tok, c1, pos)
+        h, nc1, exit_logits, *load = s1(tok, c1, pos)
         nc1 = _seg_select(active, nc1, c1)
         n = tok.shape[0]
         lanes = jnp.arange(n, dtype=jnp.int32)  # slot index IS the ring id
@@ -768,7 +771,7 @@ def _pool_tick_fused(tok, c1, pos, active, start, budget, ring, rows, c_thr,
         new_pos = pos + easy.astype(jnp.int32)
         new_active = easy & (new_pos - start + 1 < budget)
         return (nc1, ring, h, src, n_hard, easy, hard, pred, new_tok,
-                new_pos, new_active, conf)
+                new_pos, new_active, conf, load)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5))
@@ -794,6 +797,17 @@ def _admit_stage1(c1_pool, tok, pos, active, start, budget, logits0, c1_rows,
             active.at[slots].set(budgets > 1),
             start.at[slots].set(position), budget.at[slots].set(budgets),
             tok0)
+
+
+def _record_load(program: str, load) -> None:
+    """The MoE load counters a program returned (``moe.LOAD_FIELDS``,
+    summed over its MoE layers), as the args of a ``moe_load`` span on the
+    open profile window's record. ``load`` is empty for a model without
+    MoE layers: nothing is recorded."""
+    if load:
+        with observe.annotate("moe_load", program=program, **dict(
+                zip(LOAD_FIELDS, (int(v) for v in load[0])))):
+            pass
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -1243,8 +1257,8 @@ class ContinuousScheduler:
         # tracer-conversion errors) keep the composed three-program tick;
         # any other failure is a real stage-1 bug and raises here.
         try:
-            h_av, _, _ = jax.eval_shape(self.fns.s1_raw, self._tok,
-                                        self._c1, self._pos)
+            h_av, *_ = jax.eval_shape(self.fns.s1_raw, self._tok,
+                                      self._c1, self._pos)
         except jax.errors.JAXTypeError:
             self._ring_row_spec = None
             return
@@ -1268,7 +1282,7 @@ class ContinuousScheduler:
                 self.stats.n_samples += 1
                 self.stats.record_submit(r.sample_id, r.arrival_time)
             with observe.annotate("prefill"):
-                logits0, caches = self.fns.prefill(
+                logits0, caches, *load = self.fns.prefill(
                     self.ex1.place_io(jnp.asarray(prompts)), self.max_len)
                 c1_rows, rows_rows = self.fns.split(caches)
             self._ensure_pool(c1_rows, rows_rows)
@@ -1303,7 +1317,9 @@ class ContinuousScheduler:
                                            self.ex2.place_io(rows_rows),
                                            self.ex2.place_io(slots_dev))
             with observe.annotate("admit_sync"):
-                tok0_np = np.asarray(tok0)       # one admission sync per chunk
+                # one admission sync per chunk
+                tok0_np, load_np = jax.device_get((tok0, load))
+            _record_load("prefill", load_np)
             for j, (r, slot) in enumerate(zip(reqs, slots)):
                 self.results[r.sample_id] = [int(tok0_np[j])]
                 self._sid[slot] = r.sample_id
@@ -1434,11 +1450,11 @@ class ContinuousScheduler:
                 bt_safe, step_safe = _sanitize_paged_bucket(
                     bucket["cache"], ids, bucket["step"],
                     sentinel=self.max_len)
-                logits, self._pool = self.fns.s2_paged(
+                logits, self._pool, *load = self.fns.s2_paged(
                     bucket["h"], bt_safe, step_safe, self._pool)
             else:
-                logits, new_rows = self.fns.s2(bucket["h"], bucket["cache"],
-                                               bucket["step"])
+                logits, new_rows, *load = self.fns.s2(
+                    bucket["h"], bucket["cache"], bucket["step"])
                 self._rows = _scatter_rows(self._rows, new_rows, ids)
         toks = _greedy_row(logits)
         # ex2 -> ex1 hop: greedy tokens come home to the slot lanes
@@ -1468,12 +1484,13 @@ class ContinuousScheduler:
                              take=take, capacity=self.sc.capacity)
         for slot in popped_slots:
             self._advance_slot(slot)
-        self._pending.append((entries, toks))
+        self._pending.append((entries, toks, *load))
         while len(self._pending) > self.sc.max_pending:
             self._harvest_one()
 
     def _harvest_one(self) -> None:
-        entries, toks = self._pending.popleft()
+        item = self._pending.popleft()
+        entries, toks, *load = item
         # bounded wait: a bucket whose device result never resolves raises
         # HarvestTimeout instead of blocking np.asarray forever — the
         # entries go back on the pending deque so a caller that survives
@@ -1483,9 +1500,10 @@ class ContinuousScheduler:
                 bounded_wait(toks, self.sc.harvest_timeout_s,
                              what=f"stage-2 bucket ({len(entries)} tokens)")
             except HarvestTimeout:
-                self._pending.appendleft((entries, toks))
+                self._pending.appendleft(item)
                 raise
-            toks_np = np.asarray(toks)
+            toks_np, load_np = jax.device_get((toks, load))
+        _record_load("bucket", load_np)
         for j, (sid, idx) in enumerate(entries):
             self.results[sid][idx] = int(toks_np[j])
 
@@ -1522,15 +1540,16 @@ class ContinuousScheduler:
         else:
             self._tick_composed()
 
-    def _finish_tick(self, n_hard_dev, easy, hard, pred, conf):
+    def _finish_tick(self, n_hard_dev, easy, hard, pred, conf, load):
         """The one per-tick host sync: n_hard (control flow) + the easy/
         hard masks, emitted tokens and confidences (results + the
-        controller's reservoir feed), fetched together. Emits easy tokens
-        and feeds the controller; returns the host-side pieces the hard
-        path needs."""
+        controller's reservoir feed) and the MoE load counters, fetched
+        together. Emits easy tokens and feeds the controller; returns the
+        host-side pieces the hard path needs."""
         with observe.annotate("finish_tick_sync"):
-            n_hard, easy_np, hard_np, emit_np, conf_np = jax.device_get(
-                (n_hard_dev, easy, hard, pred, conf))
+            n_hard, easy_np, hard_np, emit_np, conf_np, load_np = \
+                jax.device_get((n_hard_dev, easy, hard, pred, conf, load))
+        _record_load("tick", load_np)
         n_hard = int(n_hard)
         n_dec = int(easy_np.sum()) + n_hard
         self.stats.record_decisions(n_dec, n_hard)
@@ -1564,13 +1583,14 @@ class ContinuousScheduler:
     def _tick_composed(self) -> None:
         with observe.annotate("pool_tick"):
             (self._c1, slab, slots, steps, n_hard_dev, easy, hard, pred,
-             self._tok, self._pos, self._active_lane, conf) = _pool_tick(
+             self._tok, self._pos, self._active_lane, conf,
+             load) = _pool_tick(
                 self._tok, self._c1, self._pos, self._active_lane,
                 self._start_lane, self._budget_lane, self.c_thr,
                 s1=_tick_fn(self.fns.s1_raw),
                 backend=dispatch.kernel_backend(), mesh=self.ex1.mesh)
         n_hard, hard_np = self._finish_tick(n_hard_dev, easy, hard, pred,
-                                            conf)
+                                            conf, load)
         if n_hard > 0:
             self._park_hard(hard_np)
             # ex1 -> ex2 hop: the id lane crosses first (the cache gather
@@ -1592,14 +1612,14 @@ class ContinuousScheduler:
         with observe.annotate("pool_tick_fused"):
             (self._c1, ring_buf, h, src, n_hard_dev, easy, hard, pred,
              self._tok, self._pos, self._active_lane,
-             conf) = _pool_tick_fused(
+             conf, load) = _pool_tick_fused(
                 self._tok, self._c1, self._pos, self._active_lane,
                 self._start_lane, self._budget_lane, ring_buf, self._rows,
                 self.c_thr, s1=_tick_fn(self.fns.s1_raw),
                 backend=dispatch.kernel_backend())
         self.ring.put_buf(ring_buf)
         n_hard, hard_np = self._finish_tick(n_hard_dev, easy, hard, pred,
-                                            conf)
+                                            conf, load)
         if n_hard > 0:
             # the enqueue happened IN the tick; its fault boundary fires
             # here (same visit cadence as the composed path — once per

@@ -584,6 +584,9 @@ class DecodeFns(NamedTuple):
     s1: Callable        # (tok (B,1), c1, step) -> (h (B,d), c1', exit_logits)
     s2: Callable        # (h (C,d), cache_rows, step) -> (logits, new_rows)
     s1_raw: Callable    # s1's body, un-jitted (continuous pool tick)
+    # For a model with MoE layers, prefill, s1/s1_raw, s2 and s2_paged
+    # return one more value last: the MoE layers' summed load counters
+    # (moe.LOAD_FIELDS), fetched with what the host already syncs on.
     # paged stage-2 cache (None = dense). When set, the scheduler/server
     # store the stage-2 cache as page pools + per-slot block tables; the
     # ring's cache payload is the (max_pages,) i32 table row, not dense
@@ -622,11 +625,15 @@ def decode_stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
     else:
         p2 = params
 
+    # MoE load counters ride along; a dense model's stage calls stay as
+    # they were
+    load = {"with_load": True} if cfg.moe is not None else {}
+
     @functools.partial(jax.jit, static_argnames=("max_len",))
     def pf(p, tokens, max_len: int):
-        logits, caches, _ = T.prefill(p["backbone"], cfg, tokens,
-                                      max_len=max_len)
-        return logits, caches
+        logits, caches, _, *lc = T.prefill(p["backbone"], cfg, tokens,
+                                           max_len=max_len, **load)
+        return (logits, caches, *lc)
 
     @jax.jit
     def split(caches):
@@ -634,17 +641,19 @@ def decode_stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
         return c1, cache_rows_of(c2)
 
     def s1_body(p, tok, c1, step):
-        h, nc1, exit_logits = ee.stage1_decode(p, cfg, spec, tok, c1, step)
-        return h[:, 0], nc1, exit_logits
+        h, nc1, exit_logits, *lc = ee.stage1_decode(p, cfg, spec, tok, c1,
+                                                    step, **load)
+        return (h[:, 0], nc1, exit_logits, *lc)
 
     s1 = jax.jit(s1_body, donate_argnums=(2,))
 
     @functools.partial(jax.jit, donate_argnums=(2,))
     def s2(p, h_rows, cache_rows, step):
-        logits, nc = ee.stage2_decode(p, cfg, spec, h_rows[:, None],
-                                      cache_of_rows(cache_rows), step,
-                                      presliced_params=presliced)
-        return logits, cache_rows_of(nc)
+        logits, nc, *lc = ee.stage2_decode(p, cfg, spec, h_rows[:, None],
+                                           cache_of_rows(cache_rows), step,
+                                           presliced_params=presliced,
+                                           **load)
+        return (logits, cache_rows_of(nc), *lc)
 
     pf = functools.partial(pf, p_full)
     s1 = functools.partial(s1, p_full)
@@ -657,10 +666,11 @@ def decode_stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
 
     @functools.partial(jax.jit, donate_argnums=(4,))
     def s2_paged(p, h_rows, bt, step, pool):
-        logits, nc = ee.stage2_decode(p, cfg, spec, h_rows[:, None],
-                                      _inject_bt(pool, bt), step,
-                                      presliced_params=presliced)
-        return logits, _strip_bt(nc)
+        logits, nc, *lc = ee.stage2_decode(p, cfg, spec, h_rows[:, None],
+                                           _inject_bt(pool, bt), step,
+                                           presliced_params=presliced,
+                                           **load)
+        return (logits, _strip_bt(nc), *lc)
 
     s2_paged = functools.partial(s2_paged, p2)
 
@@ -771,11 +781,11 @@ class DecodeServer(_RingedServer):
             bt_safe, step_safe = _sanitize_paged_bucket(
                 bucket["cache"], bucket_ids, self._pos,
                 sentinel=self._max_len)
-            logits, self._pool = self.fns.s2_paged(bucket["h"], bt_safe,
-                                                   step_safe, self._pool)
+            logits, self._pool, *_ = self.fns.s2_paged(
+                bucket["h"], bt_safe, step_safe, self._pool)
         else:
-            logits, new_rows = self.fns.s2(bucket["h"], bucket["cache"],
-                                           self._pos)
+            logits, new_rows, *_ = self.fns.s2(bucket["h"], bucket["cache"],
+                                               self._pos)
             self._rows = _scatter_rows(self._rows, new_rows, bucket_ids)
         self._step_buckets.append((bucket_ids, logits))
 
@@ -785,7 +795,7 @@ class DecodeServer(_RingedServer):
         token lane starts as the decision kernel's pred (easy rows' argmax
         comes free with the exit decision) and hard rows are overwritten
         per bucket. Ring drains fully — decode is step-synchronous."""
-        h_rows, self._c1, exit_logits = self.fns.s1(tok, self._c1, pos)
+        h_rows, self._c1, exit_logits, *_ = self.fns.s1(tok, self._c1, pos)
         self._pos = pos
         self._step_buckets = []
         if self._use_fused():
@@ -847,7 +857,7 @@ class DecodeServer(_RingedServer):
         self.stats.n_samples += B
         self.ring.reset()                    # fresh ring per stream shape
         self._ids = self.ex1.place_io(jnp.arange(B, dtype=jnp.int32))
-        logits0, caches = self.fns.prefill(prompt, S + n_tokens)
+        logits0, caches, *_ = self.fns.prefill(prompt, S + n_tokens)
         self._c1, rows = self.fns.split(caches)
         if self.fns.page_size is not None:
             # paged parity mode: an identity block table (row b owns pages
@@ -918,7 +928,7 @@ class HostLoopDecoder:
         prompt = jnp.asarray(np.asarray(prompt, np.int32))
         B, S = prompt.shape
         self.stats.n_samples += B
-        logits0, caches = self.fns.prefill(prompt, S + n_tokens)
+        logits0, caches, *_ = self.fns.prefill(prompt, S + n_tokens)
         c1, rows = self.fns.split(caches)
         merged = np.asarray(logits0)
         logits_out, toks_out = [], []
@@ -930,7 +940,8 @@ class HostLoopDecoder:
             if t == n_tokens - 1:
                 break
             pos = S + t
-            h_rows, c1, exit_logits = self.fns.s1(jnp.asarray(tok), c1, pos)
+            h_rows, c1, exit_logits, *_ = self.fns.s1(jnp.asarray(tok), c1,
+                                                      pos)
             exit_mask, _, _ = ed.decision_and_argmax(exit_logits,
                                                      self.sc.c_thr)
             exit_mask = np.asarray(exit_mask)        # per-step host sync
@@ -944,7 +955,8 @@ class HostLoopDecoder:
                 bucket_h = jnp.stack([h_rows[i] for i in take])
                 bucket_cache = jax.tree.map(
                     lambda m: jnp.stack([m[i] for i in take]), rows)
-                logits, new_rows = self.fns.s2(bucket_h, bucket_cache, pos)
+                logits, new_rows, *_ = self.fns.s2(bucket_h, bucket_cache,
+                                                   pos)
                 lnp = np.asarray(logits)
                 for j, sid in enumerate(chunk):
                     merged[sid] = lnp[j]

@@ -1,5 +1,7 @@
 """Per-architecture smoke tests (reduced configs) + decode/prefill
 consistency for the cache machinery."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -72,13 +74,24 @@ def test_smoke_serve_batch(arch):
     assert_finite(out["logits"], f"{arch} serve logits")
 
 
+def _held_share(arch):
+    """DeepSeek-V2-Lite's layout with YaRN and a layer that holds 3 of 8
+    routed experts (one holder's share under expert parallelism)."""
+    cfg = get_smoke(arch)
+    return cfg.replace(moe=dataclasses.replace(cfg.moe, n_experts=8,
+                                               n_held=3))
+
+
 @pytest.mark.parametrize("arch", ["qwen2-1.5b", "qwen3-4b", "mamba2-130m",
                                   "recurrentgemma-9b", "deepseek-v2-lite-16b",
-                                  "grok-1-314b"])
+                                  "grok-1-314b", "deepseek-v2-lite-16b:held"])
 def test_decode_matches_forward(arch):
     """prefill(t[:n]) + decode_step(t[n]) logits == forward(t[:n+1]) last
     logits — the cache machinery is exact (fp32 smoke configs)."""
-    cfg = get_smoke(arch)
+    name, _, held = arch.partition(":")
+    cfg = _held_share(name) if held else get_smoke(name)
+    if held:
+        assert cfg.moe.held < cfg.moe.n_experts and cfg.rope_scaling
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     S = 9
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, S + 1), 0,

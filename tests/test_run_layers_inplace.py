@@ -184,7 +184,7 @@ def _stage2_hlo(cfg, spec, params):
     the paged bucket program where every layer is global attention, else
     the dense one (recurrent and windowed layers keep dense rows)."""
     fns = SL.decode_stage_fns(params, cfg, spec, page_size=4)
-    _, full = fns.prefill(jnp.zeros((B, S), jnp.int32), max_len=S + 4)
+    _, full, *_ = fns.prefill(jnp.zeros((B, S), jnp.int32), max_len=S + 4)
     _, rows = fns.split(full)
     h = jnp.zeros((B, cfg.d_model), cfg.act_dtype())
     step = jnp.zeros((B,), jnp.int32)

@@ -116,6 +116,35 @@ def test_paged_gather_append_compiles(one_chip):
     assert "tpu_custom_call" in txt
 
 
+def test_paged_gather_append_compiles_at_mla_widths(one_chip):
+    """DeepSeek-V2-Lite's paged pools: the MLA latent (512) and the rope
+    key (64) per token, page 16, the pages of 32 slots at 2560 positions."""
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+    slots, max_pages, n_pages = 32, 2560 // PAGE, 4097
+    txt = _compiled_text(
+        paged_gather_append_pallas,
+        s((n_pages, PAGE, 512), jnp.bfloat16),
+        s((n_pages, PAGE, 64), jnp.bfloat16),
+        s((slots, 512), jnp.bfloat16), s((slots, 64), jnp.bfloat16),
+        s((slots, max_pages), jnp.int32), s((slots,), jnp.int32))
+    assert "tpu_custom_call" in txt
+
+
+def test_held_expert_grouped_matmul_compiles(one_chip):
+    """The held-experts MoE layer's grouped matmul at DeepSeek-V2-Lite's
+    widths, 16 held experts of 1408: a 32-slot tick's 192 assignments
+    (top-6) lower to the chip's native ragged dot, whose FLOPs are those
+    of the rows (2 M K N), not of every group."""
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+    compiled = jax.jit(jax.lax.ragged_dot).lower(
+        s((192, 2048), jnp.bfloat16), s((16, 2048, 1408), jnp.bfloat16),
+        s((16,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["flops"] < 2 * 2 * 192 * 2048 * 1408
+
+
 def test_flash_attention_compiles(one_chip):
     s = lambda shape, dt: _spec(one_chip, shape, dt)
     H = CFG.n_heads
