@@ -10,6 +10,16 @@ as grouped matrix multiplications over the held stacks
 group and add nothing: that is the part the other holders would add. No
 expert has a capacity, so no assignment is dropped and a token's output
 does not depend on its batch; the shapes are static (T*k rows).
+
+The serving programs hand a layer the expert stacks of every layer,
+(L, n_held, ...), and its index in them (``run_layers``; not where a
+mesh splits the experts): the grouped matmuls read the layer's experts
+in place, as groups at offset ``layer * n_held`` of the stacks flattened
+to (L * n_held, ...), every other layer's group empty.
+The chip's ragged dot is a custom call, which cannot fuse a slice of its
+operand as a dense matmul does, so a layer's experts sliced out of the
+stack would be copied before every multiplication. A layer's own
+(n_held, ...) arrays are a stack of one.
 """
 from __future__ import annotations
 
@@ -25,6 +35,10 @@ from repro.models.layers import dense_init, init_mlp, mlp
 # routed assignments, assignments on held experts, held experts with at
 # least one row, the largest held group, and 1 (the number of layers)
 LOAD_FIELDS = ("routed", "held_rows", "touched", "max_group", "layers")
+
+# the held experts' stacked leaves, which ``run_layers`` hands every MoE
+# layer whole (see the module docstring)
+EXPERT_STACKS = ("e_gate", "e_up", "e_down")
 
 
 def init_moe(key, cfg: ArchConfig) -> dict:
@@ -63,30 +77,40 @@ def route(params, cfg: ArchConfig, x_flat) -> Tuple[jnp.ndarray, jnp.ndarray, jn
     return topk_idx, topk_w, aux
 
 
-def moe_fwd(params, cfg: ArchConfig, x, *, first: int = 0
+def moe_fwd(params, cfg: ArchConfig, x, *, first: int = 0, layer=None
             ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """x: (B, S, d) -> (out (B, S, d), aux_loss (), load (5,) int32).
     The layer holds experts [first, first + n_held); ``load`` counts as
-    ``LOAD_FIELDS`` says."""
+    ``LOAD_FIELDS`` says. With ``layer`` (an int32 scalar) the expert
+    leaves are stacks (L, n_held, ...) holding this layer's experts at
+    index ``layer``; without it, the layer's own (n_held, ...)."""
     m = cfg.moe
     B, S, d = x.shape
     T, k, G = B * S, m.top_k, m.held
     xf = x.reshape(T, d)
     topk_idx, topk_w, aux = route(params, cfg, xf)
+    experts = [params[n] for n in EXPERT_STACKS]
+    if layer is None:
+        experts, layer = [e[None] for e in experts], 0
+    L = experts[0].shape[0]
+    # merging the leading dims is a bitcast: the stacks are read in place
+    w_gate, w_up, w_down = (e.reshape((L * G,) + e.shape[2:])
+                            for e in experts)
 
-    # ---- dispatch: held assignments grouped by expert, absent ones last ---
+    # ---- dispatch: held assignments grouped by expert at the layer's
+    # offset in the stack, absent ones last; the other layers' groups empty
     local = topk_idx.reshape(-1) - first                 # (T*k,)
     held = (local >= 0) & (local < G)
-    group = jnp.where(held, local, G)
+    group = jnp.where(held, layer * G + local, L * G)
     order = jnp.argsort(group, stable=True)
-    sizes = jnp.bincount(group, length=G + 1)[:G].astype(jnp.int32)
+    sizes = jnp.bincount(group, length=L * G + 1)[:L * G].astype(jnp.int32)
     rows = xf[order // k]                                # (T*k, d)
 
     # ---- the held experts' grouped SwiGLU (rows past the groups: unset) ---
-    g = jax.lax.ragged_dot(rows, params["e_gate"], sizes)
-    u = jax.lax.ragged_dot(rows, params["e_up"], sizes)
+    g = jax.lax.ragged_dot(rows, w_gate, sizes)
+    u = jax.lax.ragged_dot(rows, w_up, sizes)
     h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-    y = jax.lax.ragged_dot(h, params["e_down"], sizes)  # (T*k, d)
+    y = jax.lax.ragged_dot(h, w_down, sizes)            # (T*k, d)
 
     # ---- combine: back to (token, slot) order, absent rows zeroed (what a
     # grouped matmul leaves past its groups is not specified), weighted sum
@@ -96,6 +120,7 @@ def moe_fwd(params, cfg: ArchConfig, x, *, first: int = 0
 
     if m.n_shared:
         out = out + mlp(params["shared"], xf, "swiglu")
+    # every other layer's group is empty: these count this layer's
     load = jnp.stack([jnp.int32(T * k), jnp.sum(sizes),
                       jnp.sum(sizes > 0).astype(jnp.int32), jnp.max(sizes),
                       jnp.int32(1)])

@@ -91,9 +91,11 @@ def _init_block_cache(cfg: ArchConfig, kind: str, batch: int, max_len: int,
 
 def _apply_block(params, cfg: ArchConfig, kind: str, h, *, mode: str,
                  cache=None, step=None, causal: bool = True,
-                 memory=None, dense_mlp: bool = False):
+                 memory=None, dense_mlp: bool = False, layer=None):
     """Returns (h, new_cache, aux): the load-balance loss in ``train``,
-    else an MoE block's load counters (zero for other blocks)."""
+    else an MoE block's load counters (zero for other blocks). ``layer``:
+    the block's index in its expert stacks, where ``params`` holds them
+    whole (``moe.moe_fwd``)."""
     aux = jnp.zeros((), jnp.float32)
     x = rmsnorm(params["norm1"], h, cfg.norm_eps)
     new_cache = cache
@@ -169,7 +171,7 @@ def _apply_block(params, cfg: ArchConfig, kind: str, h, *, mode: str,
         h = h + y
     if "moe" in params:
         x = rmsnorm(params["norm2"], h, cfg.norm_eps)
-        y, loss, load = moe_mod.moe_fwd(params["moe"], cfg, x)
+        y, loss, load = moe_mod.moe_fwd(params["moe"], cfg, x, layer=layer)
         # training sums the load-balance loss; serving sums the load
         # counters (moe.LOAD_FIELDS), which no dense block adds to
         aux = loss if mode == "train" else load
@@ -333,6 +335,23 @@ def embed_tokens(params, cfg: ArchConfig, tokens, frontend_embeds=None):
     return h
 
 
+def _is_expert_stack(path) -> bool:
+    """Whether a leaf of the stacked superblock params is an MoE block's
+    held-expert stack (``moe.EXPERT_STACKS``)."""
+    return (len(path) >= 2 and getattr(path[-2], "key", None) == "moe"
+            and getattr(path[-1], "key", None) in moe_mod.EXPERT_STACKS)
+
+
+def _experts_in_place() -> bool:
+    """Whether MoE blocks read their experts from the whole stacks. Not
+    under a published mesh with a model axis (``hints``): there
+    ``launch/shardings.py`` splits the stacks' expert dim over that axis,
+    and merging it with the layer dim would gather every layer's experts
+    on every device, so each layer's experts are indexed like any leaf."""
+    mesh = hints.mesh()
+    return mesh is None or dict(mesh.shape).get("model", 1) <= 1
+
+
 def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
                caches=None, step=None, memory=None, causal: bool = True,
                cache_base_sb: int = 0, param_base_sb: int = 0):
@@ -347,9 +366,13 @@ def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
 
     The stacked superblock params are never copied in ``decode`` and
     ``prefill``: the scan runs over layer indices and each iteration reads
-    its layer from the stack in place. Only ``train`` slices the stack to
-    [lo, hi) and scans over the slice, so that the backward pass
-    accumulates a segment-sized gradient."""
+    its layer from the stack in place. A dense matmul fuses that index
+    into its operand; the chip's ragged dot, a custom call, cannot, so an
+    MoE block gets its expert stacks whole with its index in them and
+    reads its experts at that offset (``moe.moe_fwd``), except where a
+    mesh splits the stacks' experts (``_experts_in_place``). Only ``train``
+    slices the stack to [lo, hi) and scans over the slice, so that the
+    backward pass accumulates a segment-sized gradient."""
     aux = jnp.zeros((), jnp.float32)
     new_caches: Dict[str, Any] = {"first": [], "blocks": None, "rem": []}
 
@@ -376,15 +399,18 @@ def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
             # which float32 programs pay for in temporaries, and rounds
             # the gradients differently on the chip
             param_xs = jax.tree.map(lambda x: x[p_lo:p_hi], params["blocks"])
-            layer_params = lambda bp: bp
+            layer_params = lambda bp: (bp, None)
         else:
             # index the stack in place: a sliced xs is materialized as a
-            # segment-sized copy before the loop on every call
+            # segment-sized copy before the loop on every call; the expert
+            # stacks stay whole, read at the layer's offset
             param_xs = jnp.arange(p_lo, p_hi, dtype=jnp.int32)
-            layer_params = lambda i: jax.tree.map(
-                lambda x: jax.lax.dynamic_index_in_dim(
+            in_place = _experts_in_place()
+            layer_params = lambda i: (jax.tree_util.tree_map_with_path(
+                lambda path, x: x if in_place and _is_expert_stack(path)
+                else jax.lax.dynamic_index_in_dim(
                     x, i, keepdims=False, allow_negative_indices=False),
-                params["blocks"])
+                params["blocks"]), i if in_place else None)
         c_lo, c_hi = s_lo - cache_base_sb, s_hi - cache_base_sb
         seg_caches = (jax.tree.map(lambda x: x[c_lo:c_hi], caches["blocks"])
                       if caches else None)
@@ -392,14 +418,15 @@ def run_layers(params, cfg: ArchConfig, h, lo: int, hi: int, *, mode: str,
         def body(carry, xs):
             hh = carry
             bp, bc = xs
-            bp = layer_params(bp)
+            bp, layer = layer_params(bp)
             a_tot = jnp.zeros((), jnp.float32)
             ncs = []
             for pos in range(pl):
                 c = bc[pos] if bc is not None else None
                 hh, nc, a = _apply_block(bp[pos], cfg, cfg.pattern[pos], hh,
                                          mode=mode, cache=c, step=step,
-                                         causal=causal, memory=memory)
+                                         causal=causal, memory=memory,
+                                         layer=layer)
                 ncs.append(nc)
                 a_tot = a_tot + a
             return hh, (tuple(ncs) if mode != "train" else None, a_tot)

@@ -10,7 +10,9 @@ Pallas kernel (``tpu_custom_call``), i.e. nothing routed around it. One
 more reads the compiler's temporaries for the training gradient, which only
 the chip's compiler counts as the chip would.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +21,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.archs import QWEN2_1_5B as CFG
+from repro.configs.archs import DEEPSEEK_V2_LITE, QWEN2_1_5B as CFG
 from repro.core import early_exit as ee
 from repro.core import losses
 from repro.kernels import dispatch
@@ -28,6 +30,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.fused_dispatch.kernel import fused_dispatch_pallas
 from repro.kernels.gather_compact.kernel import gather_compact_pallas
 from repro.kernels.paged_attention.kernel import paged_gather_append_pallas
+from repro.models import moe as moe_mod
 from repro.models.config import ArchConfig
 
 V, D = CFG.vocab, CFG.d_model
@@ -143,6 +146,39 @@ def test_held_expert_grouped_matmul_compiles(one_chip):
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     assert cost["flops"] < 2 * 2 * 192 * 2048 * 1408
+
+
+def test_held_experts_read_in_place_from_the_stack(one_chip):
+    """A scan over three MoE layers at DeepSeek-V2-Lite's widths, each
+    layer's router indexed in the stack and its 16 held experts read at
+    the layer's offset (``moe_fwd(layer=)``): the chip's ragged dot takes
+    the (48, 2048, 1408) stacks as they are, and no layer's experts are
+    sliced out of them into a (16, 2048, 1408) copy first."""
+    n = 3
+    cfg = DEEPSEEK_V2_LITE.replace(moe=dataclasses.replace(
+        DEEPSEEK_V2_LITE.moe, n_held=16, n_shared=0))
+    s = lambda shape, dt: _spec(one_chip, shape, dt)
+    stacks = {"e_gate": s((n, 16, 2048, 1408), jnp.bfloat16),
+              "e_up": s((n, 16, 2048, 1408), jnp.bfloat16),
+              "e_down": s((n, 16, 1408, 2048), jnp.bfloat16)}
+
+    def layers(stacks, router, x):
+        def body(h, i):
+            p = dict(stacks, router=jax.lax.dynamic_index_in_dim(
+                router, i, keepdims=False))
+            y, _, load = moe_mod.moe_fwd(p, cfg, h, layer=i)
+            return h + y, load
+        return jax.lax.scan(body, x, jnp.arange(n, dtype=jnp.int32))
+
+    txt = _compiled_text(layers, stacks, s((n, 2048, 64), jnp.float32),
+                         s((32, 1, 2048), jnp.bfloat16))
+    assert not re.search(r"dynamic-slice\S* = bf16\[16,(2048,1408|1408,2048)\]",
+                         txt)
+    shape_of = dict(re.findall(r"(%[\w.-]+) = (\w+\[[\d,]*\])", txt))
+    dots = re.findall(r"%ragged-dot-none\S* = .*?custom-call\((.*?)\)", txt)
+    assert len(dots) == 3
+    assert {shape_of[d.split(", ")[-1]] for d in dots} == {
+        "bf16[48,2048,1408]", "bf16[48,1408,2048]"}
 
 
 def test_flash_attention_compiles(one_chip):
