@@ -41,7 +41,7 @@ from typing import Any, Optional
 
 
 def _lookup(tree: dict, path: str) -> Optional[Any]:
-    """Resolve a dotted path ('serve_decode.q0.3.speedup') against nested
+    """Resolve a dotted path ('serve_pipeline.q0.3.speedup') against nested
     dicts. Keys themselves may contain dots ('q0.3'), so greedily match the
     longest key prefix at each level."""
     node: Any = tree

@@ -38,11 +38,10 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     from benchmarks import (fig9_tap, kernel_dispatch, roofline,
-                            serve_continuous, serve_decode, serve_drift,
-                            serve_fleet, serve_migration, serve_observed,
-                            serve_paged, serve_pipeline, table1_resources,
-                            table2_overhead, table3_throughput,
-                            table4_networks)
+                            serve_drift, serve_fleet, serve_migration,
+                            serve_observed, serve_paged, serve_pipeline,
+                            table1_resources, table2_overhead,
+                            table3_throughput, table4_networks)
     seeds = 1 if args.fast else 3
     benches = [
         ("fig9_tap", lambda: fig9_tap.run(n_seeds=seeds)),
@@ -53,8 +52,6 @@ def main(argv=None) -> int:
         ("roofline", roofline.run),
         ("kernel_dispatch", lambda: kernel_dispatch.run(fast=args.fast)),
         ("serve_pipeline", lambda: serve_pipeline.run(fast=args.fast)),
-        ("serve_decode", lambda: serve_decode.run(fast=args.fast)),
-        ("serve_continuous", lambda: serve_continuous.run(fast=args.fast)),
         ("serve_paged", lambda: serve_paged.run(fast=args.fast)),
         ("serve_drift", lambda: serve_drift.run(fast=args.fast)),
         ("serve_migration", lambda: serve_migration.run(fast=args.fast)),

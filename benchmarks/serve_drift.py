@@ -86,14 +86,37 @@ def conf_of(sid, t, difficulty):
     return np.clip(raw, _CONF_LO, _CONF_HI)
 
 
+def sid_pages(s2, page_size: int = 2) -> dict:
+    """The paged stage-2 surface (``DecodeFns`` fields) of a toy whose
+    stage-2 cache row is ``{"sid": (B, 1)}``: admission writes each sample
+    id into its row's first page, and ``s2_paged`` reads it back through
+    the block table and runs the toy's dense ``s2`` on it — so the
+    scheduler's page pool, block tables, allocator and bucket sanitising
+    carry the stream exactly as they carry a model's cache."""
+
+    def pool_init(rows, n_pages):
+        return jnp.zeros((n_pages, page_size), jnp.int32)
+
+    def admit_pages(pool, rows, bt_rows):
+        return pool.at[bt_rows[:, 0], 0].set(rows["sid"][:, 0])
+
+    def s2_paged(h_rows, bt, step, pool):
+        logits, _ = s2(h_rows, {"sid": pool[bt[:, 0], :1]}, step)
+        return logits, pool
+
+    return dict(page_size=page_size, s2_paged=s2_paged,
+                pool_init=pool_init, admit_pages=admit_pages)
+
+
 def drift_fns(difficulty: np.ndarray, d_model: int = 96, burn1: int = 2,
               burn2: int = 16) -> SL.DecodeFns:
     """A ``DecodeFns`` with analytic confidences/tokens and real matmul
     burn: stage 1 applies ``burn1`` (d, d) matmuls per tick, stage 2
     ``burn2`` per bucket row — the deep-half cost asymmetry that makes a
     drifted hard rate expensive. The sample id rides the stage-1 cache and
-    the stage-2 row payload (exactly like the scheduler property tests'
-    toy fns), so the full ring/bucket machinery is exercised.
+    the stage-2 page pool (``sid_pages``, exactly like the scheduler
+    property tests' toy fns), so the full ring/bucket/page machinery is
+    exercised.
 
     Exit logits are ``z * one_hot(token)`` with z solved so the row's
     max-softmax confidence is EXACTLY ``conf_of(sid, t, difficulty[sid])``
@@ -140,7 +163,9 @@ def drift_fns(difficulty: np.ndarray, d_model: int = 96, burn1: int = 2,
         shift = _burn(h_rows, w2, burn2)
         return _logits(sid, step - _S + 1) + shift, cache_rows
 
-    return SL.DecodeFns(prefill, split, jax.jit(s1_raw), jax.jit(s2), s1_raw)
+    s2 = jax.jit(s2)
+    return SL.DecodeFns(prefill, split, jax.jit(s1_raw), s2, s1_raw,
+                        **sid_pages(s2))
 
 
 # ---------------------------------------------------------------------------

@@ -25,8 +25,7 @@ Gates (``benchmarks/compare.py`` against ``baseline_cpu.json``):
     >10% over tens of seconds, and both sides of a back-to-back pair see
     the same state); alternating which side runs first inside each pair
     cancels the residual within-pair drift, and the median sheds
-    stall-poisoned pairs. Same scheme as serve_continuous, with more
-    pairs because this floor is far tighter than its 1.3x one;
+    stall-poisoned pairs;
   * ``equivalence`` — observed token streams bitwise-equal to both the
     unobserved scheduler and the ``HostLoopDecoder`` oracle (tracing must
     never perturb results);
@@ -54,14 +53,14 @@ from repro.runtime import serve_loop as SL
 from repro.runtime.scheduler import Request, poisson_arrivals
 from repro.runtime.telemetry import EventLog
 
-ARRIVAL_RATE = 2000.0      # saturating (see serve_continuous)
+ARRIVAL_RATE = 2000.0      # saturating: the pool stays full
+PAGE = 16                  # stage-2 page size, as every chip cell serves
 Q = 0.3                    # the CI-gated operating point
 
 
 def _bench_cfg() -> ArchConfig:
-    """Wider than serve_continuous's bench model ON PURPOSE: that bench
-    wants scheduling overhead visible against near-zero tick compute, but
-    the observability contract is about a REAL serving load, where a tick
+    """Wider than the other CPU serving benches' model ON PURPOSE: the
+    observability contract is about a REAL serving load, where a tick
     costs model-forward time and the plane's per-event host work must
     amortize into it. d_model=32 would charge the plane against μs ticks
     and gate a regime no deployment runs in."""
@@ -112,17 +111,16 @@ def _dark_pass(fns, sc, n_slots, max_len, reqs):
 
 
 def run(fast: bool = False) -> dict:
-    # Longer passes than serve_continuous's: the gate is a hard 5%-overhead
-    # floor, and per-pass noise (GC pauses, CPU steal on shared runners)
-    # amortizes with makespan — a 20ms stall is 5% of a 0.4s pass but 1.5%
-    # of a 1.3s one.
+    # Long passes: the gate is a hard 5%-overhead floor, and per-pass noise
+    # (GC pauses, CPU steal on shared runners) amortizes with makespan — a
+    # 20ms stall is 5% of a 0.4s pass but 1.5% of a 1.3s one.
     seq = 8
     if fast:
         n_requests, n_slots, tok_choices = 96, 8, (6, 8, 12, 40)
     else:
         n_requests, n_slots, tok_choices = 144, 16, (6, 8, 12, 40)
     max_tok = max(tok_choices)
-    max_len = seq + max_tok
+    max_len = seq + max_tok                     # 48: a whole number of pages
     cfg = _bench_cfg()
     spec0 = ee.EarlyExitSpec(exit_layer=2, c_thr=0.5)
     params = ee.init_ee_params(jax.random.PRNGKey(0), cfg, spec0)
@@ -131,7 +129,7 @@ def run(fast: bool = False) -> dict:
     n_tokens = np.random.default_rng(7).choice(tok_choices, size=n_requests)
     conf = SL.decode_step0_confidences(params, cfg, spec0, prompts[:n_slots],
                                        max_len=max_len)
-    fns = SL.decode_stage_fns(params, cfg, spec0)
+    fns = SL.decode_stage_fns(params, cfg, spec0, page_size=PAGE)
     c_thr = float(jnp.quantile(conf, Q))
     capacity = max(2, int(np.ceil(Q * n_slots)))
     sc = SL.ServeConfig(capacity=capacity, queue_depth=4, c_thr=c_thr)

@@ -116,7 +116,8 @@ def run(fast: bool = False, chips1: Optional[int] = None,
         parity = True
         if placement is not None:
             sub = toks[:2 * batch]
-            dis = SL.build_server(params, cfg, spec, sc, placement)
+            dis = SL.build(params, cfg, spec, sc, mode="prefill",
+                           scheduler=None, placement=placement)
             r_dis = SL.serve_dataset(dis, sub, batch=batch)
             r_one = SL.serve_dataset(SL.TwoStageServer(s1, s2, sc), sub,
                                      batch=batch)

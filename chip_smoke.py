@@ -1,6 +1,6 @@
 """Chip smoke: serve full-width qwen2-1.5b on a TPU through the continuous
-paged scheduler that ``python -m repro.launch.serve --mode decode
---scheduler continuous --page-size 16`` runs, and check what comes out.
+paged scheduler that ``python -m repro.launch.serve --mode decode`` runs
+(page size 16), and check what comes out.
 
     python chip_smoke.py              # one chip
     python chip_smoke.py --chips 4    # stage-disaggregated serving vs one chip

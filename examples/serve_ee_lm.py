@@ -11,9 +11,10 @@ decision + compaction via the kernel dispatch layer, device ring buffer,
 async bucket drains) -> report throughput, realized q, bucket occupancy,
 and verify every request got an answer consistent with the one-shot
 pipeline. Then the same model generates continuations through the
-decode-time DecodeServer (per-token exit decisions; hard tokens' hidden
-rows + stage-2 KV-cache segment rows through the pytree ring) and the
-output is verified bitwise against the host-loop decode baseline."""
+continuous decode scheduler (per-token exit decisions; hard tokens' hidden
+rows + stage-2 block-table rows through the pytree ring, stage 2 over the
+paged KV pool) and every token stream is checked against the host-loop
+decode reference."""
 import argparse
 import time
 
@@ -90,11 +91,16 @@ c_thr_dec = ed.calibrate_threshold(dec_conf,
                                    target_exit_rate=1.0 - args.target_p)
 spec_dec = ee.EarlyExitSpec(exit_layer=spec0.exit_layer, c_thr=c_thr_dec)
 sc_dec = SL.ServeConfig(capacity=cap, c_thr=c_thr_dec)
-fns = SL.decode_stage_fns(params, cfg, spec_dec)
+page = 16
+max_len = -(-(args.seq + args.decode_tokens) // page) * page
 
-dec = SL.DecodeServer(fns, sc_dec)
+dec = SL.build(params, cfg, spec_dec, sc_dec, mode="decode",
+               n_slots=args.batch, max_len=max_len, page_size=page)
+for i, prompt in enumerate(prompts):
+    dec.submit(SL.Request(sample_id=i, prompt=prompt,
+                          n_tokens=args.decode_tokens))
 t0 = time.perf_counter()
-gen = dec.generate(prompts, args.decode_tokens)
+gen = dec.run()
 dt = time.perf_counter() - t0
 n_decode = args.batch * (args.decode_tokens - 1)
 s = dec.stats
@@ -104,9 +110,10 @@ print(f"decode realized q={s.realized_q:.3f} (per token)  "
       f"token exits: {s.n_exited}  stage-2 tokens: {s.n_stage2}  "
       f"stalls: {s.n_stalls}  mean bucket fill {s.mean_bucket_fill:.2f}")
 
-# bitwise parity against the host-loop decode baseline
-ref = SL.HostLoopDecoder(fns, sc_dec).generate(prompts, args.decode_tokens)
-assert np.array_equal(gen["tokens"], ref["tokens"]), "decode token drift!"
-assert np.array_equal(gen["logits"], ref["logits"]), "decode logits drift!"
-print("decode output bitwise-identical to the host-loop baseline")
+# every stream equals the host-loop decode reference's
+ref = SL.build(params, cfg, spec_dec, sc_dec, mode="decode", scheduler=None,
+               host=True).generate(prompts, args.decode_tokens)
+for i in range(args.batch):
+    assert gen[i] == ref["tokens"][i].tolist(), f"decode drift, request {i}"
+print("decode streams identical to the host-loop reference")
 print("OK")

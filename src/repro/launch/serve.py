@@ -7,23 +7,15 @@ hard samples carried between batches in the device ring buffer) and pushes
 batched requests with a controlled hard-fraction q.
 
 ``--mode decode`` serves open-loop decode requests (Poisson arrivals at
-``--arrival-rate``, default: all at t=0) under a scheduling policy:
-
-  * ``--scheduler sync`` (default): static batch formation over the
-    step-synchronous ``DecodeServer`` — full-depth prefill per batch, then
-    per-token two-stage decode in lockstep, hard tokens' hidden rows +
-    stage-2 KV-cache segment rows through the pytree ring into bucketed
-    stage-2 dispatches;
-  * ``--scheduler continuous``: the slot-based ``ContinuousScheduler``
-    (``runtime/scheduler.py``) — a fixed pool of ``--batch`` decode slots
-    with per-slot step counters, backfilled from the admission queue; easy
-    samples keep decoding through stage 1 while hard tokens wait in the
-    ring for bucketed stage-2 dispatch. Trades the sync policy's bitwise
-    batch parity for utilization; per-sample token streams stay identical.
-
-Reports goodput (decode tokens/s), per-request latency percentiles and
-per-token stats — the runtime half of the ATHEENA pipeline in both
-regimes.
+``--arrival-rate``, default: all at t=0) through the slot-based
+``ContinuousScheduler`` (``runtime/scheduler.py``): a fixed pool of
+``--batch`` decode slots with per-slot step counters, backfilled from the
+admission queue; easy samples keep decoding through stage 1 while hard
+tokens wait in the ring for bucketed stage-2 dispatch over the paged
+stage-2 KV pool (``--page-size`` tokens per page; the pool's cache width
+is ``--seq + --decode-tokens`` rounded up to a whole page). Reports goodput
+(decode tokens/s), per-request latency percentiles and per-token stats —
+the runtime half of the ATHEENA pipeline.
 
 ``--controller`` attaches the online drift control plane
 (``runtime/controller.py``) to the decode scheduler: when the EWMA of the
@@ -32,7 +24,7 @@ around the provisioned ``--p``, C_thr is re-solved online from the rolling
 confidence reservoir (and the scheduler's drain policy / live-slot cap
 adapt from latency+occupancy feedback); past the re-plan band the Eq. (1)
 stage re-plan is reported, and APPLIED under ``--controller-replan``: on a
-disaggregated continuous scheduler the full chip re-split executes as a
+disaggregated placement the full chip re-split executes as a
 zero-downtime live migration (``runtime/migration.py`` — quiesce /
 snapshot / re-place / resume, rolled back on failure), otherwise the
 bucket-capacity half applies alone. The controller's state machine report
@@ -124,6 +116,13 @@ def serve_requests(sched, prompts: np.ndarray, n_tokens: int,
     streams = {sid: results[sid] for sid in sids}
     assert all(len(v) == n_tokens for v in streams.values())
     return streams
+
+
+def _pool_width(args) -> int:
+    """The scheduler's cache width: prompt + generated tokens, rounded up
+    to a whole page (the paged pool's width is a page multiple)."""
+    need = args.seq + args.decode_tokens
+    return -(-need // args.page_size) * args.page_size
 
 
 def _summarized_stats(stats) -> dict:
@@ -235,14 +234,11 @@ def _serve_fleet(args, cfg, spec, params, sc, placement) -> int:
     schedulers sharing one clock; requests cycle over the --tenant-slos
     tenants. Prints the FleetStats schema (per-replica ServeStats
     embedded, q series summarized)."""
-    if args.scheduler != "continuous":
-        raise SystemExit("--replicas > 1 routes over continuous-scheduler "
-                         "replicas; pass --scheduler continuous")
     tenant_slos = _parse_tenant_slos(args.tenant_slos)
     tenants = list(tenant_slos)
     prompts = np.asarray(jax.random.randint(
         jax.random.PRNGKey(1), (args.requests, args.seq), 0, cfg.vocab))
-    max_len = args.seq + args.decode_tokens
+    max_len = _pool_width(args)
     clock = Clock()
     obs = _setup_observability(args)
     replicas = [serve_api.build(params, cfg, spec, sc, mode="decode",
@@ -304,11 +300,6 @@ def main(argv=None) -> int:
                     help="request length (prompt length in decode mode)")
     ap.add_argument("--decode-tokens", type=int, default=32,
                     help="tokens to generate per request (decode mode)")
-    ap.add_argument("--scheduler", default="sync",
-                    choices=("sync", "continuous"),
-                    help="decode scheduling policy: static batch formation "
-                         "over the step-synchronous server, or the "
-                         "slot-based continuous scheduler")
     ap.add_argument("--replicas", type=int, default=1,
                     help="serve decode through a FleetRouter over N "
                          "replica schedulers (1 = single-replica, no "
@@ -325,15 +316,13 @@ def main(argv=None) -> int:
                          "gold/standard/batch), e.g. 'web=gold,batch=batch'."
                          " Requests cycle over the listed tenants; default: "
                          "one 'default' tenant at standard")
-    ap.add_argument("--page-size", type=int, default=None,
-                    help="enable the paged KV cache with this page size "
-                         "(decode modes; seq+decode-tokens must be a "
-                         "multiple)")
+    ap.add_argument("--page-size", type=int, default=16,
+                    help="tokens per page of the stage-2 KV pool (decode "
+                         "mode)")
     ap.add_argument("--n-pages", type=int, default=None,
-                    help="page-pool capacity in pages (continuous paged "
-                         "mode; default: dense-equivalent "
-                         "n_slots*max_len/page — shrink it to trade "
-                         "admission backpressure for HBM)")
+                    help="page-pool capacity in pages (decode mode; "
+                         "default: n_slots * pool width / page — shrink "
+                         "it to trade admission backpressure for HBM)")
     ap.add_argument("--arrival-rate", type=float, default=float("inf"),
                     help="open-loop Poisson request rate (req/s) for decode "
                          "mode; inf = all requests arrive at t=0")
@@ -358,7 +347,7 @@ def main(argv=None) -> int:
                     help="APPLY the stage re-plan at discrete re-plan "
                          "points (default: report only): a zero-downtime "
                          "live migration of the full chip split on a "
-                         "disaggregated continuous scheduler, else the "
+                         "disaggregated placement, else the "
                          "bucket-capacity half alone")
     ap.add_argument("--disaggregate", action="store_true",
                     help="stage 1 / stage 2 on disjoint submeshes")
@@ -417,10 +406,10 @@ def main(argv=None) -> int:
     if args.mode == "decode":
         prompts = np.asarray(jax.random.randint(
             jax.random.PRNGKey(1), (args.requests, args.seq), 0, cfg.vocab))
-        max_len = args.seq + args.decode_tokens
+        max_len = _pool_width(args)
         obs = _setup_observability(args)
         sched = serve_api.build(params, cfg, spec, sc, mode="decode",
-                                scheduler=args.scheduler,
+                                scheduler="continuous",
                                 placement=placement, n_slots=args.batch,
                                 max_len=max_len,
                                 page_size=args.page_size,
@@ -452,7 +441,7 @@ def main(argv=None) -> int:
         n_tok = sum(len(v) for v in results.values())
         stats = _summarized_stats(sched.stats)
         payload = {"arch": args.arch, "mode": "decode",
-                   "scheduler": args.scheduler, "capacity": cap,
+                   "scheduler": "continuous", "capacity": cap,
                    "n_slots": args.batch,
                    "arrival_rate": args.arrival_rate,
                    "goodput_tokens_per_s": n_tok / makespan,

@@ -7,7 +7,7 @@ distribution, silently invalidating the provisioned design — the runtime
 threshold/hardware co-adaptation HAPI (Laskaridis et al., 2020) and the
 adaptive-inference survey identify as the piece offline DSE cannot cover.
 ``DriftController`` closes the loop over the serving telemetry the
-schedulers already sync:
+scheduler already syncs:
 
     SENSE ──> FILTER ──> HYSTERESIS ──> ACTUATE
       │          │            │             │
@@ -174,14 +174,14 @@ class ControllerState:
 
 class DriftController:
     """The closed loop. Attach to a scheduler (``attach``), and the
-    scheduler's hot loop calls ``on_tick`` once per pool tick (continuous)
-    or per static batch (sync) with the scalars it synced anyway.
+    scheduler's hot loop calls ``on_tick`` once per pool tick with the
+    scalars it synced anyway.
 
     Actuators are duck-typed against the scheduler's control surface:
-    whatever the policy exposes is driven (``set_c_thr`` everywhere;
-    ``set_active_cap``/``set_eager_drain_below``/``request_capacity`` on
-    the continuous scheduler), the rest is skipped — so one controller
-    drives both policies without either growing a fake interface.
+    ``set_c_thr`` is always driven; ``set_active_cap``/
+    ``request_capacity``/``request_migration`` only where the scheduler
+    exposes them, so a partial control surface (a test fake) is driven as
+    far as it goes.
 
     ``taps`` (optional) are the profiled (stage-1, stage-2) TAP curves and
     ``chips`` the deployment budget: with them the re-plan actuator runs
@@ -221,24 +221,13 @@ class DriftController:
 
     def attach(self, sched):
         """Wire this controller into a scheduler: the scheduler's hot loop
-        starts calling ``on_tick``, its stats gain the provisioned p (the
-        windowed ``q_drift`` view), and — on the sync policy — the
-        underlying server's confidence sink feeds the reservoir. Returns
-        the scheduler for chaining."""
+        starts calling ``on_tick`` (with the live rows' confidences, which
+        feed the reservoir) and its stats gain the provisioned p (the
+        windowed ``q_drift`` view). Returns the scheduler for chaining."""
         sched.controller = self
         sched.stats.provisioned_p = self.cfg.provisioned_p
-        self.state.c_thr = float(self._current_thr(sched))
-        server = getattr(sched, "server", None)
-        if server is not None and hasattr(server, "conf_sink"):
-            server.conf_sink = self.reservoir
+        self.state.c_thr = float(sched.c_thr)
         return sched
-
-    @staticmethod
-    def _current_thr(sched) -> float:
-        thr = getattr(sched, "c_thr", None)
-        if thr is None:
-            thr = sched.server.c_thr
-        return thr
 
     # -- the loop ------------------------------------------------------------
 
@@ -312,7 +301,7 @@ class DriftController:
         target = ed.calibrate_threshold(self.reservoir.snapshot(),
                                         target_exit_rate=1.0
                                         - cfg.provisioned_p)
-        prev = st.c_thr if st.c_thr is not None else self._current_thr(sched)
+        prev = st.c_thr if st.c_thr is not None else sched.c_thr
         step = max(-cfg.max_thr_step, min(cfg.max_thr_step, target - prev))
         new = prev + step
         if new == prev:

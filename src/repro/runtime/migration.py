@@ -26,8 +26,8 @@ the stack LIFO and serving resumes on the OLD placement):
               ``DecodeFns`` re-slice params per ``ee.split_params`` onto
               the new submeshes), rebuild the ring at the new capacity on
               the new stage-2 executor, and ``jax.device_put`` the slot
-              lanes / pooled stage-1 cache / stage-2 row store under the
-              new placement's NamedShardings (``elastic.relayout``'s move,
+              lanes / pooled stage-1 cache / block tables / page pool
+              under the new placement's NamedShardings (``elastic.relayout``'s move,
               applied to live serving state).
     RESUME    re-open admission and record the measured pause
               (admission-closed -> admission-reopened wall time) in
@@ -107,9 +107,8 @@ _DEVICE_STATE: Tuple[Tuple[str, int, bool], ...] = (
     ("_tok", 1, True), ("_pos", 1, True), ("_active_lane", 1, True),
     ("_start_lane", 1, True), ("_budget_lane", 1, True),
     ("_c1", 1, False), ("_rows", 2, True),
-    # the paged stage-2 page pool (None on dense schedulers / cold pools —
-    # the relayout loop skips None attrs); replicated like _c1: its leaves
-    # lead with page/superblock axes, never the batch axis
+    # the stage-2 page pool, replicated like _c1: its leaves lead with
+    # page/superblock axes, never the batch axis
     ("_pool", 2, False),
 )
 
@@ -177,15 +176,14 @@ class LiveMigrator:
         # the page allocator's free list: an EXACT state capture (its own
         # defensive-copy snapshot — the lane is donated by frees, so a bare
         # ref would not survive post-rollback serving)
-        alloc = getattr(s, "_alloc", None)
-        alloc_snap = alloc.snapshot() if alloc is not None else None
+        alloc = s._alloc
+        alloc_snap = alloc.snapshot()
 
         def restore():
             for attr, val in snap.items():
                 setattr(s, attr, val)
             s.stats.stage1_chips, s.stats.stage2_chips = chips
-            if alloc is not None:
-                alloc.restore(alloc_snap)
+            alloc.restore(alloc_snap)
         self._compensations.append(("restore-snapshot", restore))
 
     def _replace(self) -> None:
@@ -193,12 +191,11 @@ class LiveMigrator:
         s, plan = self.sched, self.plan
         new_pl = plan.placement if plan.placement is not None else s.placement
         new_fns = plan.fns if plan.fns is not None else s.fns
-        if getattr(s, "_paged", False) and (
-                getattr(new_fns, "s2_paged", None) is None
+        if (getattr(new_fns, "s2_paged", None) is None
                 or getattr(new_fns, "page_size", None) != s.page_size):
             raise MigrationError(
-                "a paged scheduler can only migrate onto stage fns built "
-                f"with the same page_size={s.page_size} "
+                "a scheduler can only migrate onto stage fns built with "
+                f"the same page_size={s.page_size} "
                 "(decode_stage_fns(page_size=...)) — the live page pool's "
                 "layout is not convertible mid-serve")
         cap = (s.sc.capacity if plan.capacity is None
@@ -218,17 +215,12 @@ class LiveMigrator:
         # when the pool is cold (nothing admitted yet).
         if s._c1 is not None:
             for attr, stage, io in _DEVICE_STATE:
-                val = getattr(s, attr)
-                if val is None:              # e.g. _pool on a dense pool
-                    continue
                 ex = s.ex1 if stage == 1 else s.ex2
                 put = ex.place_io if io else ex.place
-                setattr(s, attr,
-                        faults.retry(put, val, what=f"relayout:{attr}"))
-            alloc = getattr(s, "_alloc", None)
-            if alloc is not None:
-                alloc.relayout(lambda x: faults.retry(
-                    s.ex2.place, x, what="relayout:_alloc"))
+                setattr(s, attr, faults.retry(put, getattr(s, attr),
+                                              what=f"relayout:{attr}"))
+            s._alloc.relayout(lambda x: faults.retry(
+                s.ex2.place, x, what="relayout:_alloc"))
         s.stats.record_placement(new_pl)
 
     def _resume(self, t0: float) -> None:

@@ -9,8 +9,8 @@ traffic to exit-heavy (small-stage-2) replicas and hard traffic to fat
 ones, the progressive-inference scheduling framing of HAPI. The
 ``FleetRouter`` owns admission across a fleet of replicas and depends ONLY
 on the transport-agnostic ``ReplicaHandle`` surface (``serve_api.py``), so
-the replicas can be in-process ``ContinuousScheduler``/``SyncScheduler``
-objects today and multi-process / multi-host proxies tomorrow.
+the replicas can be in-process ``ContinuousScheduler`` objects today and
+multi-process / multi-host proxies tomorrow.
 
 Routing policies (``policy=``):
 

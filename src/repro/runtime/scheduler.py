@@ -14,17 +14,18 @@ inference; cf. the Laskaridis et al. early-exit survey):
     *different* depths — the per-row ``step`` vector path in
     ``models.attention``/``models.mla``. Slots whose token exits early keep
     decoding through stage 1 on the next tick; slots whose token is hard are
-    **parked** and their hidden row + stage-2 cache rows + position ride the
-    pytree ring (payload lanes ``{"h", "cache", "step"}``) until a bucket
-    fills, the bucketed stage-2 dispatch scatters results back at each row's
-    own cache offset, and the slots resume. Completed slots are immediately
-    backfilled from an **admission queue** of open-loop (Poisson) arrivals.
+    **parked** and their hidden row + stage-2 block-table row + position
+    ride the pytree ring (payload lanes ``{"h", "cache", "step"}``) until a
+    bucket fills, the bucketed stage-2 dispatch appends each row's key/value
+    into its pages at the row's own cache offset, and the slots resume.
+    Completed slots are immediately backfilled from an **admission queue**
+    of open-loop (Poisson) arrivals.
 
-  * ``SyncScheduler`` is the degenerate policy: static batch formation over
-    a step-synchronous server's ``generate`` (``DecodeServer`` — which stays
-    bitwise-parity-checked against ``HostLoopDecoder``). It exists so both
-    policies share one request/latency bookkeeping and can be compared under
-    identical open-loop traffic (``benchmarks/serve_continuous.py``).
+  * The stage-2 KV cache is **paged**: one shared page pool plus a
+    per-slot block table, pages allocated at admission and freed at finish
+    (``PageAllocator``). This is the one serving store; the dense stage-2
+    decode (``DecodeFns.s2``) survives only as the ``HostLoopDecoder``
+    reference the token streams are checked against.
 
 **Correctness contract.** Continuous mode deliberately trades batch-level
 bitwise identity for utilization: merged logits are never materialized per
@@ -46,11 +47,11 @@ as slots churn.
 
 The device-side pytree ring (``ring_init``/``ring_enqueue``/``ring_drain``)
 and the chunked-enqueue/backpressure plumbing (``RingQueue``) live here and
-are shared with the step-synchronous servers in ``runtime/serve_loop.py``
-(which re-exports them; the paper's Fig. 7 sizing/deadlock story is
-unchanged). ``ServeStats`` also lives here: it now records per-request
-submit→finish latency (``latency_p50/p90/p99``) and a per-dispatch
-``realized_q`` series — the drift signal threshold re-planning consumes.
+are shared with the prefill server in ``runtime/serve_loop.py`` (which
+re-exports them; the paper's Fig. 7 sizing/deadlock story is unchanged).
+``ServeStats`` also lives here: it records per-request submit→finish
+latency (``latency_p50/p90/p99``) and a per-dispatch ``realized_q`` series
+— the drift signal threshold re-planning consumes.
 """
 from __future__ import annotations
 
@@ -167,9 +168,9 @@ class ServeStats:
 
     v3 adds the paged-KV-cache memory economics: ``cache_pages_total`` /
     ``cache_pages_in_use`` / ``cache_pages_free`` (the page allocator's
-    free-list view; all 0 for dense pools), ``cache_hbm_bytes`` (bytes the
-    KV store actually holds resident — the page pools when paged, the dense
-    slot store otherwise), ``page_fragmentation`` (1 − live_tokens /
+    free-list view; all 0 where nothing is paged — the prefill servers and
+    the host-loop oracle), ``cache_hbm_bytes`` (bytes the page pools hold
+    resident), ``page_fragmentation`` (1 − live_tokens /
     (pages_in_use × page_size): the fraction of allocated page capacity not
     holding a live token — tail-page waste), and ``ring_bytes_moved``
     (cumulative bytes enqueued onto the stage-boundary ring; the hop-size
@@ -207,8 +208,8 @@ class ServeStats:
     n_migration_rollbacks: int = 0
     migration_pauses_ms: Deque[float] = field(
         default_factory=lambda: deque(maxlen=1024), repr=False)
-    # paged-cache memory economics (v3): the owning scheduler/server keeps
-    # these current; dense pools leave the page counters at 0
+    # paged-cache memory economics (v3): the continuous scheduler keeps
+    # these current; servers without a page pool leave them at 0
     cache_pages_total: int = 0
     cache_pages_in_use: int = 0
     cache_hbm_bytes: int = 0
@@ -329,8 +330,7 @@ class ServeStats:
     @property
     def page_fragmentation(self) -> float:
         """Fraction of allocated page capacity not holding a live token
-        (tail-page internal fragmentation). 0.0 for dense pools / empty
-        allocators."""
+        (tail-page internal fragmentation). 0.0 with no pages in use."""
         cap = self.cache_pages_in_use * self.cache_page_size
         if cap <= 0:
             return 0.0
@@ -451,7 +451,7 @@ def ring_drain(buf: dict, capacity: int):
 
 class RingQueue:
     """Chunked-enqueue/bucket-pop plumbing over the device ring: the one
-    hard-token queue implementation the step-synchronous servers
+    hard-token queue implementation the prefill server
     (``runtime/serve_loop.py``) and the continuous scheduler share.
 
     ``enqueue`` appends ``n_hard`` valid rows of a compacted slab pytree in
@@ -473,9 +473,6 @@ class RingQueue:
         self._buf: Optional[dict] = None
         self.count = 0                    # host mirror of buf['count']
         self._row_nbytes = 0              # per-row payload bytes (all leaves)
-
-    def reset(self) -> None:
-        self._buf, self.count, self._row_nbytes = None, 0, 0
 
     def _note_row_bytes(self) -> None:
         """Cache the per-row payload size of the live buffer — the unit of
@@ -994,20 +991,29 @@ class PageAllocator:
 _FREE, _ACTIVE, _PARKED = 0, 1, 2
 
 
+# what stage fns must carry for the paged stage-2 store
+_PAGED_SURFACE = ("page_size", "s2_paged", "pool_init", "admit_pages")
+
+
 class ContinuousScheduler:
     """Continuous-batching two-stage EE decode over a fixed slot pool.
 
-    ``fns`` is a ``runtime.serve_loop.DecodeFns`` (duck-typed: anything with
-    ``prefill``/``split``/``s1_raw``/``s2`` works — property tests drive the
-    policy with toy stage callables). All admitted requests must satisfy
-    ``len(prompt) + n_tokens <= max_len`` (the pool's shared cache width).
+    ``fns`` is a ``runtime.serve_loop.DecodeFns`` with its paged surface
+    (``page_size``/``s2_paged``/``pool_init``/``admit_pages``, as
+    ``decode_stage_fns(page_size=...)`` builds it; duck-typed — property
+    tests drive the policy with toy stage callables). All admitted requests
+    must satisfy ``len(prompt) + n_tokens <= max_len`` (the pool's shared
+    cache width), a multiple of ``page_size``. ``n_pages`` allocatable
+    pages back the pool (default: ``n_slots * max_len / page_size``; pass
+    less to serve more slots than a full-width store would hold at the same
+    HBM budget).
 
     Under a disaggregated ``placement`` the slot lanes, pooled stage-1 cache
-    and the pool tick live on ``ex1``; the stage-2 row store, the ring and
-    the bucketed vector-step ``stage2_decode`` dispatches on ``ex2``. The
-    hard slab + step lane hop ex1 -> ex2 at enqueue and each bucket's greedy
-    tokens hop ex2 -> ex1 at unpark — ``jax.device_put`` transfers, never
-    the host.
+    and the pool tick live on ``ex1``; the page pool, the block-table lane,
+    the ring and the bucketed vector-step ``stage2_decode`` dispatches on
+    ``ex2``. The hard slab + step lane hop ex1 -> ex2 at enqueue and each
+    bucket's greedy tokens hop ex2 -> ex1 at unpark — ``jax.device_put``
+    transfers, never the host.
 
     ``results`` maps sample id -> list of emitted greedy tokens (stream
     order). Latency is recorded per request in ``stats``.
@@ -1031,6 +1037,12 @@ class ContinuousScheduler:
                  n_pages: Optional[int] = None, events=None):
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
+        missing = [name for name in _PAGED_SURFACE
+                   if getattr(fns, name, None) is None]
+        if missing:
+            raise ValueError(
+                f"stage fns lack the paged decode surface {missing}: build "
+                "them with decode_stage_fns(page_size=...)")
         self.fns = fns
         # optional request-lifecycle feed (telemetry.EventLog): when set,
         # the scheduler emits submit/admit/park/bucket/finish/tick events
@@ -1067,35 +1079,23 @@ class ContinuousScheduler:
         self.stats = ServeStats()
         self.stats.record_placement(self.placement)
         self.ring = RingQueue(sc, self.ex2, self.stats)
-        # paged KV-cache mode: on iff the stage fns carry the paged decode
-        # surface (page_size + s2_paged + pool_init + admit_pages —
-        # serve_loop.decode_stage_fns(page_size=...)). The stage-2 row
-        # store becomes a shared PAGE POOL + a per-slot block-table lane
-        # (self._rows, reused verbatim as the ring payload's "cache" lane:
-        # a hop ships page INDICES, never cache rows), and capacity is
-        # measured in pages — ``n_pages`` allocatable pages (default: full
-        # dense equivalence, n_slots * max_len/page_size).
-        self.page_size = getattr(fns, "page_size", None)
-        self._paged = (self.page_size is not None
-                       and getattr(fns, "s2_paged", None) is not None)
-        self._pool = None                    # the paged stage-2 page pool
-        self._alloc: Optional[PageAllocator] = None
-        if self._paged:
-            if max_len % self.page_size != 0:
-                raise ValueError(
-                    f"max_len={max_len} must be a multiple of page_size="
-                    f"{self.page_size} (paged/dense bitwise parity needs "
-                    f"the gathered span == max_len)")
-            self.max_pages = max_len // self.page_size
-            self.n_pages = (int(n_pages) if n_pages is not None
-                            else n_slots * self.max_pages)
-            self._alloc = PageAllocator(self.n_pages, self.page_size,
-                                        ex=self.ex2)
-            self.stats.cache_pages_total = self.n_pages
-            self.stats.cache_page_size = self.page_size
-        elif n_pages is not None:
-            raise ValueError("n_pages given but fns carry no paged decode "
-                             "surface (decode_stage_fns(page_size=...))")
+        # the stage-2 store is a shared PAGE POOL + a per-slot block-table
+        # lane (self._rows, reused verbatim as the ring payload's "cache"
+        # lane: a hop ships page INDICES, never cache rows), and capacity
+        # is measured in pages
+        self.page_size = fns.page_size
+        if max_len % self.page_size != 0:
+            raise ValueError(
+                f"max_len={max_len} must be a multiple of page_size="
+                f"{self.page_size} (parity with the dense reference needs "
+                f"the gathered span == max_len)")
+        self.max_pages = max_len // self.page_size
+        self.n_pages = (int(n_pages) if n_pages is not None
+                        else n_slots * self.max_pages)
+        self._pool = None                    # the stage-2 page pool
+        self._alloc = PageAllocator(self.n_pages, self.page_size, ex=self.ex2)
+        self.stats.cache_pages_total = self.n_pages
+        self.stats.cache_page_size = self.page_size
         # the transport-agnostic admission queue (runtime/serve_api.py):
         # owns FIFO order, the queued-sid set, submit-side validation and
         # the revocation primitive fleet preemption uses
@@ -1107,7 +1107,7 @@ class ContinuousScheduler:
         self._emitted = [0] * n_slots
         self._budget = [0] * n_slots
         self._state = [_FREE] * n_slots
-        # paged bookkeeping: pages owned / prompt length per slot (live
+        # page bookkeeping: pages owned / prompt length per slot (live
         # cache tokens = prompt + emitted - 1 — the fragmentation gauge)
         self._slot_pages = [0] * n_slots
         self._slot_len = [0] * n_slots
@@ -1226,22 +1226,15 @@ class ContinuousScheduler:
         if self._c1 is not None:
             return
         self._c1 = seg_pool_like(c1_row, self.n_slots)
-        if self._paged:
-            # the slot-major store is the BLOCK-TABLE lane (zero rows =
-            # all-null tables); the actual cache bytes live in one shared
-            # page pool (+1 page: the NULL page at index 0)
-            self._rows = self.ex2.place_io(
-                jnp.zeros((self.n_slots, self.max_pages), jnp.int32))
-            self._pool = self.ex2.place_io(
-                self.fns.pool_init(rows_row, self.n_pages + 1))
-            self.stats.cache_hbm_bytes = sum(
-                leaf.nbytes for leaf in jax.tree.leaves(self._pool))
-        else:
-            self._rows = self.ex2.place_io(
-                jax.tree.map(lambda x: jnp.zeros(
-                    (self.n_slots,) + x.shape[1:], x.dtype), rows_row))
-            self.stats.cache_hbm_bytes = sum(
-                leaf.nbytes for leaf in jax.tree.leaves(self._rows))
+        # the slot-major store is the BLOCK-TABLE lane (zero rows = all-null
+        # tables); the actual cache bytes live in one shared page pool (+1
+        # page: the NULL page at index 0)
+        self._rows = self.ex2.place_io(
+            jnp.zeros((self.n_slots, self.max_pages), jnp.int32))
+        self._pool = self.ex2.place_io(
+            self.fns.pool_init(rows_row, self.n_pages + 1))
+        self.stats.cache_hbm_bytes = sum(
+            leaf.nbytes for leaf in jax.tree.leaves(self._pool))
         self._tok = self.ex1.place_io(jnp.zeros((self.n_slots, 1), jnp.int32))
         self._pos = self.ex1.place_io(jnp.zeros((self.n_slots,), jnp.int32))
         self._active_lane = self.ex1.place_io(jnp.zeros((self.n_slots,),
@@ -1294,28 +1287,23 @@ class ContinuousScheduler:
                 self._c1, self._tok, self._pos, self._active_lane,
                 self._start_lane, self._budget_lane, logits0, c1_rows,
                 self.ex1.place_io(slots_dev), S, self.ex1.place_io(budgets))
-            if self._paged:
-                # alloc-on-admit: one block-table row per request (the page-
-                # budget admission check in _try_admit guarantees the free
-                # list covers the chunk), then ONE fused pool scatter moves
-                # the chunk's prefill cache rows into their pages
-                needs = []
-                for r, slot in zip(reqs, slots):
-                    need = PageAllocator.pages_for(S + r.n_tokens - 1,
-                                                   self.page_size)
-                    needs.append(need)
-                    self._slot_pages[slot] = need
-                    self._slot_len[slot] = S
-                bt_rows = self.ex2.place_io(
-                    self._alloc.alloc_many(needs, max_pages=self.max_pages))
-                self._pool = self.fns.admit_pages(
-                    self._pool, self.ex2.place_io(rows_rows), bt_rows)
-                self._rows = _scatter_rows(self._rows, bt_rows,
-                                           self.ex2.place_io(slots_dev))
-            else:
-                self._rows = _scatter_rows(self._rows,
-                                           self.ex2.place_io(rows_rows),
-                                           self.ex2.place_io(slots_dev))
+            # alloc-on-admit: one block-table row per request (the page-
+            # budget admission check in _try_admit guarantees the free list
+            # covers the chunk), then ONE fused pool scatter moves the
+            # chunk's prefill cache rows into their pages
+            needs = []
+            for r, slot in zip(reqs, slots):
+                need = PageAllocator.pages_for(S + r.n_tokens - 1,
+                                               self.page_size)
+                needs.append(need)
+                self._slot_pages[slot] = need
+                self._slot_len[slot] = S
+            bt_rows = self.ex2.place_io(
+                self._alloc.alloc_many(needs, max_pages=self.max_pages))
+            self._pool = self.fns.admit_pages(
+                self._pool, self.ex2.place_io(rows_rows), bt_rows)
+            self._rows = _scatter_rows(self._rows, bt_rows,
+                                       self.ex2.place_io(slots_dev))
             with observe.annotate("admit_sync"):
                 # one admission sync per chunk
                 tok0_np, load_np = jax.device_get((tok0, load))
@@ -1358,20 +1346,19 @@ class ContinuousScheduler:
                 if (r.arrival_time > now or len(r.prompt) != S0
                         or n_adm >= headroom):
                     break
-                if self._paged:
-                    need = PageAllocator.pages_for(
-                        len(r.prompt) + r.n_tokens - 1, self.page_size)
-                    if need > self.n_pages:
-                        raise ValueError(
-                            f"request {r.sample_id} needs {need} pages but "
-                            f"the pool holds {self.n_pages} total — it can "
-                            "never be admitted")
-                    if pages_acc + need > self._alloc.n_free:
-                        # free-list empty(ish): admission BACKPRESSURE, not
-                        # a drop — the head request waits for pages to be
-                        # freed by finishing slots (attrition)
-                        break
-                    pages_acc += need
+                need = PageAllocator.pages_for(
+                    len(r.prompt) + r.n_tokens - 1, self.page_size)
+                if need > self.n_pages:
+                    raise ValueError(
+                        f"request {r.sample_id} needs {need} pages but the "
+                        f"pool holds {self.n_pages} total — it can never "
+                        "be admitted")
+                if pages_acc + need > self._alloc.n_free:
+                    # free-list empty(ish): admission BACKPRESSURE, not a
+                    # drop — the head request waits for pages to be freed
+                    # by finishing slots (attrition)
+                    break
+                pages_acc += need
                 n_adm += 1
             if n_adm == 0:
                 return admitted
@@ -1391,7 +1378,7 @@ class ContinuousScheduler:
         self._state[slot] = _FREE
         self._sid[slot] = -1
         self._free.append(slot)
-        if self._paged and self._slot_pages[slot] > 0:
+        if self._slot_pages[slot] > 0:
             # free-on-finish: the slot's pages go back on the free list and
             # its device block-table row is zeroed — a later flush clone of
             # this row must never let stage 2 append into recycled pages
@@ -1437,25 +1424,18 @@ class ContinuousScheduler:
         bucket, ids, take = popped
         with observe.annotate("stage2_bucket_dispatch", reason=reason,
                               take=take):
-            if self._paged:
-                # paged stage 2: the bucket's "cache" lane carries block-
-                # table rows (page indices — the whole ring hop is index-
-                # sized). Flush lanes (id -1) cloned a live slot's bt row
-                # out of the ring slab; sanitize them to the NULL table +
-                # sentinel step so the shared pool is never appended
-                # through a discarded row. The pool is donated through
-                # s2_paged and comes back updated — no scatter-back (pages
-                # are shared state, not slot rows).
-                from repro.runtime.serve_loop import _sanitize_paged_bucket
-                bt_safe, step_safe = _sanitize_paged_bucket(
-                    bucket["cache"], ids, bucket["step"],
-                    sentinel=self.max_len)
-                logits, self._pool, *load = self.fns.s2_paged(
-                    bucket["h"], bt_safe, step_safe, self._pool)
-            else:
-                logits, new_rows, *load = self.fns.s2(
-                    bucket["h"], bucket["cache"], bucket["step"])
-                self._rows = _scatter_rows(self._rows, new_rows, ids)
+            # the bucket's "cache" lane carries block-table rows (page
+            # indices — the whole ring hop is index-sized). Flush lanes (id
+            # -1) cloned a live slot's bt row out of the ring slab; sanitize
+            # them to the NULL table + sentinel step so the shared pool is
+            # never appended through a discarded row. The pool is donated
+            # through s2_paged and comes back updated — no scatter-back
+            # (pages are shared state, not slot rows).
+            from repro.runtime.serve_loop import _sanitize_paged_bucket
+            bt_safe, step_safe = _sanitize_paged_bucket(
+                bucket["cache"], ids, bucket["step"], sentinel=self.max_len)
+            logits, self._pool, *load = self.fns.s2_paged(
+                bucket["h"], bt_safe, step_safe, self._pool)
         toks = _greedy_row(logits)
         # ex2 -> ex1 hop: greedy tokens come home to the slot lanes
         self._tok, self._pos, self._active_lane = _unpark_lanes(
@@ -1652,8 +1632,6 @@ class ContinuousScheduler:
         """Fold the allocator's view + the host token tallies into the v3
         stats fields (once per scheduler iteration — the gauges are cheap
         host arithmetic)."""
-        if not self._paged:
-            return
         self.stats.cache_pages_in_use = self._alloc.n_in_use
         self.stats.live_tokens = sum(
             self._slot_len[i] + self._emitted[i] - 1
@@ -1753,163 +1731,6 @@ class ContinuousScheduler:
     def run(self) -> Dict[int, List[int]]:
         """Drive the pool until the queue and every slot drain — the
         standalone entry point (``drain`` under its original name)."""
-        return self.drain()
-
-
-# ---------------------------------------------------------------------------
-# the degenerate sync policy: static batch formation over a step-synchronous
-# server's generate()
-# ---------------------------------------------------------------------------
-
-class SyncScheduler:
-    """Batch-formation wrapper over a step-synchronous decode server
-    (``DecodeServer`` or ``HostLoopDecoder``): admit requests in arrival
-    order into static batches of ``n_slots``, wait for the batch's last
-    arrival, run ``generate`` to the batch's *longest* request (lockstep:
-    finished samples ride along until the whole batch completes — the
-    utilization gap continuous batching removes), truncate per request.
-    Prompts within a batch must share one length. A partial tail batch
-    runs at its own (smaller) shape — one extra compile, but the stats
-    (realized q, decisions, occupancy) count only real traffic, never
-    padding rows.
-
-    Implements the same ``ReplicaHandle`` surface (``serve_api.py``) as
-    the continuous scheduler — shared submit-side validation (``max_len``
-    bounds requests only when given: the static-batch regime has no
-    pooled cache width), one ``step`` per static batch, the finish feed,
-    revocation — so a fleet router can mix sync and continuous replicas.
-    ``request_capacity`` re-sizes the server's stage-2 bucket at the next
-    batch boundary (always a shape-change-safe point: nothing is in
-    flight between generates); ``request_migration`` raises — the sync
-    policy has no live pool to migrate (use the continuous scheduler)."""
-
-    def __init__(self, server, n_slots: int, clock=None,
-                 max_len: Optional[int] = None, events=None):
-        self.server = server
-        self.n_slots = n_slots
-        self.max_len = max_len
-        self.events = events                 # see ContinuousScheduler
-        self.clock = clock or Clock()
-        self.queue: RequestQueue = RequestQueue(
-            max_len=max_len, is_dup=lambda sid: sid in self.results)
-        self.results: Dict[int, List[int]] = {}
-        self.controller = None               # attached via controller.attach
-        self._seen_decisions = 0
-        self._seen_hard = 0
-        self._busy_sids: set = set()         # admitted, mid-generate (empty
-        self._finished: Deque = deque(maxlen=_SERIES_CAP)   # between steps)
-
-    @property
-    def stats(self) -> ServeStats:
-        return self.server.stats
-
-    def set_c_thr(self, c_thr: float) -> None:
-        """Threshold actuation on the sync policy: batch granularity (the
-        step-synchronous server re-reads its threshold per generate)."""
-        self.server.set_c_thr(c_thr)
-
-    def request_capacity(self, capacity: int) -> None:
-        """Re-size the stage-2 bucket from the next static batch on —
-        batch boundaries are always discrete re-plan points for the sync
-        policy (no in-flight state between generates)."""
-        cap = max(1, int(capacity))
-        sc = self.server.sc
-        if cap == sc.capacity:
-            return
-        new_sc = ServeConfig(capacity=cap, queue_depth=sc.queue_depth,
-                             c_thr=sc.c_thr, max_pending=sc.max_pending,
-                             harvest_timeout_s=sc.harvest_timeout_s)
-        self.server.sc = new_sc
-        self.server.ring = RingQueue(new_sc, self.server.ex2, self.stats)
-
-    def request_migration(self, plan) -> None:
-        raise NotImplementedError(
-            "the sync policy has no live slot pool to migrate — live "
-            "migration needs the continuous scheduler")
-
-    # -- ReplicaHandle introspection -----------------------------------------
-
-    @property
-    def n_busy(self) -> int:
-        return len(self._busy_sids)          # 0 between steps (lockstep)
-
-    @property
-    def queue_len(self) -> int:
-        return len(self.queue)
-
-    def next_arrival(self) -> Optional[float]:
-        return self.queue.next_arrival()
-
-    def revoke_queued(self, sample_ids=None) -> List[Request]:
-        return self.queue.revoke(sample_ids)
-
-    def drain_finished(self) -> List:
-        """Pop the finish feed: (sid, n_hard, n_decisions) per finished
-        request. The sync server tallies hardness per batch, not per row,
-        so each request carries its batch's realized q scaled to its own
-        decision count — an unbiased estimate at batch granularity."""
-        out = list(self._finished)
-        self._finished.clear()
-        return out
-
-    def submit(self, req: Request) -> None:
-        self.queue.append(req)
-        if self.events is not None:
-            self.events.emit("submit", sid=req.sample_id,
-                             arrival=req.arrival_time,
-                             n_tokens=req.n_tokens)
-
-    def step(self) -> str:
-        """Form and run ONE static batch (waiting for its last arrival —
-        the sync policy's admission rule). Returns ``"busy"`` when a batch
-        ran, ``"idle"`` when the queue is empty; never ``"waiting"`` (the
-        batch wait IS the policy, so the clock advances internally)."""
-        if not self.queue:
-            return "idle"
-        batch = [self.queue.popleft()
-                 for _ in range(min(self.n_slots, len(self.queue)))]
-        self._busy_sids = {r.sample_id for r in batch}
-        self.clock.advance_to(max(r.arrival_time for r in batch))
-        for r in batch:
-            self.stats.record_submit(r.sample_id, r.arrival_time)
-            if self.events is not None:
-                self.events.emit("admit", sid=r.sample_id, slot=-1,
-                                 prompt_len=len(r.prompt))
-        prompts = [np.asarray(r.prompt, np.int32) for r in batch]
-        n_max = max(r.n_tokens for r in batch)
-        dec0, hard0 = self.stats.n_decisions, self.stats.n_stage2
-        out = self.server.generate(np.stack(prompts), n_max)
-        q_batch = ((self.stats.n_stage2 - hard0)
-                   / max(self.stats.n_decisions - dec0, 1))
-        t = self.clock.now()
-        for i, r in enumerate(batch):
-            self.results[r.sample_id] = [
-                int(x) for x in out["tokens"][i, :r.n_tokens]]
-            self.stats.record_finish(r.sample_id, t)
-            n_dec = r.n_tokens - 1
-            self._finished.append((r.sample_id, q_batch * n_dec, n_dec))
-            if self.events is not None:
-                self.events.emit("finish", sid=r.sample_id,
-                                 n_decisions=n_dec)
-        self._busy_sids = set()
-        if self.controller is not None:
-            # one controller visit per static batch (the sync policy's
-            # natural actuation granularity); confidences arrive via
-            # the server's conf sink, wired at attach
-            st = self.stats
-            n_dec = st.n_decisions - self._seen_decisions
-            n_hard = st.n_stage2 - self._seen_hard
-            self._seen_decisions = st.n_decisions
-            self._seen_hard = st.n_stage2
-            self.controller.on_tick(self, n_dec, n_hard, None)
-        return "busy"
-
-    def drain(self) -> Dict[int, List[int]]:
-        while self.step() != "idle":
-            pass
-        return self.results
-
-    def run(self) -> Dict[int, List[int]]:
         return self.drain()
 
 

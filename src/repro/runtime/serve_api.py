@@ -13,7 +13,7 @@ This module owns that contract:
   * ``validate_request`` — THE submit-side validation (n_tokens >= 1,
     prompt + n_tokens within the pool's max_len, duplicate sample ids).
     One definition, byte-identical error messages, shared by the
-    continuous scheduler, the sync scheduler and the fleet router — a
+    continuous scheduler and the fleet router — a
     malformed request is rejected at whichever surface sees it first,
     with the same error either way.
   * ``RequestQueue`` — the admission queue abstraction: FIFO arrival
@@ -27,12 +27,11 @@ This module owns that contract:
     actuators (``set_c_thr``/``request_capacity``/``request_migration``)
     and the load/feed introspection the router's policies consume
     (``n_slots``/``n_busy``/``queue_len``/``next_arrival``/
-    ``revoke_queued``/``drain_finished``). ``ContinuousScheduler`` and
-    ``SyncScheduler`` both implement it; the router depends ONLY on it.
-  * ``build`` — the one construction entry point for every serving mode
-    (prefill/decode x sync/continuous x device/host-loop), replacing the
-    four ``build_*`` factories that ``runtime/serve_loop.py`` now
-    re-exports as keyword-compatible deprecation shims.
+    ``revoke_queued``/``drain_finished``). ``ContinuousScheduler``
+    implements it; the router depends ONLY on it.
+  * ``build`` — the one construction entry point: decode serving (the
+    continuous scheduler over the paged stage-2 store), prefill serving,
+    and the host-loop oracles of both.
 
 ``step()`` returns one of three strings — the replica state machine the
 router (and ``drain``) drives:
@@ -49,7 +48,6 @@ This module deliberately imports nothing from the runtime at module scope
 """
 from __future__ import annotations
 
-import warnings
 from collections import deque
 from typing import (Callable, Deque, Dict, Iterator, List, Optional,
                     Protocol, Sequence, runtime_checkable)
@@ -62,8 +60,8 @@ def validate_request(req, *, max_len: Optional[int] = None,
     """Submit-side request validation — the single shared definition (and
     the single set of error messages) for every admission surface.
 
-    ``max_len`` bounds ``len(prompt) + n_tokens`` (None = unbounded, the
-    sync policy's static-batch regime); ``is_dup(sid)`` reports whether
+    ``max_len`` bounds ``len(prompt) + n_tokens`` (None = unbounded);
+    ``is_dup(sid)`` reports whether
     the surface has already seen the sample id (queued, admitted or
     finished)."""
     if req.n_tokens < 1:
@@ -174,7 +172,7 @@ class RequestQueue:
 @runtime_checkable
 class ReplicaHandle(Protocol):
     """What a routable serving replica looks like. ``ContinuousScheduler``
-    and ``SyncScheduler`` implement it in-process; the fleet router
+    implements it in-process; the fleet router
     depends only on this surface, so a transport proxy (multi-process,
     multi-host) that speaks it routes identically.
 
@@ -231,7 +229,6 @@ class ReplicaHandle(Protocol):
 # ---------------------------------------------------------------------------
 
 _MODES = ("prefill", "decode")
-_SCHEDULERS = (None, "sync", "continuous")
 
 
 def build(params, cfg, spec, sc, *, mode: str = "decode",
@@ -239,94 +236,62 @@ def build(params, cfg, spec, sc, *, mode: str = "decode",
           n_slots: Optional[int] = None, max_len: Optional[int] = None,
           clock=None, host: bool = False, page_size: Optional[int] = None,
           n_pages: Optional[int] = None, events=None):
-    """Build a serving object for any (mode, scheduler) point — the single
-    construction path ``launch/serve.py``, the benchmarks and the examples
-    share (the old ``build_*`` factories in ``runtime/serve_loop.py`` are
-    deprecation shims over this).
+    """Build a serving object — the single construction path
+    ``launch/serve.py``, the benchmarks and the examples share.
 
-    ==========  ============  =============================================
-    mode        scheduler     returns
-    ==========  ============  =============================================
-    "prefill"   None          ``TwoStageServer`` (``HostLoopServer`` when
-                              ``host=True``) — batch-level EE serving
-    "decode"    None          ``DecodeServer`` (``HostLoopDecoder`` when
-                              ``host=True``) — step-synchronous generate()
-    "decode"    "sync"        ``SyncScheduler`` over a ``DecodeServer``
-                              (needs ``n_slots``)
-    "decode"    "continuous"  ``ContinuousScheduler`` (needs ``n_slots``
-                              and ``max_len``; carries the ``fns_factory``
-                              live migration rebuilds stage callables
-                              with)
-    ==========  ============  =============================================
+    ==========  ======  ===================================================
+    mode        host    returns
+    ==========  ======  ===================================================
+    "decode"    False   ``ContinuousScheduler`` over the paged stage-2
+                        store (needs ``n_slots``, ``max_len`` and
+                        ``page_size``; carries the ``fns_factory`` live
+                        migration rebuilds stage callables with)
+    "decode"    True    ``HostLoopDecoder`` — the dense host-loop decode,
+                        the reference the scheduler's streams are checked
+                        against
+    "prefill"   False   ``TwoStageServer`` — batch-level EE serving
+    "prefill"   True    ``HostLoopServer`` — its host-loop reference
+    ==========  ======  ===================================================
 
-    ``placement`` disaggregates the two stages onto disjoint submeshes for
-    any device-resident variant; ``clock`` (sync/continuous only) shares a
-    time base across replicas — REQUIRED when the result joins a
-    ``FleetRouter`` fleet.
+    ``scheduler`` is "continuous" for the scheduler and None for the other
+    three; any other value raises. ``placement`` disaggregates the two
+    stages onto disjoint submeshes for the device-resident objects;
+    ``clock`` shares a time base across replicas — REQUIRED when the
+    scheduler joins a ``FleetRouter`` fleet.
 
-    ``page_size`` switches the stage-2 KV store to the PAGED pool (decode
-    modes only): the stage fns gain the block-table decode surface, the
-    step-synchronous ``DecodeServer`` pages its generate-time cache, and
-    the continuous scheduler allocates pages on admit / frees on finish
-    over ``n_pages`` allocatable pages (default: dense-equivalent
-    capacity, ``n_slots * max_len / page_size`` — pass less to serve more
-    slots than the dense store could hold at the same HBM budget)."""
-    # ``events`` (scheduler modes only) wires a telemetry.EventLog request-
-    # lifecycle feed into the scheduler — the observability plane
-    # (runtime/observe.Tracer / StatsSampler) subscribes to it.
+    ``page_size`` sizes the pages of the stage-2 KV pool (``max_len`` must
+    be a multiple); the scheduler allocates pages on admit and frees them
+    on finish over ``n_pages`` allocatable pages (default:
+    ``n_slots * max_len / page_size`` — pass less to serve more slots than
+    a full-width store could hold at the same HBM budget)."""
+    # ``events`` wires a telemetry.EventLog request-lifecycle feed into the
+    # scheduler — the observability plane (runtime/observe.Tracer /
+    # StatsSampler) subscribes to it.
     from repro.runtime import serve_loop as SL
 
     if mode not in _MODES:
         raise ValueError(f"mode must be one of {_MODES}, got {mode!r}")
-    if scheduler not in _SCHEDULERS:
+    want = None if host or mode == "prefill" else "continuous"
+    if scheduler != want:
         raise ValueError(
-            f"scheduler must be one of {_SCHEDULERS}, got {scheduler!r}")
-    if page_size is not None and mode != "decode":
-        raise ValueError("page_size is a decode-mode knob (the paged pool "
-                         "is the stage-2 decode cache)")
-    if n_pages is not None and page_size is None:
-        raise ValueError("n_pages needs page_size")
-    if n_pages is not None and scheduler != "continuous":
-        raise ValueError("n_pages sizes the continuous scheduler's page "
-                         "pool; the sync/bare paged servers are "
-                         "batch-sized")
-    if mode == "prefill":
-        if scheduler is not None:
-            raise ValueError(
-                "prefill serving has no scheduling policy: pass "
-                "scheduler=None (decode owns sync/continuous)")
-        if host:
-            return SL.HostLoopServer(*SL._stage_fns(params, cfg, spec), sc)
-        s1, s2 = SL._stage_fns(params, cfg, spec, placement)
-        return SL.TwoStageServer(s1, s2, sc, placement)
-    if events is not None and scheduler is None:
-        raise ValueError("events= is a scheduler-mode feed (the bare "
-                         "servers have no request lifecycle to emit)")
-    # decode
-    if scheduler is None:
-        fns = SL.decode_stage_fns(params, cfg, spec,
-                                  None if host else placement,
-                                  page_size=page_size)
-        if host:
-            if page_size is not None:
-                raise ValueError("the host-loop oracle has no paged cache "
-                                 "(it IS the dense reference)")
-            return SL.HostLoopDecoder(fns, sc)
-        return SL.DecodeServer(fns, sc, placement)
-    if host:
-        raise ValueError("host=True is a baseline-oracle knob for the bare "
-                         "servers; schedulers wrap the device-resident one")
-    if n_slots is None:
-        raise ValueError(f"scheduler={scheduler!r} needs n_slots")
-    if scheduler == "sync":
-        server = SL.DecodeServer(
-            SL.decode_stage_fns(params, cfg, spec, placement,
-                                page_size=page_size), sc, placement)
-        return SL.SyncScheduler(server, n_slots, clock=clock,
-                                max_len=max_len, events=events)
-    if max_len is None:
-        raise ValueError("scheduler='continuous' needs max_len (the pool's "
-                         "shared cache width)")
+            f"mode={mode!r} host={host} takes scheduler={want!r}, got "
+            f"{scheduler!r} (decode serving is the continuous scheduler; "
+            "prefill serving and the host-loop oracles have none)")
+    if want is None:
+        if page_size is not None or n_pages is not None or events is not None:
+            raise ValueError("page_size / n_pages / events configure the "
+                             "continuous scheduler's page pool and request "
+                             "lifecycle feed")
+        if mode == "prefill":
+            if host:
+                return SL.HostLoopServer(*SL._stage_fns(params, cfg, spec),
+                                         sc)
+            s1, s2 = SL._stage_fns(params, cfg, spec, placement)
+            return SL.TwoStageServer(s1, s2, sc, placement)
+        return SL.HostLoopDecoder(SL.decode_stage_fns(params, cfg, spec), sc)
+    if n_slots is None or max_len is None or page_size is None:
+        raise ValueError("the continuous scheduler needs n_slots, max_len "
+                         "(the pool's shared cache width) and page_size")
     return SL.ContinuousScheduler(
         SL.decode_stage_fns(params, cfg, spec, placement,
                             page_size=page_size), sc,
@@ -334,18 +299,3 @@ def build(params, cfg, spec, sc, *, mode: str = "decode",
         n_pages=n_pages, events=events,
         fns_factory=lambda pl: SL.decode_stage_fns(params, cfg, spec, pl,
                                                    page_size=page_size))
-
-
-_WARNED: set = set()
-
-
-def _deprecated_factory(name: str) -> None:
-    """One DeprecationWarning per shim name per process — the old
-    ``serve_loop.build_*`` factories forward here."""
-    if name in _WARNED:
-        return
-    _WARNED.add(name)
-    warnings.warn(
-        f"runtime.serve_loop.{name} is deprecated; construct servers via "
-        f"runtime.serve_api.build(mode=..., scheduler=..., placement=...)",
-        DeprecationWarning, stacklevel=3)

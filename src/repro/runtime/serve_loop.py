@@ -26,7 +26,7 @@ map through host memory. The servers here mirror that:
     cursors and one Sample-ID lane, updated in place by jitted
     ``ring_enqueue`` / ``ring_drain`` steps with ``donate_argnums`` so no
     copy of the queue ever exists. Prefill rings carry the bare hidden slab;
-    decode rings carry ``{hidden row, stage-2 KV-cache segment row}``. The
+    decode rings carry ``{hidden row, stage-2 block-table row, step}``. The
     pre-device-resident implementation (kept below as ``HostLoopServer``,
     the benchmark baseline) instead synced each hidden row to host, held it
     in a Python ``deque`` and re-stacked it per bucket;
@@ -37,18 +37,19 @@ map through host memory. The servers here mirror that:
     subsequent stage-1 batches. The single host sync per batch is the scalar
     ``n_hard`` needed for backpressure control flow.
 
-**Decode serving (``DecodeServer``).** Autoregressive decode makes the exit
-decision *per token*: every decode step runs ``ee.stage1_decode`` on the
-full token batch, and only the hard tokens' hidden rows — together with
-those samples' stage-2 KV-cache segment rows (``ee.split_caches``) — travel
-through the ring into bucketed ``ee.stage2_decode`` dispatches. Updated
-bucket cache rows are scattered back into the sample-major stage-2 cache
-store on device. Decode is step-synchronous (token t+1 of a sample needs
-its token-t logits), so the ring drains fully at the end of each step; its
-job is device-side bucketing + backpressure within the step. A token that
-exits early skips stage 2 entirely, so its stage-2 cache keeps zeros at
-that position — the *exit-gap* semantics shared bitwise with the host-loop
-baseline (cf. the cache-handling challenges in Laskaridis et al. 2021).
+**Decode serving.** Autoregressive decode makes the exit decision *per
+token* and is served by the slot-based ``ContinuousScheduler``
+(``runtime/scheduler.py``): every pool tick runs ``ee.stage1_decode`` on
+the slot batch, and only the hard tokens' hidden rows — with their stage-2
+block-table rows — travel through the ring into bucketed
+``ee.stage2_decode`` dispatches over the paged stage-2 KV pool. This
+module builds the jitted stage callables it runs (``decode_stage_fns``)
+and keeps ``HostLoopDecoder``, the per-token host loop over the dense
+stage-2 cache, as the reference its token streams are checked against. A
+token that exits early skips stage 2 entirely, so its stage-2 cache keeps
+zeros at that position — the *exit-gap* semantics the scheduler and the
+host loop share (cf. the cache-handling challenges in Laskaridis et al.
+2021).
 
 **Ring sizing / deadlock avoidance (paper Fig. 7).** The ring holds
 ``queue_depth * capacity`` samples. A stage-1 batch whose hard count exceeds
@@ -58,9 +59,7 @@ and are used only when no full bucket exists. Any batch size is therefore
 correct even against a tiny ring (no deadlock, no drop); an undersized ring
 just stalls stage 1 harder — the paper's Fig. 7 minimum-depth sizing is a
 throughput constraint, surfaced by ``ServeStats.n_stalls``, not a
-correctness one. For decode rings each row additionally carries the sample's
-stage-2 cache segment, so ring bytes scale with ``max_len`` — size
-``queue_depth`` down accordingly.
+correctness one.
 
 **Stage disaggregation.** Every server runs over a ``StagePlacement``
 (runtime/stage_executor.py): stage 1 + the exit-decision kernels on one
@@ -78,13 +77,10 @@ token for decode) and reports per-stage occupancy/stall statistics plus
 per-request latency so a deployment can re-plan (``core.stage_mesh``) when
 drift is persistent.
 
-**Continuous batching.** The step-synchronous servers here are the ``sync``
-scheduling policy. ``runtime/scheduler.py`` owns the slot-based
-``ContinuousScheduler`` (per-slot step counters, admission queue, HAPI-style
-staged dispatch) that trades this file's bitwise batch parity for
-utilization; the ring primitives, ``RingQueue`` backpressure plumbing,
-``ServeConfig`` and ``ServeStats`` live there and are re-exported here so
-the two policies share one implementation.
+The ring primitives, ``RingQueue`` backpressure plumbing, ``ServeConfig``
+and ``ServeStats`` live in ``runtime/scheduler.py`` and are re-exported
+here, so the prefill server and the decode scheduler share one
+implementation.
 """
 from __future__ import annotations
 
@@ -103,13 +99,13 @@ from repro.kernels import dispatch
 from repro.models import transformer as T
 from repro.models.config import ArchConfig
 from repro.runtime.stage_executor import StagePlacement
-from repro.runtime import faults, serve_api
+from repro.runtime import faults
 # the scheduler module owns the shared serving substrate; re-exported names
 # keep this module the one import site for serving callers and tests
 from repro.runtime.scheduler import (  # noqa: F401  (re-exports)
     ContinuousScheduler, HarvestTimeout, Request, RingQueue, ServeConfig,
-    ServeStats, SyncScheduler, _gather_rows, _ring_enqueue_range,
-    _scatter_rows, bounded_wait, ring_drain, ring_enqueue, ring_init)
+    ServeStats, _gather_rows, bounded_wait, ring_drain, ring_enqueue,
+    ring_init)
 from repro.runtime.serve_api import (  # noqa: F401  (re-exports)
     ReplicaHandle, RequestQueue, build, validate_request)
 
@@ -122,115 +118,22 @@ def _decide_compact(hidden, exit_logits, sample_ids, c_thr, *, backend,
     threshold never recompiles; the resolved kernel backend is a static
     arg, so a dispatch override is honored rather than baked in at first
     trace). Compaction capacity = the stage-1 batch, so no hard sample is
-    ever dropped here; the ring applies backpressure. The per-row
-    confidences the fused kernel already computes ride along for the
-    drift-telemetry reservoir (free on device; only fetched when a
-    controller is listening), as do the greedy preds — the decode merge
-    path emits them instead of re-running argmax over the logits."""
-    exit_mask, pred, conf = dispatch.exit_decision_op(
+    ever dropped here; the ring applies backpressure."""
+    exit_mask, _, _ = dispatch.exit_decision_op(
         exit_logits, c_thr, backend=backend, mesh=mesh)
     b = hidden.shape[0]
     slab, pos, n_hard = dispatch.gather_compact_op(
         hidden, ~exit_mask, b, backend=backend, mesh=mesh)
     slab_ids = jnp.where(pos >= 0,
                          jnp.take(sample_ids, jnp.maximum(pos, 0)), -1)
-    return slab, slab_ids, n_hard, exit_mask, pred, conf
-
-
-# ---------------------------------------------------------------------------
-# shared ring plumbing: the step-synchronous servers sit on the scheduler's
-# RingQueue (chunked enqueue under backpressure + bucket pops) — one ring
-# implementation for prefill, sync decode and continuous decode
-# ---------------------------------------------------------------------------
-
-class _RingedServer:
-    def __init__(self, sc: ServeConfig,
-                 placement: Optional[StagePlacement] = None):
-        self.sc = sc
-        self.placement = placement or StagePlacement.single_device()
-        self.ex1 = self.placement.ex1
-        self.ex2 = self.placement.ex2    # the ring + stage 2 live here
-        self.stats = ServeStats()
-        self.stats.record_placement(self.placement)
-        self.ring = RingQueue(sc, self.ex2, self.stats)
-        # control surface: the live threshold (traced — re-aiming it never
-        # recompiles) and an optional telemetry sink for the per-decision
-        # confidences (None = no extra host fetch on the hot path)
-        self.c_thr = float(sc.c_thr)
-        self.conf_sink = None
-
-    def set_c_thr(self, c_thr: float) -> None:
-        self.c_thr = float(c_thr)
-
-    @property
-    def _count(self) -> int:             # host mirror of the ring count
-        return self.ring.count
-
-    def _drain(self) -> None:             # pop one bucket + dispatch stage 2
-        raise NotImplementedError
-
-    def _enqueue_backpressured(self, slab_tree, slab_ids, n_hard: int) -> None:
-        """Enqueue ``n_hard`` valid rows of a compacted slab pytree in
-        chunks, stalling (draining) whenever the ring is out of space — see
-        ``scheduler.RingQueue.enqueue`` (the Fig. 7 backpressure story)."""
-        self.ring.enqueue(slab_tree, slab_ids, n_hard, self._drain)
-
-    def _use_fused(self) -> bool:
-        """The fused dispatch op (decision + compaction + in-ring enqueue,
-        one program) applies when stage 1 and the ring share a submesh; a
-        disaggregated placement keeps the composed chain, whose enqueue IS
-        the cross-submesh hop."""
-        return not self.placement.disaggregated
-
-    def _fused_dispatch_enqueue(self, exit_logits, sample_ids, payload,
-                                row_spec):
-        """One fused op replaces exit_decision -> gather_compact -> per-leaf
-        ring scatter: compacted hard rows land directly in the ring slabs
-        at (head+count) offsets, with the ring buffer donated through the
-        op. Syncs the scalar n_hard (+ confidences when a sink listens —
-        the same single host sync as the composed path), advances the
-        ring's host count mirror, and pushes any overflow past the ring's
-        free space through the composed backpressure chain (identical
-        stall/drain ordering). Returns (exit_mask, pred, conf, n_hard)."""
-        ring_buf = self.ring.ensure(row_spec)
-        (ring_buf, exit_mask, pred, conf, src,
-         n_hard_dev) = dispatch.fused_dispatch_op(
-            exit_logits, None, sample_ids, payload, ring_buf, self.c_thr)
-        self.ring.put_buf(ring_buf)
-        if self.conf_sink is not None:        # rides the n_hard sync
-            n_hard_dev, conf_np = jax.device_get((n_hard_dev, conf))
-            self.conf_sink.extend(conf_np)
-        n_hard = int(n_hard_dev)              # the one host sync
-        if n_hard > 0:
-            # the enqueue already happened in-op; its fault boundary keeps
-            # the composed visit cadence (once per hard batch)
-            faults.fault_point("enqueue")
-            n_enq = min(n_hard, self.ring.size - self.ring.count)
-            self.ring.note_enqueued(n_enq)
-            if n_enq < n_hard:                # ring filled mid-batch: spill
-                slab = _gather_rows(payload, src)
-                ids = jnp.where(src >= 0,
-                                jnp.take(sample_ids, jnp.maximum(src, 0)),
-                                -1)
-                self.ring.enqueue(slab, ids, n_hard, self._drain,
-                                  off=n_enq, fire_fault=False)
-        return exit_mask, pred, conf, n_hard
-
-    def _pop_bucket(self):
-        """Pop up to ``capacity`` rows; returns (bucket pytree, ids) or
-        None when the ring is empty. Updates occupancy stats."""
-        popped = self.ring.pop()
-        if popped is None:
-            return None
-        bucket, bucket_ids, _ = popped
-        return bucket, bucket_ids
+    return slab, slab_ids, n_hard, exit_mask
 
 
 # ---------------------------------------------------------------------------
 # device-resident two-stage prefill server
 # ---------------------------------------------------------------------------
 
-class TwoStageServer(_RingedServer):
+class TwoStageServer:
     """Batch-level EE server over jitted stage callables, device-resident.
 
     stage1_fn: tokens (B, S) -> (hidden, exit_logits)
@@ -256,7 +159,14 @@ class TwoStageServer(_RingedServer):
     def __init__(self, stage1_fn: Callable, stage2_fn: Callable,
                  sc: ServeConfig,
                  placement: Optional[StagePlacement] = None):
-        super().__init__(sc, placement)
+        self.sc = sc
+        self.placement = placement or StagePlacement.single_device()
+        self.ex1 = self.placement.ex1
+        self.ex2 = self.placement.ex2    # the ring + stage 2 live here
+        self.stats = ServeStats()
+        self.stats.record_placement(self.placement)
+        self.ring = RingQueue(sc, self.ex2, self.stats)
+        self.c_thr = float(sc.c_thr)     # traced: a new value never recompiles
         self.stage1 = stage1_fn
         self.stage2 = stage2_fn
         # pending device futures, collected at flush()
@@ -264,6 +174,44 @@ class TwoStageServer(_RingedServer):
         self._buckets: List[Tuple[jnp.ndarray, jnp.ndarray]] = []
 
     # -- internal ------------------------------------------------------------
+
+    def _use_fused(self) -> bool:
+        """The fused dispatch op (decision + compaction + in-ring enqueue,
+        one program) applies when stage 1 and the ring share a submesh; a
+        disaggregated placement keeps the composed chain, whose enqueue IS
+        the cross-submesh hop."""
+        return not self.placement.disaggregated
+
+    def _fused_dispatch_enqueue(self, exit_logits, sample_ids, payload,
+                                row_spec):
+        """One fused op replaces exit_decision -> gather_compact -> per-leaf
+        ring scatter: compacted hard rows land directly in the ring slabs
+        at (head+count) offsets, with the ring buffer donated through the
+        op. Syncs the scalar n_hard (the same single host sync as the
+        composed path), advances the ring's host count mirror, and pushes
+        any overflow past the ring's free space through the composed
+        backpressure chain (identical stall/drain ordering). Returns
+        (exit_mask, n_hard)."""
+        ring_buf = self.ring.ensure(row_spec)
+        (ring_buf, exit_mask, _, _, src,
+         n_hard_dev) = dispatch.fused_dispatch_op(
+            exit_logits, None, sample_ids, payload, ring_buf, self.c_thr)
+        self.ring.put_buf(ring_buf)
+        n_hard = int(n_hard_dev)              # the one host sync
+        if n_hard > 0:
+            # the enqueue already happened in-op; its fault boundary keeps
+            # the composed visit cadence (once per hard batch)
+            faults.fault_point("enqueue")
+            n_enq = min(n_hard, self.ring.size - self.ring.count)
+            self.ring.note_enqueued(n_enq)
+            if n_enq < n_hard:                # ring filled mid-batch: spill
+                slab = _gather_rows(payload, src)
+                ids = jnp.where(src >= 0,
+                                jnp.take(sample_ids, jnp.maximum(src, 0)),
+                                -1)
+                self.ring.enqueue(slab, ids, n_hard, self._drain,
+                                  off=n_enq, fire_fault=False)
+        return exit_mask, n_hard
 
     @staticmethod
     def _collect_easy(entry, results: dict) -> None:
@@ -294,10 +242,10 @@ class TwoStageServer(_RingedServer):
 
     def _drain(self) -> None:
         """Pop one bucket from the ring and dispatch stage 2 (async)."""
-        popped = self._pop_bucket()
+        popped = self.ring.pop()
         if popped is None:
             return
-        bucket, bucket_ids = popped
+        bucket, bucket_ids, _ = popped
         logits = self.stage2(bucket)
         self._buckets.append((bucket_ids, logits))
 
@@ -320,24 +268,23 @@ class TwoStageServer(_RingedServer):
                                                            np.int32)))
         hidden, exit_logits = self.stage1(tokens)
         if self._use_fused():
-            exit_mask, _, conf, n_hard = self._fused_dispatch_enqueue(
+            exit_mask, n_hard = self._fused_dispatch_enqueue(
                 exit_logits, ids_dev, hidden,
                 jax.ShapeDtypeStruct(hidden.shape[1:], hidden.dtype))
         else:
-            slab, slab_ids, n_hard_dev, exit_mask, _, conf = _decide_compact(
+            slab, slab_ids, n_hard_dev, exit_mask = _decide_compact(
                 hidden, exit_logits, ids_dev, self.c_thr,
                 backend=dispatch.kernel_backend(), mesh=self.ex1.mesh)
-            if self.conf_sink is not None:    # rides the n_hard sync
-                n_hard_dev, conf_np = jax.device_get((n_hard_dev, conf))
-                self.conf_sink.extend(conf_np)
             n_hard = int(n_hard_dev)          # the one host sync per batch
             if n_hard > 0:
-                self._enqueue_backpressured(slab, slab_ids, n_hard)
+                # chunked, stalling (draining) whenever the ring is out of
+                # space — the Fig. 7 backpressure story
+                self.ring.enqueue(slab, slab_ids, n_hard, self._drain)
         b = int(tokens.shape[0])
         self.stats.n_samples += b
         self.stats.record_decisions(b, n_hard)
         self._easy.append((np.asarray(sample_ids), exit_mask, exit_logits))
-        while self._count >= self.sc.capacity:
+        while self.ring.count >= self.sc.capacity:
             self._drain()
         self._harvest_oldest(results)
 
@@ -345,7 +292,7 @@ class TwoStageServer(_RingedServer):
         """Drain the ring (partial final bucket included) and collect every
         pending device future into ``results`` — the only point that
         deliberately blocks on the device."""
-        while self._count > 0:
+        while self.ring.count > 0:
             self._drain()
         pending = ([x for t in self._easy for x in t[1:]]
                    + [x for t in self._buckets for x in t])
@@ -497,7 +444,7 @@ def paged_seg_pool(rows: dict, page_size: int, n_pages: int) -> dict:
         if not _is_layer_cache(node):
             raise ValueError(f"non-attention cache {jax.tree.structure(node)}"
                              " — not pageable (windowed/recurrent/cross "
-                             "layers keep dense rows)")
+                             "layers have no paged store yet)")
         if "bt" in node:
             raise ValueError("rows template is already paged")
         return {k: _pool_leaf(v, lead) for k, v in node.items()}
@@ -545,52 +492,29 @@ def _sanitize_paged_bucket(bt_rows, ids, step, sentinel: int):
             jnp.where(bad, sentinel, step))
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _merge_bucket_logits(merged, ids, logits):
-    """Exit Merge, one bucket at a time: overwrite hard samples' rows of
-    the per-step logits with their stage-2 results (flush ids dropped)."""
-    safe = jnp.where(ids >= 0, ids, merged.shape[0])
-    return merged.at[safe].set(logits, mode="drop")
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _merge_bucket_tokens(tok_vec, ids, logits):
-    """Exit Merge for the greedy token lane: easy rows keep the decision
-    kernel's pred (already argmax of the exit logits — no second logits
-    pass), hard rows take their bucket's argmax (flush ids dropped)."""
-    safe = jnp.where(ids >= 0, ids, tok_vec.shape[0])
-    s2_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return tok_vec.at[safe].set(s2_tok, mode="drop")
-
-
-@jax.jit
-def _greedy_tokens(logits):
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-
-
 class DecodeFns(NamedTuple):
-    """Jitted decode-stage callables shared by ``DecodeServer``, the
-    host-loop baseline AND the continuous scheduler, so benchmark deltas are
-    purely the exit/scheduling machinery and parity is bitwise (sync) /
-    per-sample token-equivalent (continuous). ``step`` may be the scalar
-    batch position (sync) or a per-row (B,) vector (continuous pool).
-    ``s1_raw`` is the un-jitted stage-1 body: the continuous pool tick
-    inlines it inside its own jitted step (masked cache select around it),
-    which a donating jit wrapper would get in the way of. A
+    """Jitted decode-stage callables shared by the continuous scheduler AND
+    the host-loop reference, so the scheduler's per-sample token streams
+    can equal the reference's. ``step`` may be the scalar batch position
+    (host loop) or a per-row (B,) vector (the scheduler's slot pool).
+    ``s1_raw`` is the un-jitted stage-1 body: the pool tick inlines it
+    inside its own jitted step (masked cache select around it), which a
+    donating jit wrapper would get in the way of. A
     ``jax.tree_util.Partial`` binding the stage-1 params keeps them tick
     ARGUMENTS rather than constants baked into the tick program."""
     prefill: Callable   # (tokens (B,S), max_len static) -> (logits, caches)
     split: Callable     # caches -> (stage1_caches, stage2_cache_rows)
     s1: Callable        # (tok (B,1), c1, step) -> (h (B,d), c1', exit_logits)
-    s2: Callable        # (h (C,d), cache_rows, step) -> (logits, new_rows)
+    s2: Callable        # (h (C,d), cache_rows, step) -> (logits, new_rows):
+                        # the dense stage 2 the host-loop reference runs
     s1_raw: Callable    # s1's body, un-jitted (continuous pool tick)
     # For a model with MoE layers, prefill, s1/s1_raw, s2 and s2_paged
     # return one more value last: the MoE layers' summed load counters
     # (moe.LOAD_FIELDS), fetched with what the host already syncs on.
-    # paged stage-2 cache (None = dense). When set, the scheduler/server
-    # store the stage-2 cache as page pools + per-slot block tables; the
-    # ring's cache payload is the (max_pages,) i32 table row, not dense
-    # cache rows.
+    # The paged stage-2 surface the scheduler serves with (None when built
+    # without a page_size, which only the host-loop reference accepts): the
+    # stage-2 cache lives in page pools + per-slot block tables, and the
+    # ring's cache payload is the (max_pages,) i32 table row.
     page_size: Optional[int] = None
     s2_paged: Optional[Callable] = None   # (h, bt, step, pool) -> (logits, pool')
     pool_init: Optional[Callable] = None  # (rows template, n_pages) -> pool
@@ -731,191 +655,14 @@ def decode_step0_confidences(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
     return ed.softmax_confidence(exit_logits)
 
 
-class DecodeServer(_RingedServer):
-    """Device-resident decode-time two-stage EE server.
-
-    ``generate`` prefills the full-depth model (populating both cache
-    segments for the prompt), then decodes greedily with a per-token exit
-    decision: each step runs stage 1 on the whole batch, the fused
-    decision/compaction kernels emit the hard-token slab, and the hard
-    tokens' hidden rows + their stage-2 KV-cache segment rows ride the
-    pytree ring into bucketed stage-2 dispatches. Updated cache rows
-    scatter back on device; easy tokens never touch stage 2 (their stage-2
-    cache keeps zeros at that position — exit-gap semantics, identical in
-    the host baseline). The only per-step host sync is the scalar
-    ``n_hard``; merged per-step logits are harvested lazily under
-    ``max_pending``.
-
-    Under a disaggregated ``placement`` the stage-2 cache store, the ring
-    and the bucketed ``stage2_decode`` dispatches live on ``ex2``'s submesh
-    while stage 1, the exit kernels and the merged logits stay on ``ex1``:
-    each step's hard slab hops ex1 -> ex2 (enqueue) and each bucket's
-    logits hop ex2 -> ex1 (exit merge) as ``jax.device_put`` transfers —
-    never through the host.
-    """
-
-    def __init__(self, fns: DecodeFns, sc: ServeConfig,
-                 placement: Optional[StagePlacement] = None):
-        super().__init__(sc, placement)
-        self.fns = fns
-        self._c1 = None          # stage-1 segment caches (run_layers layout)
-        self._rows = None        # stage-2 segment cache, sample-major rows
-                                 # (paged mode: the (B, M) block-table lane)
-        self._pool = None        # paged mode: the stage-2 page pools
-        self._max_len = 0        # paged mode: the append sentinel
-        self._ids = None         # arange(B) device constant
-        self._pos = None         # current absolute position (drains need it)
-        self._step_buckets: List[Tuple[jnp.ndarray, jnp.ndarray]] = []
-
-    # -- internal ------------------------------------------------------------
-
-    def _drain(self) -> None:
-        popped = self._pop_bucket()
-        if popped is None:
-            return
-        bucket, bucket_ids = popped
-        if self.fns.page_size is not None:
-            # shared pool: flush rows must not append (a flush slot clones
-            # batch row 0's payload — possibly an EASY row, whose stage-2
-            # pages must keep zeros at this step: exit-gap semantics)
-            bt_safe, step_safe = _sanitize_paged_bucket(
-                bucket["cache"], bucket_ids, self._pos,
-                sentinel=self._max_len)
-            logits, self._pool, *_ = self.fns.s2_paged(
-                bucket["h"], bt_safe, step_safe, self._pool)
-        else:
-            logits, new_rows, *_ = self.fns.s2(bucket["h"], bucket["cache"],
-                                               self._pos)
-            self._rows = _scatter_rows(self._rows, new_rows, bucket_ids)
-        self._step_buckets.append((bucket_ids, logits))
-
-    def _step(self, tok, pos: int):
-        """One decode step for the whole batch; returns (merged (B, V)
-        logits, next greedy tokens (B, 1)), both device-side on ex1. The
-        token lane starts as the decision kernel's pred (easy rows' argmax
-        comes free with the exit decision) and hard rows are overwritten
-        per bucket. Ring drains fully — decode is step-synchronous."""
-        h_rows, self._c1, exit_logits, *_ = self.fns.s1(tok, self._c1, pos)
-        self._pos = pos
-        self._step_buckets = []
-        if self._use_fused():
-            # fused: hard rows' hidden AND stage-2 cache rows land in the
-            # ring in the same pass (self._ids is arange(B), so the op's
-            # gather-by-src is exactly the composed gather-by-ids)
-            row_spec = {
-                "h": jax.ShapeDtypeStruct(h_rows.shape[1:], h_rows.dtype),
-                "cache": jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
-                    self._rows)}
-            _, pred, conf, n_hard = self._fused_dispatch_enqueue(
-                exit_logits, self._ids, {"h": h_rows, "cache": self._rows},
-                row_spec)
-            b = h_rows.shape[0]
-            self.stats.record_decisions(b, n_hard)
-        else:
-            slab, slab_ids, n_hard_dev, _, pred, conf = _decide_compact(
-                h_rows, exit_logits, self._ids, self.c_thr,
-                backend=dispatch.kernel_backend(), mesh=self.ex1.mesh)
-            if self.conf_sink is not None:   # rides the n_hard sync
-                n_hard_dev, conf_np = jax.device_get((n_hard_dev, conf))
-                self.conf_sink.extend(conf_np)
-            n_hard = int(n_hard_dev)         # the one host sync per step
-            b = h_rows.shape[0]
-            self.stats.record_decisions(b, n_hard)
-            if n_hard > 0:
-                # ex1 -> ex2 hop: the id lane crosses first (the cache
-                # gather runs ON ex2 — the store never leaves stage 2's
-                # submesh); the hidden slab crosses inside the enqueue's
-                # place_io
-                slab_ids = self.ex2.place_io(slab_ids)
-                cache_slab = _gather_rows(self._rows, slab_ids)
-                self._enqueue_backpressured({"h": slab, "cache": cache_slab},
-                                            slab_ids, n_hard)
-        while self._count > 0:               # full buckets, then the partial
-            self._drain()
-        merged = exit_logits
-        tok_vec = pred
-        for bucket_ids, logits in self._step_buckets:
-            # ex2 -> ex1 hop: bucket results come home for the exit merge
-            ids1 = self.ex1.place_io(bucket_ids)
-            logits1 = self.ex1.place_io(logits)
-            merged = _merge_bucket_logits(merged, ids1, logits1)
-            tok_vec = _merge_bucket_tokens(tok_vec, ids1, logits1)
-        return merged, tok_vec[:, None]
-
-    # -- public --------------------------------------------------------------
-
-    def generate(self, prompt: np.ndarray, n_tokens: int) -> dict:
-        """Greedy EE generation: prefill the (B, S) prompt, then emit
-        ``n_tokens`` tokens (the first from the prefill logits, the rest
-        from per-token two-stage decode). Returns {'tokens' (B, n_tokens),
-        'logits' (B, n_tokens, V)} as host arrays."""
-        if n_tokens < 1:
-            raise ValueError(f"n_tokens must be >= 1, got {n_tokens}")
-        prompt = self.ex1.place_io(jnp.asarray(np.asarray(prompt, np.int32)))
-        B, S = prompt.shape
-        self.stats.n_samples += B
-        self.ring.reset()                    # fresh ring per stream shape
-        self._ids = self.ex1.place_io(jnp.arange(B, dtype=jnp.int32))
-        logits0, caches, *_ = self.fns.prefill(prompt, S + n_tokens)
-        self._c1, rows = self.fns.split(caches)
-        if self.fns.page_size is not None:
-            # paged parity mode: an identity block table (row b owns pages
-            # [1 + b*M, 1 + (b+1)*M)) over a pool exactly sized for the
-            # batch — the dense oracle with the paged data path
-            page = self.fns.page_size
-            if (S + n_tokens) % page != 0:
-                raise ValueError(
-                    f"paged decode needs S + n_tokens divisible by "
-                    f"page_size={page}, got {S} + {n_tokens}")
-            self._max_len = S + n_tokens
-            M = self._max_len // page
-            bt = 1 + jnp.arange(B * M, dtype=jnp.int32).reshape(B, M)
-            rows = self.ex2.place_io(rows)
-            pool = self.ex2.place_io(self.fns.pool_init(rows, B * M + 1))
-            bt = self.ex2.place_io(bt)
-            self._pool = self.fns.admit_pages(pool, rows, bt)
-            self._rows = bt              # the ring's cache payload lane
-            self.stats.cache_pages_total = B * M
-            self.stats.cache_pages_in_use = B * M
-            self.stats.cache_page_size = page
-            # end-of-stream occupancy: every row fills its span
-            self.stats.live_tokens = B * (S + n_tokens - 1)
-            self.stats.cache_hbm_bytes = sum(
-                leaf.nbytes for leaf in jax.tree.leaves(self._pool))
-        else:
-            # the stage-2 cache store migrates to its home submesh once, at
-            # stream start (prefill runs on ex1, which holds full params)
-            self._rows = self.ex2.place_io(rows)
-            self.stats.cache_hbm_bytes = sum(
-                leaf.nbytes for leaf in jax.tree.leaves(self._rows))
-        merged = logits0
-        tok = _greedy_tokens(merged)         # t=0: from the prefill logits
-        logits_out: List = [None] * n_tokens
-        toks_out: List = []
-        pending: List[Tuple[int, jnp.ndarray]] = []
-        for t in range(n_tokens):
-            toks_out.append(tok)
-            pending.append((t, merged))
-            while len(pending) > self.sc.max_pending:
-                slot, arr = pending.pop(0)
-                logits_out[slot] = np.asarray(arr)
-            if t == n_tokens - 1:
-                break
-            merged, tok = self._step(tok, S + t)
-        for slot, arr in pending:            # flush
-            logits_out[slot] = np.asarray(arr)
-        tokens = np.concatenate([np.asarray(x) for x in toks_out], axis=1)
-        return {"tokens": tokens, "logits": np.stack(logits_out, axis=1)}
-
-
 class HostLoopDecoder:
-    """Per-token host-loop decode baseline (HostLoopServer-style): syncs the
-    exit mask each step, walks the hard tokens in Python, re-stacks each
-    bucket's hidden rows AND cache rows sample by sample, and scatters
-    updated cache rows back one sample at a time. Shares the jitted stage
-    callables with ``DecodeServer``, so merged logits are bitwise identical
-    — the delta is purely the exit machinery."""
+    """Per-token host-loop decode over the dense stage-2 cache — the
+    reference the continuous scheduler's token streams are checked against
+    (HostLoopServer-style): syncs the exit mask each step, walks the hard
+    tokens in Python, re-stacks each bucket's hidden rows AND cache rows
+    sample by sample, and scatters updated cache rows back one sample at a
+    time. Shares the jitted stage callables with the scheduler, so the two
+    differ only in the exit/scheduling machinery and the cache layout."""
 
     def __init__(self, fns: DecodeFns, sc: ServeConfig):
         self.fns = fns
@@ -1005,76 +752,6 @@ def _stage_fns(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
     # params are program arguments, not closure constants (see
     # decode_stage_fns)
     return functools.partial(s1, p1), functools.partial(s2, p2)
-
-
-# ---------------------------------------------------------------------------
-# DEPRECATED construction factories: keyword-compatible shims over
-# serve_api.build — the one entry point every serving mode shares. Each
-# shim warns once per process (DeprecationWarning) and forwards.
-# ---------------------------------------------------------------------------
-
-def build_server(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
-                 sc: ServeConfig,
-                 placement: Optional[StagePlacement] = None
-                 ) -> TwoStageServer:
-    """DEPRECATED — use ``serve_api.build(mode="prefill")``."""
-    serve_api._deprecated_factory("build_server")
-    return serve_api.build(params, cfg, spec, sc, mode="prefill",
-                           scheduler=None, placement=placement)
-
-
-def build_host_server(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
-                      sc: ServeConfig) -> HostLoopServer:
-    """DEPRECATED — use ``serve_api.build(mode="prefill", host=True)``."""
-    serve_api._deprecated_factory("build_host_server")
-    return serve_api.build(params, cfg, spec, sc, mode="prefill",
-                           scheduler=None, host=True)
-
-
-def build_decode_server(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
-                        sc: ServeConfig,
-                        placement: Optional[StagePlacement] = None
-                        ) -> DecodeServer:
-    """DEPRECATED — use ``serve_api.build(mode="decode",
-    scheduler=None)``."""
-    serve_api._deprecated_factory("build_decode_server")
-    return serve_api.build(params, cfg, spec, sc, mode="decode",
-                           scheduler=None, placement=placement)
-
-
-def build_host_decoder(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
-                       sc: ServeConfig) -> HostLoopDecoder:
-    """DEPRECATED — use ``serve_api.build(mode="decode", scheduler=None,
-    host=True)``."""
-    serve_api._deprecated_factory("build_host_decoder")
-    return serve_api.build(params, cfg, spec, sc, mode="decode",
-                           scheduler=None, host=True)
-
-
-def build_continuous_scheduler(params, cfg: ArchConfig,
-                               spec: ee.EarlyExitSpec, sc: ServeConfig, *,
-                               n_slots: int, max_len: int,
-                               placement: Optional[StagePlacement] = None,
-                               clock=None) -> ContinuousScheduler:
-    """DEPRECATED — use ``serve_api.build(mode="decode",
-    scheduler="continuous")`` (same keywords; carries the ``fns_factory``
-    live migration rebuilds stage callables with)."""
-    serve_api._deprecated_factory("build_continuous_scheduler")
-    return serve_api.build(params, cfg, spec, sc, mode="decode",
-                           scheduler="continuous", placement=placement,
-                           n_slots=n_slots, max_len=max_len, clock=clock)
-
-
-def build_sync_scheduler(params, cfg: ArchConfig, spec: ee.EarlyExitSpec,
-                         sc: ServeConfig, *, n_slots: int,
-                         placement: Optional[StagePlacement] = None,
-                         clock=None) -> SyncScheduler:
-    """DEPRECATED — use ``serve_api.build(mode="decode",
-    scheduler="sync")``."""
-    serve_api._deprecated_factory("build_sync_scheduler")
-    return serve_api.build(params, cfg, spec, sc, mode="decode",
-                           scheduler="sync", placement=placement,
-                           n_slots=n_slots, clock=clock)
 
 
 def serve_dataset(server, tokens: np.ndarray, batch: int) -> dict:

@@ -2,8 +2,7 @@
 machine (warmup / hysteresis / cooldown / bounded actuation), the
 scheduler control surface (threshold, drain policy, occupancy cap,
 discrete-point capacity re-size — with token streams invariant under every
-actuation), closed-loop convergence on a nonstationary trace, and the sync
-policy's actuation path.
+actuation), and closed-loop convergence on a nonstationary trace.
 
 The end-to-end tests drive the REAL ``ContinuousScheduler`` with the drift
 benchmark's semi-synthetic ``DecodeFns`` (analytic confidences/tokens, so
@@ -21,7 +20,7 @@ from repro.runtime import serve_loop as SL
 from repro.runtime import telemetry as TM
 from repro.runtime.controller import ControllerConfig, DriftController
 from repro.runtime.scheduler import (ContinuousScheduler, LogicalClock,
-                                     Request, ServeStats, SyncScheduler)
+                                     Request, ServeStats)
 from repro.runtime.stage_executor import StagePlacement
 
 _S = 4                       # drift_fns prompt length
@@ -500,29 +499,6 @@ def test_closed_loop_converges_on_drift_trace():
     assert ctl.state.c_thr < thr0                    # threshold moved down
 
 
-def test_sync_scheduler_actuation_path():
-    """The sync policy's controller visit: per-batch sensing, conf-sink
-    reservoir feed through DecodeServer, threshold actuation applied."""
-    n, n_tokens, n_slots = 12, 8, 4
-    fns = _flat_fns(n, difficulty=0.4)               # conf ~ 0.31..0.49
-    sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.95)  # all hard
-    ctl = DriftController(ControllerConfig(
-        provisioned_p=0.25, min_decisions=8, persistence_ticks=1,
-        cooldown_ticks=0, max_thr_step=0.5, reservoir_size=128,
-        min_reservoir=16, autoscale=False, replan=False))
-    sched = SyncScheduler(SL.DecodeServer(fns, sc), n_slots,
-                          clock=LogicalClock())
-    ctl.attach(sched)
-    assert sched.server.conf_sink is ctl.reservoir
-    for i in range(n):
-        sched.submit(Request(i, np.full((_S,), i, np.int32), n_tokens))
-    res = sched.run()
-    assert res == _expected(n, n_tokens)
-    assert len(ctl.reservoir) > 0                    # sink fed
-    assert ctl.state.n_recalibrations >= 1
-    assert sched.server.c_thr < 0.95                 # actuation landed
-
-
 @pytest.mark.skipif(
     jax.device_count() < 8,
     reason="needs XLA_FLAGS=--xla_force_host_platform_device_count=8")
@@ -536,13 +512,13 @@ def test_controller_disaggregated_replan_recommends_split(tiny_cfg,
     prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(21), (6, 8),
                                            0, tiny_cfg.vocab))
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=1.1)   # all hard
-    oracle = SL.build_host_decoder(tiny_params, tiny_cfg, tiny_spec,
-                                   sc).generate(prompt, 5)
+    oracle = SL.build(tiny_params, tiny_cfg, tiny_spec, sc, scheduler=None,
+                      host=True).generate(prompt, 5)
     pl = StagePlacement.from_plan(
         StageMeshPlan.proportional(0.5, jax.device_count()))
-    sched = SL.build_continuous_scheduler(tiny_params, tiny_cfg, tiny_spec,
-                                          sc, n_slots=4, max_len=13,
-                                          placement=pl, clock=LogicalClock())
+    sched = SL.build(tiny_params, tiny_cfg, tiny_spec, sc, n_slots=4,
+                     max_len=16, page_size=4, placement=pl,
+                     clock=LogicalClock())
     ctl = DriftController(ControllerConfig(
         provisioned_p=0.25, min_decisions=8, persistence_ticks=1,
         cooldown_ticks=0, recalibrate=False, autoscale=False,

@@ -18,6 +18,7 @@ from repro.runtime import scheduler as SCH
 from repro.runtime import serve_loop as SL
 from repro.runtime.scheduler import (ContinuousScheduler, LogicalClock,
                                      Request)
+from test_scheduler import _TOY_S, _toy_tok, toy_decode_fns
 
 try:
     from hypothesis import given, settings, strategies as st_h
@@ -272,55 +273,8 @@ def test_pred_matches_argmax_bitwise(backend):
 # the single-launch steady-state tick contract
 # ---------------------------------------------------------------------------
 
-_TOY_VOCAB = 32
-_TOY_S = 4
-
-
-def _toy_tok(sid, t):
-    return (3 + sid * 31 + t * 7) % _TOY_VOCAB
-
-
-def _toy_hard(sid, t, q_pct):
-    return ((sid * 131 + t * 17) % 100) < q_pct
-
-
-def _toy_decode_fns(q_pct: int, trace_counter=None):
-    """Analytic DecodeFns (same construction as test_scheduler's): exit
-    decisions and greedy tokens are pure functions of (sample id, decode
-    index). ``trace_counter`` counts s1_raw TRACES (not executions) — the
-    single-program assertion below."""
-
-    def _logits(sid, t):
-        tok = _toy_tok(sid, t)
-        hard = _toy_hard(sid, t, q_pct)
-        oh = jax.nn.one_hot(tok, _TOY_VOCAB)
-        return jnp.where(hard[:, None], oh * 1e-3, oh * 50.0)
-
-    def prefill(prompts, max_len):
-        sid = prompts[:, 0].astype(jnp.int32)
-        caches = {"first": [sid[:, None]], "blocks": (), "rem": []}
-        return _logits(sid, jnp.zeros_like(sid)), caches
-
-    def split(caches):
-        return caches, {"sid": caches["first"][0]}
-
-    def s1_raw(tok, c1, pos):
-        if trace_counter is not None:
-            trace_counter["n"] += 1          # runs at trace time only
-        sid = c1["first"][0][:, 0]
-        t = pos - _TOY_S + 1
-        h = jnp.stack([sid, pos], 1).astype(jnp.float32)
-        return h, c1, _logits(sid, t)
-
-    def s2(h_rows, cache_rows, step):
-        sid = cache_rows["sid"][:, 0]
-        return _logits(sid, step - _TOY_S + 1), cache_rows
-
-    return SL.DecodeFns(prefill, split, jax.jit(s1_raw), s2, s1_raw)
-
-
 def _toy_run(q_pct, n_toks, *, n_slots, capacity, queue_depth):
-    fns = _toy_decode_fns(q_pct)
+    fns = toy_decode_fns(q_pct)
     sc = SL.ServeConfig(capacity=capacity, queue_depth=queue_depth,
                         c_thr=0.5)
     sched = ContinuousScheduler(fns, sc, n_slots=n_slots,
@@ -364,7 +318,7 @@ def test_steady_state_tick_is_single_program(monkeypatch):
     fused launch (the composed tick would raise), no separate enqueue
     program runs, and the stage-1 body is never retraced once warm."""
     traces = {"n": 0}
-    fns = _toy_decode_fns(0, trace_counter=traces)     # all-easy traffic
+    fns = toy_decode_fns(0, trace_counter=traces)     # all-easy traffic
     fused_calls = {"n": 0}
     real_fused = SCH._pool_tick_fused
 
@@ -405,7 +359,7 @@ def test_steady_state_tick_is_single_program(monkeypatch):
 def test_fused_tick_off_for_disaggregated_placement():
     """A placement whose stages live on different submeshes must keep the
     composed chain (the enqueue IS the cross-submesh hop)."""
-    fns = _toy_decode_fns(50)
+    fns = toy_decode_fns(50)
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
     sched = ContinuousScheduler(fns, sc, n_slots=2, max_len=_TOY_S + 4,
                                 clock=LogicalClock())
@@ -422,12 +376,12 @@ def test_fused_tick_falls_back_when_fns_resist_eval_shape():
     """Duck-typed host-side stage fns that cannot be abstractly evaluated
     (they pull their inputs to the host) must keep the composed tick
     rather than fail at pool build."""
-    fns = _toy_decode_fns(0)
+    fns = toy_decode_fns(0)
 
     def opaque_s1(tok, c1, pos):
         return np.asarray(tok)          # host-side: no abstract evaluation
 
-    hacked = SL.DecodeFns(fns.prefill, fns.split, fns.s1, fns.s2, opaque_s1)
+    hacked = fns._replace(s1_raw=opaque_s1)
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
     sched = ContinuousScheduler(hacked, sc, n_slots=2, max_len=_TOY_S + 4,
                                 clock=LogicalClock())
@@ -441,12 +395,12 @@ def test_fused_tick_falls_back_when_fns_resist_eval_shape():
 def test_fused_tick_eval_shape_failure_on_traceable_fns_raises():
     """A stage-1 body that traces but fails (a real model bug) must raise
     at pool build, never drop silently to the composed tick."""
-    fns = _toy_decode_fns(0)
+    fns = toy_decode_fns(0)
 
     def broken_s1(tok, c1, pos):
         return tok @ jnp.zeros((3, 3), tok.dtype)   # shape mismatch
 
-    broken = SL.DecodeFns(fns.prefill, fns.split, fns.s1, fns.s2, broken_s1)
+    broken = fns._replace(s1_raw=broken_s1)
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
     sched = ContinuousScheduler(broken, sc, n_slots=2, max_len=_TOY_S + 4,
                                 clock=LogicalClock())

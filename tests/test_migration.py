@@ -361,14 +361,14 @@ def test_real_model_resplit_subprocess():
     c_thr = float(jnp.quantile(conf, 0.5))
     spec = ee.EarlyExitSpec(exit_layer=2, c_thr=c_thr)
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=c_thr)
-    oracle = SL.build_host_decoder(params, cfg, spec, sc).generate(prompt, 5)
+    oracle = SL.build(params, cfg, spec, sc, scheduler=None,
+                      host=True).generate(prompt, 5)
     want = {i: [int(x) for x in oracle["tokens"][i][:n_toks[i]]]
             for i in range(6)}
     pl_a = StagePlacement.from_plan(StageMeshPlan.proportional(0.5, 8))
     pl_b = StagePlacement.from_plan(StageMeshPlan.proportional(0.25, 8))
-    s = SL.build_continuous_scheduler(params, cfg, spec, sc, n_slots=3,
-                                      max_len=13, placement=pl_a,
-                                      clock=LogicalClock())
+    s = SL.build(params, cfg, spec, sc, n_slots=3, max_len=16,
+                 page_size=4, placement=pl_a, clock=LogicalClock())
     plan = MigrationPlan(placement=pl_b, fns=s.fns_factory(pl_b),
                          capacity=3, reason="resplit")
     class Trig:

@@ -4,11 +4,10 @@ Layers under test, bottom up: the ``paged_attention`` kernel family
 (gather + tail-page append, interpret kernel vs jnp ref, bitwise), the
 model decode paths (``attention_decode``/``mla_decode`` paged vs dense —
 bitwise, because both route the gathered cache through ONE masked decode
-core), the step-synchronous ``DecodeServer`` (paged tokens AND logits
-bitwise-equal to dense and to the host oracle), the continuous scheduler
-(paged streams == dense streams == host oracle across the calibrated q
-grid, incl. ring wraparound/overflow and a page-constrained pool that
-exercises admission backpressure), the ``PageAllocator`` invariants
+core), the continuous scheduler (paged streams == the dense host oracle
+across the calibrated q grid, incl. ring wraparound/overflow and a
+page-constrained pool that exercises admission backpressure; fns it
+cannot page rejected), the ``PageAllocator`` invariants
 (deterministic sweep always; hypothesis when available), live migration
 (paged pool re-placed, rollback restores allocator state exactly), and an
 8-device disaggregated subprocess bar.
@@ -155,48 +154,14 @@ def test_mla_decode_paged_bitwise():
                    S0=2)
 
 
-# ---------------------------------------------------------------------------
-# step-synchronous server: paged generate bitwise-equal to dense + oracle
-# ---------------------------------------------------------------------------
-
 @pytest.fixture(scope="module")
 def prompt(tiny_cfg):
     return np.asarray(jax.random.randint(jax.random.PRNGKey(77), (6, 6), 0,
                                          tiny_cfg.vocab))
 
 
-def test_sync_server_paged_bitwise(tiny_cfg, tiny_params, tiny_spec,
-                                   prompt):
-    S, n_tok, page = prompt.shape[1], 10, 4
-    assert (S + n_tok) % page == 0
-    sc = ServeConfig(capacity=3, queue_depth=2, c_thr=0.7)
-    dense_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec)
-    paged_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec,
-                                    page_size=page)
-    out_d = SL.DecodeServer(dense_fns, sc).generate(prompt, n_tok)
-    srv_p = SL.DecodeServer(paged_fns, sc)
-    out_p = srv_p.generate(prompt, n_tok)
-    np.testing.assert_array_equal(out_d["tokens"], out_p["tokens"])
-    np.testing.assert_array_equal(out_d["logits"], out_p["logits"])
-    oracle = SL.HostLoopDecoder(dense_fns, sc).generate(prompt, n_tok)
-    np.testing.assert_array_equal(oracle["tokens"], out_p["tokens"])
-    # v3 gauges: the sync paged pool is exactly batch-sized
-    st = srv_p.stats
-    Mp = (S + n_tok) // page
-    assert st.cache_pages_total == st.cache_pages_in_use \
-        == prompt.shape[0] * Mp
-    assert st.cache_hbm_bytes > 0
-
-
-def test_sync_server_paged_needs_page_multiple(tiny_cfg, tiny_params,
-                                               tiny_spec, prompt):
-    fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec, page_size=4)
-    with pytest.raises(ValueError, match="divisible"):
-        SL.DecodeServer(fns, ServeConfig(capacity=2)).generate(prompt, 7)
-
-
 # ---------------------------------------------------------------------------
-# continuous scheduler: paged streams == dense streams == host oracle
+# continuous scheduler: paged streams == the dense host oracle
 # ---------------------------------------------------------------------------
 
 N_TOKS = [7, 3, 5, 1, 7, 2]
@@ -217,8 +182,7 @@ def _expect(oracle_tokens, n_toks):
 
 def test_continuous_paged_q_grid(tiny_cfg, tiny_params, tiny_spec, prompt):
     """The acceptance bar, single-device: paged continuous streams equal
-    the dense continuous streams AND the host-loop oracle at calibrated
-    q ∈ {0.1, 0.3, 0.5}."""
+    the dense host-loop oracle at calibrated q ∈ {0.1, 0.3, 0.5}."""
     max_len = prompt.shape[1] + max(N_TOKS) + 3   # 16: a page multiple
     conf = np.asarray(SL.decode_step0_confidences(
         tiny_params, tiny_cfg, tiny_spec, prompt, max_len=max_len))
@@ -231,11 +195,9 @@ def test_continuous_paged_q_grid(tiny_cfg, tiny_params, tiny_spec, prompt):
         oracle = SL.HostLoopDecoder(dense_fns, sc).generate(prompt,
                                                             max(N_TOKS))
         want = _expect(oracle["tokens"], N_TOKS)
-        res_d, _ = _run_sched(dense_fns, sc, prompt, N_TOKS, n_slots=3,
-                              max_len=max_len)
         res_p, sp = _run_sched(paged_fns, sc, prompt, N_TOKS, n_slots=3,
                                max_len=max_len)
-        assert res_d == want and res_p == want, q
+        assert res_p == want, q
         # drained pool: every page came home
         assert sp._alloc.n_free == sp.n_pages
         assert sp.stats.cache_pages_total == sp.n_pages
@@ -261,19 +223,18 @@ def test_continuous_paged_ring_overflow(tiny_cfg, tiny_params, tiny_spec,
 
 def test_continuous_paged_tight_pool_backpressure(tiny_cfg, tiny_params,
                                                   tiny_spec, prompt):
-    """A pool holding FEWER pages than dense equivalence: admission
+    """A pool holding FEWER pages than a full-width store: admission
     backpressures on the free list (head blocks, nothing drops) and the
-    streams still match dense."""
+    streams still match the host oracle."""
     sc = ServeConfig(capacity=2, queue_depth=2, c_thr=0.7)
     dense_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec)
     paged_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec,
                                     page_size=4)
-    res_d, _ = _run_sched(dense_fns, sc, prompt, N_TOKS, n_slots=4,
-                          max_len=16)
+    oracle = SL.HostLoopDecoder(dense_fns, sc).generate(prompt, max(N_TOKS))
     # each request needs at most ceil((6+7-1)/4)=3 pages; 7 pages < 4*4
     res_p, sp = _run_sched(paged_fns, sc, prompt, N_TOKS, n_slots=4,
                            max_len=16, n_pages=7)
-    assert res_d == res_p
+    assert res_p == _expect(oracle["tokens"], N_TOKS)
     assert sp._alloc.n_free == 7
     assert sp.stats.n_samples == len(N_TOKS)
 
@@ -290,19 +251,37 @@ def test_continuous_paged_rejects_oversized_request(tiny_cfg, tiny_params,
         s.drain()
 
 
+@pytest.mark.parametrize("fns_page,max_len,match", [
+    (None, 16, "lack the paged decode surface"),
+    (4, 14, "must be a multiple of page_size"),
+], ids=["no_paged_surface", "max_len_not_page_multiple"])
+def test_scheduler_rejects_fns_it_cannot_page(tiny_cfg, tiny_params,
+                                              tiny_spec, fns_page, max_len,
+                                              match):
+    """The scheduler serves only the paged store: stage fns built without
+    a page size, or a pool width that is not a whole number of pages, are
+    refused at construction."""
+    fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec,
+                              page_size=fns_page)
+    with pytest.raises(ValueError, match=match):
+        ContinuousScheduler(fns, ServeConfig(capacity=2), n_slots=2,
+                            max_len=max_len, clock=LogicalClock())
+
+
 def test_paged_ring_ships_indices_not_rows(tiny_cfg, tiny_params,
                                            tiny_spec, prompt):
-    """The perf story the ring gauge tells: the paged payload hops page
-    INDICES, so ring_bytes_moved collapses vs dense at identical traffic."""
+    """The perf story the ring gauge tells: the payload hops page INDICES —
+    per hard token, the hidden row, the (max_pages,) i32 block-table row
+    and the i32 step; no cache row."""
     sc = ServeConfig(capacity=2, queue_depth=2, c_thr=1.1)   # all-hard
     n_toks = [5] * prompt.shape[0]
-    dense_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec)
     paged_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec,
                                     page_size=4)
-    _, sd = _run_sched(dense_fns, sc, prompt, n_toks, n_slots=3, max_len=12)
     _, sp = _run_sched(paged_fns, sc, prompt, n_toks, n_slots=3, max_len=12)
-    assert sd.stats.ring_bytes_moved > 0 and sp.stats.ring_bytes_moved > 0
-    assert sd.stats.ring_bytes_moved >= 5 * sp.stats.ring_bytes_moved
+    h_bytes = tiny_cfg.d_model * jnp.dtype(tiny_cfg.act_dtype()).itemsize
+    row_bytes = h_bytes + (12 // 4) * 4 + 4
+    assert sp.stats.n_stage2 == sum(n - 1 for n in n_toks)
+    assert sp.stats.ring_bytes_moved == sp.stats.n_stage2 * row_bytes
     # and the v3 dict carries all of it
     d = sp.stats.as_dict()
     for k in ("cache_pages_total", "cache_pages_in_use", "cache_pages_free",
@@ -427,8 +406,8 @@ def _mig_sched(fns, *, mig_after, plan, prompt, n_toks, n_pages=None):
 def test_paged_migration_stream_equivalence(tiny_cfg, tiny_params,
                                             tiny_spec, prompt):
     """A mid-trace capacity migration over a LIVE paged pool: streams
-    bitwise-equal to the unmigrated paged (and dense) run, pool and
-    allocator migrated, zero rollbacks."""
+    bitwise-equal to the unmigrated run, pool and allocator migrated, zero
+    rollbacks."""
     paged_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec,
                                     page_size=4)
     base, _ = _run_sched(paged_fns,
@@ -470,25 +449,30 @@ def test_paged_migration_rollback_restores_allocator(tiny_cfg, tiny_params,
     assert sched._alloc.n_free == sched.n_pages      # every page home
 
 
-def test_paged_migration_rejects_dense_fns(tiny_cfg, tiny_params, tiny_spec,
-                                           prompt):
-    """Migrating a paged scheduler onto dense stage fns must roll back
-    (the live page pool's layout is not convertible mid-serve)."""
+@pytest.mark.parametrize("fns_page", [None, 2],
+                         ids=["no_paged_surface", "other_page_size"])
+def test_paged_migration_rejects_fns_of_another_page_layout(
+        tiny_cfg, tiny_params, tiny_spec, prompt, fns_page):
+    """Migrating onto stage fns without the paged surface, or with another
+    page size, must roll back (the live page pool's layout is not
+    convertible mid-serve)."""
     from repro.runtime.stage_executor import StagePlacement
     paged_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec,
                                     page_size=4)
-    dense_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec)
+    bad_fns = SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec,
+                                  page_size=fns_page)
     with faults.installed(None):
         sched = _mig_sched(
             paged_fns, mig_after=3, prompt=prompt, n_toks=N_TOKS,
             plan=MigrationPlan(placement=StagePlacement.single_device(),
-                               fns=dense_fns, reason="bad"))
+                               fns=bad_fns, reason="bad"))
         res = sched.drain()
     base, _ = _run_sched(paged_fns,
                          ServeConfig(capacity=2, queue_depth=2, c_thr=0.7),
                          prompt, N_TOKS, n_slots=3, max_len=16)
     assert res == base
     assert sched.stats.n_migration_rollbacks == 1
+    assert sched.fns is paged_fns
 
 
 # ---------------------------------------------------------------------------
@@ -496,9 +480,9 @@ def test_paged_migration_rejects_dense_fns(tiny_cfg, tiny_params, tiny_spec,
 # ---------------------------------------------------------------------------
 
 def test_paged_disaggregated_subprocess():
-    """Paged continuous streams equal dense streams AND the host oracle,
-    single-device and stage-disaggregated, at calibrated q ∈ {0.1, 0.3,
-    0.5}, under --xla_force_host_platform_device_count=8."""
+    """Paged continuous streams equal the dense host oracle, single-device
+    and stage-disaggregated, at calibrated q ∈ {0.1, 0.3, 0.5}, under
+    --xla_force_host_platform_device_count=8."""
     code = ("import os\n"
             "os.environ['XLA_FLAGS']="
             "'--xla_force_host_platform_device_count=8'\n"

@@ -182,7 +182,8 @@ def _hlo_shapes(hlo):
 def _stage2_hlo(cfg, spec, params):
     """The compiled stage-2 decode program on the full tree (one device):
     the paged bucket program where every layer is global attention, else
-    the dense one (recurrent and windowed layers keep dense rows)."""
+    the dense one of the host-loop reference (recurrent and windowed
+    layers have no paged store)."""
     fns = SL.decode_stage_fns(params, cfg, spec, page_size=4)
     _, full, *_ = fns.prefill(jnp.zeros((B, S), jnp.int32), max_len=S + 4)
     _, rows = fns.split(full)

@@ -127,8 +127,9 @@ def test_server_matches_one_shot(tiny_cfg, tiny_params):
     B, S, N = 4, 8, 16
     toks = np.asarray(jax.random.randint(jax.random.PRNGKey(5), (N, S), 0,
                                          tiny_cfg.vocab))
-    server = SL.build_server(tiny_params, tiny_cfg, spec,
-                             SL.ServeConfig(capacity=4, c_thr=spec.c_thr))
+    server = SL.build(tiny_params, tiny_cfg, spec,
+                      SL.ServeConfig(capacity=4, c_thr=spec.c_thr),
+                      mode="prefill", scheduler=None)
     results = SL.serve_dataset(server, toks, batch=B)
     assert set(results) == set(range(N))
     assert server.stats.n_samples == N
@@ -144,8 +145,9 @@ def test_server_matches_one_shot(tiny_cfg, tiny_params):
 
 def test_server_realized_q(tiny_cfg, tiny_params):
     spec = ee.EarlyExitSpec(exit_layer=2, c_thr=0.0)   # everything exits
-    server = SL.build_server(tiny_params, tiny_cfg, spec,
-                             SL.ServeConfig(capacity=2, c_thr=0.0))
+    server = SL.build(tiny_params, tiny_cfg, spec,
+                      SL.ServeConfig(capacity=2, c_thr=0.0),
+                      mode="prefill", scheduler=None)
     toks = np.zeros((8, 8), np.int32)
     res = SL.serve_dataset(server, toks, batch=4)
     assert server.stats.realized_q == 0.0

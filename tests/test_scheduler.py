@@ -1,6 +1,7 @@
-"""Continuous-batching decode scheduler: per-sample token-stream
-equivalence against the host-loop oracle (the continuous correctness
-contract — same greedy tokens per sample id, any interleaving), scheduler
+"""Continuous-batching decode scheduler over the paged stage-2 store:
+per-sample token-stream equivalence against the host-loop oracle (the
+continuous correctness contract — same greedy tokens per sample id, any
+interleaving), the exit-gap cache semantics, scheduler
 invariants under random traces (hypothesis over toy stage callables),
 latency / realized-q statistics, the per-metric tolerance machinery in
 benchmarks/compare.py, and the disaggregated equivalence bar (in-process
@@ -16,13 +17,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.serve_drift import sid_pages
 from repro.core import early_exit as ee
 from repro.runtime import serve_loop as SL
+from repro.models import transformer as T
 from repro.runtime.scheduler import (ContinuousScheduler, LogicalClock,
-                                     Request, ServeStats, SyncScheduler,
-                                     poisson_arrivals)
+                                     Request, ServeStats, poisson_arrivals)
 
 _REPO_ROOT = Path(__file__).resolve().parents[1]
+PAGE = 4                     # the stage-2 page size of the model fns here
+
+
+def _width(n: int) -> int:
+    """The pool's cache width for requests of up to ``n`` tokens: rounded
+    up to a whole page."""
+    return -(-n // PAGE) * PAGE
 
 
 @pytest.fixture(scope="module")
@@ -33,7 +42,8 @@ def prompt(tiny_cfg):
 
 @pytest.fixture(scope="module")
 def fns(tiny_cfg, tiny_params, tiny_spec):
-    return SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec)
+    return SL.decode_stage_fns(tiny_params, tiny_cfg, tiny_spec,
+                               page_size=PAGE)
 
 
 def _decode_conf(tiny_cfg, tiny_params, tiny_spec, prompt, max_len):
@@ -52,7 +62,8 @@ N_TOKS = [7, 3, 5, 1, 7, 2]          # variable lengths incl. a prefill-only
 
 def _run_continuous(fns, sc, prompt, n_tokens, n_slots, max_len,
                     arrivals=None, **kw):
-    sched = ContinuousScheduler(fns, sc, n_slots=n_slots, max_len=max_len,
+    sched = ContinuousScheduler(fns, sc, n_slots=n_slots,
+                                max_len=_width(max_len),
                                 clock=LogicalClock(), **kw)
     for i in range(len(n_tokens)):
         t = 0.0 if arrivals is None else float(arrivals[i])
@@ -66,24 +77,41 @@ def _run_continuous(fns, sc, prompt, n_tokens, n_slots, max_len,
 # host-loop oracle — all-exit, none-exit, mixed, and the calibrated q grid
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("c_thr", [0.0, 1.1, None])
+@pytest.mark.parametrize("c_thr,n_toks", [
+    pytest.param(c_thr, n_toks, id=f"{c_thr}{suffix}")
+    for suffix, n_toks in [("", N_TOKS),
+                           ("-equal_lengths", [max(N_TOKS)] * len(N_TOKS))]
+    for c_thr in (0.0, 1.1, None)])
 def test_continuous_token_stream_equivalence(tiny_cfg, tiny_params,
-                                             tiny_spec, prompt, fns, c_thr):
-    """A pool smaller than the request count (backfill), variable lengths
-    (incl. a one-token request), every sample's stream equal to the
-    host-loop decode — for all-exit, none-exit, and mixed traffic."""
+                                             tiny_spec, prompt, fns, c_thr,
+                                             n_toks):
+    """A pool smaller than the request count (backfill), every sample's
+    stream equal to the host-loop decode — for all-exit, none-exit, and
+    mixed traffic. One exit decision per generated token after the first;
+    where every request runs the oracle's length, the exit and stage-2
+    counts equal the oracle's too."""
     max_tok = max(N_TOKS)
     if c_thr is None:
         conf = _decode_conf(tiny_cfg, tiny_params, tiny_spec, prompt,
                             prompt.shape[1] + max_tok)
         c_thr = float(np.median(conf))
     sc = SL.ServeConfig(capacity=3, queue_depth=2, c_thr=c_thr)
-    oracle = SL.HostLoopDecoder(fns, sc).generate(prompt, max_tok)
-    res, sched = _run_continuous(fns, sc, prompt, N_TOKS, n_slots=4,
+    host = SL.HostLoopDecoder(fns, sc)
+    oracle = host.generate(prompt, max_tok)
+    res, sched = _run_continuous(fns, sc, prompt, n_toks, n_slots=4,
                                  max_len=prompt.shape[1] + max_tok)
-    assert res == _expect_streams(oracle["tokens"], N_TOKS)
-    assert sched.stats.n_samples == len(N_TOKS)
-    assert sched.stats.n_finished == len(N_TOKS)
+    assert res == _expect_streams(oracle["tokens"], n_toks)
+    st = sched.stats
+    assert st.n_samples == st.n_finished == len(n_toks)
+    n_dec = sum(n - 1 for n in n_toks)
+    assert st.n_decisions == st.as_dict()["n_decisions"] == n_dec
+    assert st.n_exited + st.n_stage2 == n_dec
+    assert st.decisions_per_sample == n_dec / len(n_toks)
+    if c_thr > 1.0:                                   # every token hard
+        assert st.n_exited == 0 and st.realized_q == 1.0
+    if len(set(n_toks)) == 1:
+        for fld in ("n_samples", "n_decisions", "n_exited", "n_stage2"):
+            assert getattr(st, fld) == getattr(host.stats, fld), fld
 
 
 def test_continuous_equivalence_q_grid(tiny_cfg, tiny_params, tiny_spec,
@@ -134,21 +162,53 @@ def test_continuous_eager_drain_off(tiny_cfg, tiny_params, tiny_spec,
     assert res == _expect_streams(oracle["tokens"], n_toks)
 
 
-def test_sync_scheduler_matches_oracle(tiny_cfg, tiny_params, prompt, fns):
-    """The degenerate sync policy (batch formation over DecodeServer,
-    incl. a smaller partial tail batch) yields the same truncated streams,
-    records per-request latency, and counts only real traffic."""
-    sc = SL.ServeConfig(capacity=3, queue_depth=2, c_thr=0.9)
-    oracle = SL.HostLoopDecoder(fns, sc).generate(prompt, max(N_TOKS))
-    sched = SyncScheduler(SL.DecodeServer(fns, sc), n_slots=4,
-                          clock=LogicalClock())
-    for i in range(len(N_TOKS)):
-        sched.submit(Request(sample_id=i, prompt=prompt[i],
-                             n_tokens=N_TOKS[i]))
-    res = sched.run()
-    assert res == _expect_streams(oracle["tokens"], N_TOKS)
-    assert sched.stats.n_finished == len(N_TOKS)
-    assert sched.stats.n_samples == len(N_TOKS)     # padding isn't traffic
+def test_continuous_all_hard_matches_unstaged_decode(tiny_cfg, tiny_params,
+                                                     prompt, fns):
+    """With nothing exiting, staged EE decode through the scheduler must
+    reproduce the plain full-depth decode loop (same greedy
+    continuation)."""
+    sc = SL.ServeConfig(capacity=3, queue_depth=2, c_thr=1.1)
+    n_tokens = 4
+    n_toks = [n_tokens] * prompt.shape[0]
+    res, sched = _run_continuous(fns, sc, prompt, n_toks, n_slots=4,
+                                 max_len=prompt.shape[1] + n_tokens)
+    assert sched.stats.n_exited == 0
+    bb = tiny_params["backbone"]
+    logits, caches, _ = T.prefill(bb, tiny_cfg, jnp.asarray(prompt),
+                                  max_len=prompt.shape[1] + n_tokens)
+    want = [np.argmax(np.asarray(logits), -1).astype(np.int32)]
+    for t in range(1, n_tokens):
+        tok = jnp.asarray(want[-1][:, None])
+        logits, caches = T.decode_step(bb, tiny_cfg, tok, caches,
+                                       jnp.int32(prompt.shape[1] + t - 1))
+        want.append(np.argmax(np.asarray(logits), -1).astype(np.int32))
+    assert res == _expect_streams(np.stack(want, 1), n_toks)
+
+
+@pytest.mark.parametrize("c_thr", [0.0, 1.1], ids=["all_exit", "all_hard"])
+def test_continuous_exit_gap_cache_semantics(prompt, fns, c_thr):
+    """A token that exits early leaves zeros at its position in the
+    stage-2 pages (exit-gap): with every token exiting, only the prompt's
+    pages ever hold data; with every token hard, stage 2 fills each
+    request's decode page too. The prompt is page-aligned, so the decode
+    positions are exactly one page per request."""
+    S, n_tokens = prompt.shape[1], 4
+    assert S % PAGE == 0 and S + n_tokens - 1 <= S + PAGE
+    sc = SL.ServeConfig(capacity=3, queue_depth=2, c_thr=c_thr)
+    B = prompt.shape[0]
+    _, sched = _run_continuous(fns, sc, prompt, [n_tokens] * B, n_slots=B,
+                               max_len=S + n_tokens)
+    written = np.zeros(sched.n_pages + 1, bool)
+    for leaf in jax.tree.leaves(sched._pool):
+        x = np.asarray(leaf)
+        if x.ndim > 2 and x.shape[0] != sched.n_pages + 1:
+            x = np.moveaxis(x, 1, 0)              # (n_sb, pages, ...) blocks
+        written |= (x.reshape(sched.n_pages + 1, -1) != 0).any(axis=1)
+    assert not written[0]                         # the NULL page stays zero
+    decode_pages = B if c_thr > 1.0 else 0
+    assert written.sum() == B * S // PAGE + decode_pages
+    assert (sched.stats.n_stage2 == 0) == (c_thr < 1.0)
+    assert (sched.stats.n_exited == 0) == (c_thr > 1.0)
 
 
 def test_continuous_admission_gating(tiny_cfg, tiny_params, prompt, fns):
@@ -209,7 +269,7 @@ def test_continuous_rejects_overlong_and_duplicate(tiny_cfg, tiny_params,
     """Malformed requests are rejected at submit() — before they can be
     popped into a chunk and damage in-flight state."""
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.0)
-    sched = ContinuousScheduler(fns, sc, n_slots=2, max_len=10,
+    sched = ContinuousScheduler(fns, sc, n_slots=2, max_len=12,
                                 clock=LogicalClock())
     with pytest.raises(ValueError, match="exceeds pool max_len"):
         sched.submit(Request(0, prompt[0], n_tokens=99))
@@ -292,11 +352,13 @@ def _toy_hard(sid, t, q_pct):
     return ((sid * 131 + t * 17) % 100) < q_pct
 
 
-def toy_decode_fns(q_pct: int):
+def toy_decode_fns(q_pct: int, trace_counter=None):
     """DecodeFns whose exit decisions and greedy tokens are pure functions
     of (sample id, decode index): hard iff hash(sid, t) < q_pct; token =
-    _toy_tok(sid, t). The sample id rides the stage-1 cache / stage-2 row
-    payload, so the scheduler's plumbing is exactly what's under test."""
+    _toy_tok(sid, t). The sample id rides the stage-1 cache and the
+    stage-2 page pool (written at admission, read back through the block
+    table), so the scheduler's plumbing is exactly what's under test.
+    ``trace_counter`` counts s1_raw TRACES (not executions)."""
 
     def _logits(sid, t):
         tok = _toy_tok(sid, t)
@@ -313,6 +375,8 @@ def toy_decode_fns(q_pct: int):
         return caches, {"sid": caches["first"][0]}
 
     def s1_raw(tok, c1, pos):
+        if trace_counter is not None:
+            trace_counter["n"] += 1          # runs at trace time only
         sid = c1["first"][0][:, 0]
         t = pos - _TOY_S + 1                 # decode index being produced
         h = jnp.stack([sid, pos], 1).astype(jnp.float32)
@@ -322,7 +386,8 @@ def toy_decode_fns(q_pct: int):
         sid = cache_rows["sid"][:, 0]
         return _logits(sid, step - _TOY_S + 1), cache_rows
 
-    return SL.DecodeFns(prefill, split, jax.jit(s1_raw), s2, s1_raw)
+    return SL.DecodeFns(prefill, split, jax.jit(s1_raw), s2, s1_raw,
+                        **sid_pages(s2))
 
 
 def _toy_requests(n_tokens_list):
@@ -430,8 +495,8 @@ def test_compare_abs_tolerance_composition():
 
 
 def test_compare_hard_min_bound():
-    """`min` is a contract floor enforced regardless of tolerance — the
-    serve_continuous >=1.3x goodput gate."""
+    """`min` is a contract floor enforced regardless of tolerance — e.g.
+    serve_fleet's routed-goodput ratio floor."""
     spec = {"tolerance": 0.25, "min": 1.3}
     assert _gate(1.45, spec, 1.31)["ok"]
     r = _gate(1.45, spec, 1.25)                      # tolerance would allow
@@ -478,14 +543,13 @@ def test_continuous_disaggregated_equivalence_8dev(tiny_cfg, tiny_params,
     c_thr = float(np.median(conf))
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=c_thr)
     spec = ee.EarlyExitSpec(exit_layer=2, c_thr=c_thr)
-    oracle = SL.build_host_decoder(tiny_params, tiny_cfg, spec,
-                                   sc).generate(prompt, 5)
+    oracle = SL.build(tiny_params, tiny_cfg, spec, sc, scheduler=None,
+                      host=True).generate(prompt, 5)
     pl = StagePlacement.from_plan(
         StageMeshPlan.proportional(0.5, jax.device_count()))
-    sched = SL.build_continuous_scheduler(tiny_params, tiny_cfg, spec, sc,
-                                          n_slots=4, max_len=13,
-                                          placement=pl,
-                                          clock=LogicalClock())
+    sched = SL.build(tiny_params, tiny_cfg, spec, sc, n_slots=4,
+                     max_len=16, page_size=PAGE, placement=pl,
+                     clock=LogicalClock())
     n_toks = [5] * prompt.shape[0]
     for r in [Request(i, prompt[i], 5) for i in range(prompt.shape[0])]:
         sched.submit(r)
@@ -521,9 +585,8 @@ def test_continuous_equivalence_subprocess():
     conf = SL.decode_step0_confidences(params, cfg, spec0, prompt,
                                        max_len=13)
     def run_sched(spec, sc, placement):
-        s = SL.build_continuous_scheduler(params, cfg, spec, sc, n_slots=3,
-                                          max_len=13, placement=placement,
-                                          clock=LogicalClock())
+        s = SL.build(params, cfg, spec, sc, n_slots=3, max_len=16,
+                     page_size=4, placement=placement, clock=LogicalClock())
         for i in range(6):
             s.submit(Request(i, prompt[i], n_toks[i]))
         return s.run()
@@ -531,8 +594,8 @@ def test_continuous_equivalence_subprocess():
         c_thr = float(jnp.quantile(conf, q))
         spec = ee.EarlyExitSpec(exit_layer=2, c_thr=c_thr)
         sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=c_thr)
-        oracle = SL.build_host_decoder(params, cfg, spec,
-                                       sc).generate(prompt, 5)
+        oracle = SL.build(params, cfg, spec, sc, scheduler=None,
+                          host=True).generate(prompt, 5)
         want = {i: [int(x) for x in oracle["tokens"][i][:n_toks[i]]]
                 for i in range(6)}
         assert run_sched(spec, sc, None) == want, ("single", q)

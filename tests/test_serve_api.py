@@ -1,21 +1,18 @@
 """Transport-agnostic serving API (`runtime/serve_api.py`): the shared
 submit-side validation (byte-identical errors across every admission
 surface), the RequestQueue revocation/copy semantics the fleet router and
-live migration ride, the ReplicaHandle protocol both schedulers implement,
-the unified `build()` construction matrix (+ the deprecation shims the old
-`serve_loop.build_*` factories became), and the versioned ServeStats
+live migration ride, the ReplicaHandle protocol the scheduler implements,
+the unified `build()` construction matrix, and the versioned ServeStats
 schema freeze."""
 import json
-import warnings
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from repro.runtime import serve_api
 from repro.runtime import serve_loop as SL
 from repro.runtime.scheduler import (ContinuousScheduler, LogicalClock,
-                                     Request, ServeStats, SyncScheduler)
+                                     Request, ServeStats)
 from repro.runtime.serve_api import (ReplicaHandle, RequestQueue, build,
                                      validate_request)
 from test_scheduler import _TOY_S, toy_decode_fns
@@ -27,14 +24,6 @@ def _req(sid, n_tokens=2, arrival=0.0, prompt_len=_TOY_S):
     return Request(sample_id=sid,
                    prompt=np.full((prompt_len,), sid, np.int32),
                    n_tokens=n_tokens, arrival_time=arrival)
-
-
-class _StubServer:
-    """Just enough server for SyncScheduler's submit-side surface (the
-    generate path never runs in these tests)."""
-
-    def __init__(self):
-        self.stats = ServeStats()
 
 
 # ---------------------------------------------------------------------------
@@ -61,8 +50,8 @@ def test_validate_request_messages():
 
 def test_submit_errors_identical_across_surfaces():
     """The same malformed request produces the same error string whether
-    it hits a bare RequestQueue, the continuous scheduler, the sync
-    scheduler, or the fleet router — the single-definition contract."""
+    it hits a bare RequestQueue, the continuous scheduler, or the fleet
+    router — the single-definition contract."""
     from repro.runtime.router import FleetRouter
     max_len = _TOY_S + 4
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
@@ -74,8 +63,6 @@ def test_submit_errors_identical_across_surfaces():
         return {
             "queue": RequestQueue(max_len=max_len),
             "continuous": cont,
-            "sync": SyncScheduler(_StubServer(), n_slots=2,
-                                  clock=LogicalClock(), max_len=max_len),
             # the router is unbounded in max_len (replicas own pool
             # geometry) so it only joins the n_tokens/duplicate cases
             "router": FleetRouter([cont]),
@@ -99,22 +86,6 @@ def test_submit_errors_identical_across_surfaces():
         fn = s.append if isinstance(s, RequestQueue) else s.submit
         fn(_req(5))
         assert _submit_error(fn, _req(5)) == "duplicate sample id 5", name
-
-
-def test_sync_scheduler_rejects_like_continuous(tiny_cfg, tiny_params,
-                                                tiny_spec):
-    """The sync policy validates at submit() too (it historically did
-    not) — same errors as the continuous path, via the shared queue."""
-    sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.9)
-    sched = build(tiny_params, tiny_cfg, tiny_spec, sc, scheduler="sync",
-                  n_slots=2, max_len=10, clock=LogicalClock())
-    with pytest.raises(ValueError, match="exceeds pool max_len"):
-        sched.submit(_req(0, n_tokens=99, prompt_len=8))
-    with pytest.raises(ValueError, match="n_tokens must be >= 1"):
-        sched.submit(_req(1, n_tokens=0, prompt_len=8))
-    sched.submit(_req(2, n_tokens=2, prompt_len=8))
-    with pytest.raises(ValueError, match="duplicate sample id"):
-        sched.submit(_req(2, n_tokens=2, prompt_len=8))
 
 
 # ---------------------------------------------------------------------------
@@ -166,19 +137,17 @@ def test_request_queue_copy_is_independent():
 
 
 # ---------------------------------------------------------------------------
-# ReplicaHandle: both schedulers implement the routable surface
+# ReplicaHandle: the scheduler implements the routable surface
 # ---------------------------------------------------------------------------
 
 def test_schedulers_implement_replica_handle():
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
     cont = ContinuousScheduler(toy_decode_fns(50), sc, n_slots=2,
                                max_len=_TOY_S + 4, clock=LogicalClock())
-    sync = SyncScheduler(_StubServer(), n_slots=2, clock=LogicalClock())
-    for s in (cont, sync):
-        assert isinstance(s, ReplicaHandle)
-        assert s.n_busy == 0 and s.queue_len == 0
-        assert s.next_arrival() is None
-        assert s.drain_finished() == []
+    assert isinstance(cont, ReplicaHandle)
+    assert cont.n_busy == 0 and cont.queue_len == 0
+    assert cont.next_arrival() is None
+    assert cont.drain_finished() == []
     assert not isinstance(object(), ReplicaHandle)
 
 
@@ -198,7 +167,7 @@ def test_continuous_finish_feed_per_request():
 
 
 # ---------------------------------------------------------------------------
-# build(): the one construction path, and the shims over it
+# build(): the one construction path
 # ---------------------------------------------------------------------------
 
 def test_build_matrix_types(tiny_cfg, tiny_params, tiny_spec):
@@ -207,48 +176,34 @@ def test_build_matrix_types(tiny_cfg, tiny_params, tiny_spec):
     assert isinstance(b(mode="prefill", scheduler=None), SL.TwoStageServer)
     assert isinstance(b(mode="prefill", scheduler=None, host=True),
                       SL.HostLoopServer)
-    assert isinstance(b(scheduler=None), SL.DecodeServer)
     assert isinstance(b(scheduler=None, host=True), SL.HostLoopDecoder)
-    assert isinstance(b(scheduler="sync", n_slots=2), SyncScheduler)
-    cont = b(scheduler="continuous", n_slots=2, max_len=12,
-             clock=LogicalClock())
+    cont = b(n_slots=2, max_len=12, page_size=4, clock=LogicalClock())
     assert isinstance(cont, ContinuousScheduler)
+    assert cont.page_size == 4 and cont.n_pages == 2 * 12 // 4
     assert cont.fns_factory is not None               # migration rebuilds
 
 
-def test_build_rejects_bad_points(tiny_cfg, tiny_params, tiny_spec):
+@pytest.mark.parametrize("kw,match", [
+    (dict(mode="train"), "mode must be one of"),
+    (dict(scheduler="fifo"), "takes scheduler='continuous'"),
+    (dict(scheduler="sync", n_slots=2, max_len=12, page_size=4),
+     "takes scheduler='continuous'"),
+    (dict(scheduler=None), "takes scheduler='continuous'"),
+    (dict(mode="prefill", scheduler="continuous"), "takes scheduler=None"),
+    (dict(scheduler="continuous", host=True), "takes scheduler=None"),
+    (dict(n_slots=2, max_len=12), "needs n_slots, max_len"),
+    (dict(scheduler=None, host=True, page_size=4), "page pool"),
+], ids=["mode", "unknown_scheduler", "sync", "bare_decode_server",
+        "prefill_scheduler", "host_scheduler", "no_page_size",
+        "paged_oracle"])
+def test_build_rejects_bad_points(tiny_cfg, tiny_params, tiny_spec, kw,
+                                  match):
+    """Decode serving is the continuous scheduler over the paged store;
+    the sync policy and the bare step-synchronous decode server are gone,
+    and build refuses to name them."""
     sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
-    b = lambda **kw: build(tiny_params, tiny_cfg, tiny_spec, sc, **kw)
-    with pytest.raises(ValueError, match="mode must be one of"):
-        b(mode="train")
-    with pytest.raises(ValueError, match="scheduler must be one of"):
-        b(scheduler="fifo")
-    with pytest.raises(ValueError, match="no scheduling policy"):
-        b(mode="prefill", scheduler="continuous")
-    with pytest.raises(ValueError, match="needs n_slots"):
-        b(scheduler="sync")
-    with pytest.raises(ValueError, match="needs max_len"):
-        b(scheduler="continuous", n_slots=2)
-    with pytest.raises(ValueError, match="baseline-oracle knob"):
-        b(scheduler="sync", n_slots=2, host=True)
-
-
-def test_deprecated_factories_warn_once_and_build(tiny_cfg, tiny_params,
-                                                  tiny_spec):
-    sc = SL.ServeConfig(capacity=2, queue_depth=2, c_thr=0.5)
-    serve_api._WARNED.discard("build_host_decoder")
-    with pytest.warns(DeprecationWarning, match="serve_api.build"):
-        dec = SL.build_host_decoder(tiny_params, tiny_cfg, tiny_spec, sc)
-    assert isinstance(dec, SL.HostLoopDecoder)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")                # second call: silent
-        SL.build_host_decoder(tiny_params, tiny_cfg, tiny_spec, sc)
-    serve_api._WARNED.discard("build_continuous_scheduler")
-    with pytest.warns(DeprecationWarning):
-        sched = SL.build_continuous_scheduler(
-            tiny_params, tiny_cfg, tiny_spec, sc, n_slots=2, max_len=12,
-            clock=LogicalClock())
-    assert isinstance(sched, ContinuousScheduler)
+    with pytest.raises(ValueError, match=match):
+        build(tiny_params, tiny_cfg, tiny_spec, sc, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -285,8 +240,6 @@ _STATS_BACKED_LEAVES = {
     "migration_pause_p99_ms": "migration_pause_p99_ms",
     "n_migrations": "n_migrations",
     "n_rollbacks": "n_migration_rollbacks",
-    # serve_paged's ring gate is dense/paged ring_bytes_moved
-    "ring_bytes_ratio": "ring_bytes_moved",
 }
 
 

@@ -260,8 +260,9 @@ def test_device_server_matches_serve_batch(tiny_cfg, tiny_params):
     N = 16
     toks = np.asarray(jax.random.randint(jax.random.PRNGKey(5), (N, 8), 0,
                                          tiny_cfg.vocab))
-    server = SL.build_server(tiny_params, tiny_cfg, spec,
-                             SL.ServeConfig(capacity=4, c_thr=spec.c_thr))
+    server = SL.build(tiny_params, tiny_cfg, spec,
+                      SL.ServeConfig(capacity=4, c_thr=spec.c_thr),
+                      mode="prefill", scheduler=None)
     results = SL.serve_dataset(server, toks, batch=8)
     one = ee.serve_batch(tiny_params, tiny_cfg, spec, jnp.asarray(toks),
                          capacity=N)

@@ -1,8 +1,8 @@
 """Stage-disaggregated serving: StageExecutor placement semantics (the
 degenerate single-device executor must be a strict no-op), per-stage param
 splitting (bitwise vs full-tree stage fns), and end-to-end bitwise parity
-of the disaggregated TwoStageServer / DecodeServer against the
-single-device servers on an 8-device host platform — in-process when the
+of the disaggregated TwoStageServer and continuous paged decode scheduler
+against their single-device runs on an 8-device host platform — in-process when the
 suite already runs multi-device (CI disaggregated job), and always via a
 subprocess so the tier-1 single-device run covers the path too."""
 import os
@@ -45,7 +45,7 @@ def test_default_placement_degenerate():
     assert not pl.disaggregated
     assert pl.ex1.mesh is None and pl.ex2.mesh is None
     # servers built with no placement get the degenerate one
-    srv = SL._RingedServer(SL.ServeConfig(capacity=2))
+    srv = SL.TwoStageServer(None, None, SL.ServeConfig(capacity=2))
     assert srv.ex1.mesh is None and srv.ex2.mesh is None
     assert srv.stats.stage1_chips == 1 and srv.stats.stage2_chips == 1
 
@@ -192,7 +192,8 @@ def test_disagg_server_params_and_ring_resident():
     cfg, spec, params, toks = _tiny_setup()
     pl = _placement_5_3()
     sc = SL.ServeConfig(capacity=4, queue_depth=2, c_thr=1.1)  # all hard
-    srv = SL.build_server(params, cfg, spec, sc, pl)
+    srv = SL.build(params, cfg, spec, sc, mode="prefill", scheduler=None,
+                   placement=pl)
     SL.serve_dataset(srv, toks, batch=8)
     ids2 = {d.id for d in pl.ex2.devices}
     assert {d.id
@@ -203,9 +204,10 @@ def test_disagg_server_params_and_ring_resident():
 def test_disagg_prefill_server_bitwise():
     cfg, spec, params, toks = _tiny_setup()
     sc = SL.ServeConfig(capacity=4, queue_depth=4, c_thr=spec.c_thr)
-    r_one = SL.serve_dataset(SL.build_server(params, cfg, spec, sc), toks,
-                             batch=8)
-    dis = SL.build_server(params, cfg, spec, sc, _placement_5_3())
+    build = lambda pl: SL.build(params, cfg, spec, sc, mode="prefill",
+                                scheduler=None, placement=pl)
+    r_one = SL.serve_dataset(build(None), toks, batch=8)
+    dis = build(_placement_5_3())
     r_dis = SL.serve_dataset(dis, toks, batch=8)
     assert set(r_dis) == set(r_one)
     for sid in r_one:
@@ -214,15 +216,22 @@ def test_disagg_prefill_server_bitwise():
 
 
 @_multi_device
-def test_disagg_decode_server_bitwise():
+def test_disagg_continuous_paged_streams():
+    """The paged decode scheduler on a 5+3 split serves the same streams
+    as on one device, with its page pool on the stage-2 submesh."""
     cfg, spec, params, toks = _tiny_setup()
-    prompt = toks[:6]
     sc = SL.ServeConfig(capacity=3, queue_depth=2, c_thr=spec.c_thr)
-    o_one = SL.build_decode_server(params, cfg, spec, sc).generate(prompt, 5)
-    o_dis = SL.build_decode_server(params, cfg, spec, sc,
-                                   _placement_5_3()).generate(prompt, 5)
-    np.testing.assert_array_equal(o_dis["tokens"], o_one["tokens"])
-    np.testing.assert_array_equal(o_dis["logits"], o_one["logits"])
+    streams = []
+    for pl in (None, _placement_5_3()):
+        sched = SL.build(params, cfg, spec, sc, n_slots=6, max_len=16,
+                         page_size=4, placement=pl)
+        for i in range(6):
+            sched.submit(SL.Request(i, toks[i], 5))
+        streams.append(sched.run())
+    assert streams[1] == streams[0]
+    ids2 = {d.id for d in _placement_5_3().ex2.devices}
+    for leaf in jax.tree.leaves(sched._pool):
+        assert {d.id for d in leaf.sharding.device_set} <= ids2
 
 
 def _tiny_setup():
@@ -244,18 +253,19 @@ def _tiny_setup():
 # ---------------------------------------------------------------------------
 
 def test_disaggregated_parity_subprocess():
-    """Disaggregated TwoStageServer AND DecodeServer against the
-    single-device servers at q ∈ {0.1, 0.3, 0.5} under
+    """Disaggregated TwoStageServer AND continuous paged decode scheduler
+    against their single-device runs at q ∈ {0.1, 0.3, 0.5} under
     --xla_force_host_platform_device_count=8, q-proportional chip splits.
 
     Where the stage-1 IO is replicated (q = 0.1, 0.3: the batch of 8 does
     not divide the stage-1 submesh) every device runs the single-device
-    program and the servers are bitwise identical. At q = 0.5 the 4+4 split
-    shards the batch, so each device runs stage 1 at a per-device batch of
-    2, and the CPU backend's matmuls round differently at batch 2 than at
-    batch 8 (sharded stage 1 == single-device batch-2 stage 1 bitwise; both
-    differ from batch 8 in the last ulp). There the contract is the greedy
-    streams: the same argmax per sample, and the same decode tokens."""
+    program and the prefill servers are bitwise identical. At q = 0.5 the
+    4+4 split shards the batch, so each device runs stage 1 at a
+    per-device batch of 2, and the CPU backend's matmuls round differently
+    at batch 2 than at batch 8 (sharded stage 1 == single-device batch-2
+    stage 1 bitwise; both differ from batch 8 in the last ulp). There the
+    prefill contract is the same argmax per sample. The decode contract is
+    the same token streams at every q."""
     code = ("import os\n"
             "os.environ['XLA_FLAGS']="
             "'--xla_force_host_platform_device_count=8'\n"
@@ -265,6 +275,7 @@ def test_disaggregated_parity_subprocess():
     from repro.core import exit_decision as ed
     from repro.core.stage_mesh import StageMeshPlan
     from repro.models.config import ArchConfig
+    from repro.launch.serve import serve_requests
     from repro.runtime import serve_loop as SL
     from repro.runtime.stage_executor import StagePlacement
 
@@ -287,10 +298,9 @@ def test_disaggregated_parity_subprocess():
         c_thr = float(jnp.quantile(conf, q))
         spec = ee.EarlyExitSpec(exit_layer=2, c_thr=c_thr)
         sc = SL.ServeConfig(capacity=4, queue_depth=2, c_thr=c_thr)
-        r1 = SL.serve_dataset(SL.build_server(params, cfg, spec, sc),
-                              toks, batch=8)
-        r2 = SL.serve_dataset(SL.build_server(params, cfg, spec, sc, pl),
-                              toks, batch=8)
+        r1, r2 = (SL.serve_dataset(
+            SL.build(params, cfg, spec, sc, mode="prefill", scheduler=None,
+                     placement=p), toks, batch=8) for p in (None, pl))
         sharded = not pl.ex1.place_io(jnp.zeros(8)).sharding \\
             .is_fully_replicated
         assert sharded == (q == 0.5), q
@@ -302,13 +312,11 @@ def test_disaggregated_parity_subprocess():
         cd = float(jnp.quantile(dconf, q))
         dspec = ee.EarlyExitSpec(exit_layer=2, c_thr=cd)
         dsc = SL.ServeConfig(capacity=3, queue_depth=2, c_thr=cd)
-        o1 = SL.build_decode_server(params, cfg, dspec,
-                                    dsc).generate(toks[:8], 5)
-        o2 = SL.build_decode_server(params, cfg, dspec, dsc,
-                                    pl).generate(toks[:8], 5)
-        assert np.array_equal(o1["tokens"], o2["tokens"]), q
-        if not sharded:
-            assert np.array_equal(o1["logits"], o2["logits"]), q
+        o1, o2 = (serve_requests(
+            SL.build(params, cfg, dspec, dsc, n_slots=8, max_len=16,
+                     page_size=4, placement=p), toks[:8], 5)
+            for p in (None, pl))
+        assert o1 == o2, q
         print("q", q, "OK", "sharded" if sharded else "replicated")
     print("PARITY_ALL_OK")
     """))
